@@ -320,14 +320,44 @@ def pack_lm_params(
     """
     del cfg  # the walk is name-driven; cfg kept for adapter symmetry
     fmt = resolve_format(fmt)
-
-    def visit(path, leaf):
-        if _leaf_name(path) in QUANTIZABLE and leaf.ndim >= 2:
-            return quantize_stacked(leaf, fmt, compensate=compensate)
-        return leaf
-
-    packed = jax.tree_util.tree_map_with_path(visit, params)
+    packed = jax.tree_util.tree_map_with_path(
+        lambda path, leaf: _pack_lm_leaf(path, leaf, fmt, compensate), params
+    )
     return stamp_lm_act(packed, calib) if calib is not None else packed
+
+
+def _pack_lm_leaf(path, leaf, fmt: ElpBsdFormat, compensate: bool):
+    if _leaf_name(path) in QUANTIZABLE and leaf.ndim >= 2:
+        return quantize_stacked(leaf, fmt, compensate=compensate)
+    return leaf
+
+
+def init_packed_lm_params(
+    cfg: ArchConfig, key: Array, fmt: ElpBsdFormat | str, *, compensate: bool = True
+) -> Any:
+    """``pack_lm_params(init_params(cfg, key), cfg, fmt)``, one leaf at a time.
+
+    Each float leaf is drawn by a jit of the whole initializer that
+    returns only that leaf (XLA drops the rest, so the values equal the
+    full initializer's), packed, and released before the next is drawn.
+    That is how a model whose float tree exceeds device memory is made
+    on one chip: qwen3_8b's tree is 16.4 GB in bf16, its largest leaf
+    3.6 GB, and its elp4 packed tree 6.0 GB.
+    """
+    from repro.models import get_model
+
+    init = get_model(cfg).init_params
+    fmt = resolve_format(fmt)
+    paths, treedef = jax.tree_util.tree_flatten_with_path(
+        jax.eval_shape(lambda k: init(cfg, k), key)
+    )
+    draw = jax.jit(lambda k, i: jax.tree.leaves(init(cfg, k))[i], static_argnums=1)
+    leaves = []
+    for i, (path, _) in enumerate(paths):
+        # waiting here keeps one float leaf live: an async draw of the
+        # next leaf would allocate it while this one is still packing
+        leaves.append(jax.block_until_ready(_pack_lm_leaf(path, draw(key, i), fmt, compensate)))
+    return jax.tree_util.tree_unflatten(treedef, leaves)
 
 
 # ---------------------------------------------------------------------------

@@ -54,6 +54,7 @@ import numpy as np
 
 from repro.bench import registry, schema
 from repro.bench.autotune import autotune_shapes, invalidate_memory_cache
+from repro.runtime.compile_cache import enable_compile_cache
 
 REPO_ROOT = os.path.normpath(os.path.join(os.path.dirname(__file__), "..", "..", ".."))
 
@@ -195,6 +196,7 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--min-us", type=float, default=200.0)
     ap.add_argument("--autotune", action="store_true")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     suites = list(schema.SUITES) if args.suite == "all" else [args.suite]
 

@@ -54,6 +54,7 @@ from typing import Iterable, Sequence
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
 
 DEFAULT_BLOCKS = (128, 128, 128)
 # Implementations the tuner races per shape. "pallas" is the tiled
@@ -435,7 +436,9 @@ def autotune_flash_decode(
         )
     backend = actual
     kv = kv or h
-    mesh = jax.make_mesh((1, jax.device_count()), ("data", "model"))
+    mesh = jax.make_mesh(
+        (1, jax.device_count()), ("data", "model"), axis_types=(AxisType.Auto,) * 2
+    )
     pctx = ParallelCtx(mesh=mesh, batch_axes=("data",), model_axis="model", flash_decode=True)
     rng = np.random.default_rng(seed)
     q = jnp.asarray(rng.normal(size=(b, 1, h, hd)), jnp.float32)

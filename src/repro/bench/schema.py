@@ -12,7 +12,7 @@ Document shape (version 1)::
       "schema_version": 1,
       "suite": "kernels" | "e2e",
       "backend": "cpu" | "tpu" | ...,
-      "jax_version": "0.4.37",
+      "jax_version": "0.9.0",
       "smoke_only": bool,            # was this run --smoke?
       "entries": {
         "<workload name>": {

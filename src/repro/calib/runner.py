@@ -267,7 +267,7 @@ def count_range_reductions(fn: Callable, *args, **kwargs) -> int:
     (max-pool → ``reduce_window_max``, relu → elementwise ``max``) do
     not, so a calibrated CNN forward must count zero.
     """
-    from jax import core as jcore
+    from jax.extend import core as jcore
 
     def subjaxprs(v):
         if isinstance(v, jcore.Jaxpr):

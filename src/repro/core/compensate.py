@@ -56,16 +56,25 @@ def _compensate_one_group(w: Array, nn_idx: Array, levels_j: Array) -> Array:
 
     # Cost (paper: |S - SO|): distance from the raw value to the flip level.
     cost = jnp.where(candidate, jnp.abs(wf - q_flip), jnp.inf)
-    order = jnp.argsort(cost)
-
-    delta_sorted = jnp.where(candidate[order], delta[order], 0.0)
+    # One stable sort carries the deltas along with their costs: a
+    # permutation gather and the scatter that inverts it cost more on a
+    # TPU than the sort itself.
+    cost_sorted, delta_sorted = jax.lax.sort(
+        (cost, jnp.where(candidate, delta, 0.0)), num_keys=1, is_stable=True
+    )
     prefix_mean = mean_err + jnp.cumsum(delta_sorted) / n
     # |mean| trajectory including "no flips" at position 0
     traj = jnp.abs(jnp.concatenate([mean_err[None], prefix_mean]))
     k_star = jnp.argmin(traj)  # number of accepted flips (first minimum)
 
-    rank = jnp.zeros((n,), dtype=jnp.int32).at[order].set(jnp.arange(n, dtype=jnp.int32))
-    accept = candidate & (rank < k_star)
+    # The first k_star of the stable cost order: every cost below the
+    # k_star-th smallest, then the ties at it in index order.
+    thr = cost_sorted[jnp.maximum(k_star - 1, 0)]
+    below = cost < thr
+    tie = cost == thr
+    tie_rank = jnp.cumsum(tie) - 1
+    first_k = below | (tie & (tie_rank < k_star - jnp.sum(below)))
+    accept = candidate & (k_star > 0) & first_k
     return jnp.where(accept, flip_idx, nn_idx)
 
 

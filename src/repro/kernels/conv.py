@@ -21,7 +21,13 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.kernels.ops import PackedWeight, dequantize_nd, quantized_matmul
+from repro.kernels.ops import (
+    PackedWeight,
+    dequantize_nd,
+    mesh_devices,
+    quantized_matmul,
+    select_impl,
+)
 
 Array = jax.Array
 F32 = jnp.float32
@@ -101,22 +107,20 @@ def quantized_conv2d(
         b, h, w = x.shape[0], x.shape[1], x.shape[2]
         ho, _ = _out_size_and_pads(h, kh, stride, padding)
         wo, _ = _out_size_and_pads(w, kw, stride, padding)
-        m0 = b * ho * wo
-        if pw.codes.ndim != 2 or jax.device_count() > 1:
+        impl, sel_blocks, source = select_impl(
+            b * ho * wo,
+            pw.shape[0],
+            pw.shape[1],
+            fmt_name=pw.fmt_name,
+            nibble=pw.nibble,
+            stacked=pw.codes.ndim != 2,
+            devices=mesh_devices(pw.codes),
+        )
+        if source == "heuristic":
+            # Interim heuristic (no measurement for this shape): XLA.
             impl = "xla"
-        else:
-            from repro.bench.autotune import lookup_impl
-
-            sel, sel_blocks = lookup_impl(
-                m0, pw.shape[0], pw.shape[1], fmt_name=pw.fmt_name, nibble=pw.nibble
-            )
-            if sel is None:
-                # Interim heuristic (no measurement for this shape): XLA.
-                impl = "xla"
-            else:
-                impl = sel
-                if block_sizes is None or block_sizes == "auto":
-                    block_sizes = tuple(sel_blocks)
+        elif sel_blocks is not None and (block_sizes is None or block_sizes == "auto"):
+            block_sizes = sel_blocks
     if impl == "xla":
         out = lax.conv_general_dilated(
             x.astype(F32),

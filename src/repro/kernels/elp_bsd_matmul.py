@@ -39,7 +39,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_compiler_params
 from repro.core.elp_bsd import ElpBsdFormat
 from repro.kernels.ref import decode_values_shift_add, unpack_nibbles_k
 
@@ -138,7 +137,7 @@ def elp_bsd_matmul(
             # float32 accumulator tile held in VMEM across the K steps
             pltpu.VMEM((block_m, block_n), jnp.float32)
         ],
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

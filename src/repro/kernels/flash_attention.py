@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_compiler_params
 
 Array = jax.Array
 F32 = jnp.float32
@@ -116,7 +115,7 @@ def flash_attention(
             pltpu.VMEM((block_q,), F32),  # running sum
             pltpu.VMEM((block_q, hd), F32),  # accumulator
         ],
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -32,7 +32,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_compiler_params
 from repro.core.elp_bsd import ElpBsdFormat
 from repro.kernels.ref import decode_values_shift_add, unpack_nibbles_k
 
@@ -141,7 +140,7 @@ def fused_decode_matmul(
             # float32 accumulator strip held in VMEM across the K steps
             pltpu.VMEM((m, block_n), jnp.float32)
         ],
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,

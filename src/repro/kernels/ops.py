@@ -24,9 +24,10 @@ execute via :func:`repro.kernels.conv.quantized_conv2d`.
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import dataclasses
 import functools
-from typing import Sequence
 
 import jax
 import jax.numpy as jnp
@@ -118,12 +119,17 @@ class PackedWeight:
 jax.tree_util.register_pytree_with_keys_class(PackedWeight)
 
 
+# jitted whole: run op by op, a conversion compiles each of its
+# primitives anew for every weight shape
+@functools.partial(
+    jax.jit, static_argnames=("fmt", "compensate", "group_axes", "granularity", "nibble")
+)
 def pack_weight(
     w: Array,
     fmt: ElpBsdFormat | str,
     *,
     compensate: bool = True,
-    group_axes: Sequence[int] | None = None,
+    group_axes: tuple[int, ...] | None = None,
     granularity: str = "per_tensor",
     nibble: bool | None = None,
 ) -> tuple[PackedWeight, Array]:
@@ -166,6 +172,7 @@ def pack_weight(
     return pw, ct.values.astype(w.dtype)
 
 
+@functools.partial(jax.jit, static_argnames=("fmt", "compensate", "granularity", "nibble"))
 def pack_conv_weight(
     w: Array,
     fmt: ElpBsdFormat | str,
@@ -269,26 +276,78 @@ def dequantize_tree(tree):
     )
 
 
-def _resolve_auto_impl(m0: int, k: int, n: int, pw: PackedWeight, block_sizes):
-    """Trace-time resolution of ``impl="auto"`` to a concrete impl.
+def mesh_devices(x: Array) -> int:
+    """Devices of the mesh ``x`` lives on, read at trace time.
 
-    Stacked weights and multi-device layouts always take the XLA path
-    (the Pallas kernels are single-[K,N], single-device). Otherwise the
-    autotune cache's measured winner decides; a miss falls back to the
-    old backend heuristic (Pallas on TPU, XLA elsewhere). When the
-    caller left blocks to "auto"/default, the winner's tuned blocks ride
-    along — that is the exact configuration the cache timed.
+    An array committed to a mesh names it in its own sharding; inside a
+    ``jit`` given mesh shardings the context mesh names it. Anything
+    else lives on one device, however many the host has.
     """
-    if pw.codes.ndim != 2 or jax.device_count() > 1:
-        return "xla", block_sizes
+    mesh = jax.typeof(x).sharding.mesh
+    if mesh.empty:
+        mesh = jax.sharding.get_abstract_mesh()
+    return 1 if mesh.empty else mesh.size
+
+
+def select_impl(
+    m: int, k: int, n: int, *, fmt_name: str, nibble: bool, stacked: bool, devices: int
+) -> tuple[str, tuple[int, int, int] | None, str]:
+    """Resolve ``impl="auto"`` for one packed matmul: ``(impl, blocks, source)``.
+
+    Stacked weights and weights sharded over several devices take the
+    XLA path (``source="structural"``): the Pallas kernels take one
+    ``[K, N]`` on one device, and a sharded decode must partition with
+    its shards. Otherwise the autotune cache's measured winner decides
+    (``"autotuned"``, with its tuned blocks); a miss falls back to the
+    Pallas kernel on TPU and XLA elsewhere (``"heuristic"``, no blocks).
+    """
+    if stacked or devices > 1:
+        return "xla", None, "structural"
     from repro.bench.autotune import lookup_impl
 
-    sel, sel_blocks = lookup_impl(m0, k, n, fmt_name=pw.fmt_name, nibble=pw.nibble)
+    sel, sel_blocks = lookup_impl(m, k, n, fmt_name=fmt_name, nibble=nibble)
     if sel is None:
-        return ("pallas" if jax.default_backend() == "tpu" else "xla"), block_sizes
-    if block_sizes is None or block_sizes == "auto":
-        return sel, tuple(sel_blocks)
-    return sel, block_sizes
+        return ("pallas" if jax.default_backend() == "tpu" else "xla"), None, "heuristic"
+    return sel, tuple(sel_blocks), "autotuned"
+
+
+_FORCED_IMPL: contextvars.ContextVar[str | None] = contextvars.ContextVar(
+    "forced_impl", default=None
+)
+
+
+@contextlib.contextmanager
+def force_impl(impl: str):
+    """Trace every ``impl="auto"`` packed matmul in the block as ``impl``.
+
+    Reference runs use ``force_impl("xla")`` to pin the plain
+    dequantize-then-dot datapath that the kernels are checked against.
+    It acts when a function is traced, so jit a fresh function inside it.
+    """
+    token = _FORCED_IMPL.set(impl)
+    try:
+        yield
+    finally:
+        _FORCED_IMPL.reset(token)
+
+
+def _resolve_auto_impl(m0: int, k: int, n: int, pw: PackedWeight, block_sizes):
+    """Trace-time resolution of ``impl="auto"`` (see :func:`select_impl`).
+    When the caller left blocks to "auto"/default, the winner's tuned
+    blocks ride along — that is the exact configuration the cache timed.
+    """
+    impl, blocks, _ = select_impl(
+        m0,
+        k,
+        n,
+        fmt_name=pw.fmt_name,
+        nibble=pw.nibble,
+        stacked=pw.codes.ndim != 2,
+        devices=mesh_devices(pw.codes),
+    )
+    if blocks is not None and (block_sizes is None or block_sizes == "auto"):
+        return impl, blocks
+    return impl, block_sizes
 
 
 def _pad_to(x: Array, axis: int, mult: int) -> Array:
@@ -298,6 +357,19 @@ def _pad_to(x: Array, axis: int, mult: int) -> Array:
     widths = [(0, 0)] * x.ndim
     widths[axis] = (0, pad)
     return jnp.pad(x, widths)
+
+
+def quantized_matmul(x: Array, pw: PackedWeight, *, impl: str = "pallas", **kw) -> Array:
+    """``x[..., K] @ dequant(pw)[K, N]`` with fused in-VMEM decode.
+
+    See :func:`_quantized_matmul` for the arguments. ``impl="auto"``
+    becomes the impl of an enclosing :func:`force_impl` here, before the
+    jit, so that a forced and an automatic trace never share a cache
+    entry.
+    """
+    if impl == "auto":
+        impl = _FORCED_IMPL.get() or impl
+    return _quantized_matmul(x, pw, impl=impl, **kw)
 
 
 @functools.partial(
@@ -312,7 +384,7 @@ def _pad_to(x: Array, axis: int, mult: int) -> Array:
         "interpret",
     ),
 )
-def quantized_matmul(
+def _quantized_matmul(
     x: Array,
     pw: PackedWeight,
     *,

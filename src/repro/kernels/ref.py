@@ -87,9 +87,14 @@ def decode_values_shift_add(codes: Array, fmt: ElpBsdFormat) -> Array:
 
 def unpack_nibbles_k(packed: Array) -> Array:
     """Unpack ``[..., K//2, N] uint8`` (two 4-bit codes along K per byte)
-    to ``[..., K, N]``. Row ``2r`` is the low nibble, ``2r+1`` the high."""
-    lo = (packed & 0x0F).astype(jnp.int32)
-    hi = ((packed >> 4) & 0x0F).astype(jnp.int32)
+    to ``[..., K, N]``. Row ``2r`` is the low nibble, ``2r+1`` the high.
+
+    The bytes widen to int32 before the mask and shift: Mosaic has no
+    8-bit vector shift on TPU v5e, so a uint8 ``>>`` does not compile.
+    """
+    wide = packed.astype(jnp.int32)
+    lo = wide & 0x0F
+    hi = (wide >> 4) & 0x0F
     out = jnp.stack([lo, hi], axis=-2)  # [..., K//2, 2, N]
     return out.reshape(*packed.shape[:-2], 2 * packed.shape[-2], packed.shape[-1])
 

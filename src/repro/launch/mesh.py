@@ -13,6 +13,7 @@ all-reduce, optionally compressed (optim.compress).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
@@ -22,4 +23,6 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     for s in shape:
         n *= s
     devices = jax.devices()[:n]
-    return jax.make_mesh(shape, axes, devices=devices)
+    return jax.make_mesh(
+        shape, axes, axis_types=(AxisType.Auto,) * len(shape), devices=devices
+    )

@@ -1,9 +1,9 @@
 """Serving launcher: ``python -m repro.launch.serve --arch <id> [...]``.
 
 Drives the continuous-batching engine (:mod:`repro.serve`, DESIGN.md
-§9): loads (or randomly initializes, for smoke runs) weights,
-optionally converts them to packed ELP_BSD through the ``repro.api``
-front door, stands up a :class:`~repro.serve.ServeEngine` on an elastic
+§9): randomly initializes weights from ``--seed``, optionally as packed
+ELP_BSD codes made one float leaf at a time
+(:func:`repro.api_schemes.init_packed_lm_params`), stands up a :class:`~repro.serve.ServeEngine` on an elastic
 mesh (``runtime/elastic`` picks the largest divisibility-honoring mesh
 for the alive devices), and serves a mixed-length staggered request
 trace — no padding of short prompts, slots reused the step a request
@@ -31,9 +31,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.api_schemes import init_packed_lm_params
 from repro.configs import get_config
 from repro.data.pipeline import LmDataset
 from repro.models import get_model
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.runtime.quantized_params import packed_bytes
 from repro.serve import ENGINE_FAMILIES, ServeEngine, ServeSetup, static_generate
 
@@ -55,6 +57,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
+    ap.add_argument("--seed", type=int, default=0, help="seed of the random weights")
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--prompt-len", type=int, default=32)
@@ -93,20 +96,23 @@ def main() -> None:
         help="capture a jax.profiler trace of the first decode dispatches into DIR",
     )
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.reduced()
-    api = get_model(cfg)
-    params = api.init_params(cfg, jax.random.PRNGKey(0))
+    key = jax.random.PRNGKey(args.seed)
     draft_params = None
     spec_draft = "model"
+    # packed tiers are made one float leaf at a time: a full-width float
+    # tree (qwen3_8b: 16.4 GB in bf16) does not fit one chip, its packed
+    # tree does
+    if args.quant:
+        params = init_packed_lm_params(cfg, key, args.quant)
+    else:
+        params = get_model(cfg).init_params(cfg, key)
     if args.speculative and args.draft_fmt != "ngram":
-        from repro import api as front
-
-        draft_params = front.quantize(
-            cfg, params, front.QuantScheme(fmt=args.draft_fmt)
-        ).params
+        draft_params = init_packed_lm_params(cfg, key, args.draft_fmt)
         print(
             f"speculative serving: {args.draft_fmt} drafts "
             f"({packed_bytes(draft_params) / 1e6:.1f} MB), "
@@ -116,9 +122,6 @@ def main() -> None:
         spec_draft = "ngram"
         print(f"speculative serving: ngram drafts, K={args.spec_k}")
     if args.quant:
-        from repro import api as front
-
-        params = front.quantize(cfg, params, front.QuantScheme(fmt=args.quant)).params
         print(f"quantized weights: {packed_bytes(params) / 1e6:.1f} MB")
 
     ds = LmDataset(cfg, seq_len=args.prompt_len, batch=max(args.slots, 4), seed=7)

@@ -13,6 +13,7 @@ import argparse
 import jax
 
 from repro.configs import get_config
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.runtime.elastic import make_mesh
 from repro.runtime.train_loop import TrainSetup, train
 
@@ -30,6 +31,7 @@ def main() -> None:
     ap.add_argument("--seq-parallel", action="store_true")
     ap.add_argument("--model-parallel", type=int, default=16)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

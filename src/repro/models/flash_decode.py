@@ -34,7 +34,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.models.context import ParallelCtx
 
 Array = jax.Array
@@ -153,7 +152,7 @@ def flash_decode_attention(
     pspec = P(bspec) if pos.ndim == 1 else P()
     if ks is not None:
         fn = partial(_local_attn, axis=axis, window=window, n_rep=n_rep, block_s=block_s)
-        mapped = shard_map(
+        mapped = jax.shard_map(
             fn,
             mesh=pctx.mesh,
             in_specs=(qspec, cspec, cspec, cspec, cspec, pspec),
@@ -168,7 +167,7 @@ def flash_decode_attention(
             axis=axis, window=window, n_rep=n_rep, block_s=block_s,
         )
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         fn4,
         mesh=pctx.mesh,
         in_specs=(qspec, cspec, cspec, pspec),

@@ -236,9 +236,10 @@ def matmul(x: Array, w) -> Array:
     decoded in-graph via ``impl="auto"`` — the autotune cache's measured
     winner per (shape, layout, backend) picks between the tiled Pallas
     kernel, the fused decode-step kernel, and the XLA dequant path, with
-    tuned block sizes resolved at trace time. Stacked (scan-layer)
-    weights and multi-device meshes always stay on the XLA path (pjit
-    must keep the decode in XLA so it partitions with the shards).
+    tuned block sizes resolved at trace time. Stacked (expert) weights
+    and weights sharded over a multi-device mesh always stay on the XLA
+    path (pjit must keep the decode in XLA so it partitions with the
+    shards); a one-device weight uses the kernels on any host.
     Either way HBM moves only the code bytes.
     """
     from repro.kernels.ops import PackedWeight, quantized_matmul

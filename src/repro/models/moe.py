@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 
 from repro.configs.base import ArchConfig
 from repro.models.context import ParallelCtx
@@ -156,7 +155,7 @@ def moe_ep(p: dict[str, Array], x2d: Array, cfg: ArchConfig, pctx: ParallelCtx) 
         # shards codes AND per-expert sf the same way (both lead with E).
         return jax.tree.map(lambda _: P(axis), w)
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         fn,
         mesh=pctx.mesh,
         in_specs=(tok, P(None, None), espec(p["we1"]), espec(p["we3"]), espec(p["we2"])),

@@ -1332,21 +1332,23 @@ class ServeEngine:
 
         Walks the packed weight tree and resolves every distinct
         ``[K, N]`` decode-step GEMM (``M`` = the slot batch) exactly the
-        way ``layers.matmul(impl="auto")`` does at trace time: the
+        way ``layers.matmul(impl="auto")`` does at trace time
+        (:func:`repro.kernels.ops.select_impl`): XLA when the mesh the
+        placed codes live on spans several devices (read by the same
+        :func:`repro.kernels.ops.mesh_devices` the trace reads), else the
         autotune cache's measured winner, or the backend heuristic on a
-        miss. Observability for "is the fused decode kernel actually
+        miss. Layer weights are
+        judged per layer slice, as the layer scan hands them to the
+        matmul. Observability for "is the fused decode kernel actually
         on?" — keyed ``MxKxN|fmt|layout``, each value recording the
         impl, how it was chosen (``autotuned`` / ``heuristic`` /
         ``structural``), and how many weights share the shape.
         """
-        from repro.bench.autotune import lookup_impl
-        from repro.kernels.ops import PackedWeight
+        from repro.kernels.ops import PackedWeight, mesh_devices, select_impl
 
-        backend = jax.default_backend()
-        multi = jax.device_count() > 1
         m = self._sched.n_slots
         out: dict[str, dict] = {}
-        for leaf in jax.tree.leaves(
+        for path, leaf in jax.tree_util.tree_leaves_with_path(
             self.params, is_leaf=lambda l: isinstance(l, PackedWeight)
         ):
             if not isinstance(leaf, PackedWeight):
@@ -1356,32 +1358,36 @@ class ServeEngine:
             if key in out:
                 out[key]["count"] += 1
                 continue
-            if leaf.codes.ndim != 2 or multi:
-                impl, source = "xla", "structural"
-            else:
-                sel, _ = lookup_impl(m, k, n, fmt_name=leaf.fmt_name, nibble=leaf.nibble)
-                if sel is None:
-                    impl = "pallas" if backend == "tpu" else "xla"
-                    source = "heuristic"
-                else:
-                    impl, source = sel, "autotuned"
+            scanned = getattr(path[0], "key", None) == "blocks"
+            impl, _, source = select_impl(
+                m,
+                k,
+                n,
+                fmt_name=leaf.fmt_name,
+                nibble=leaf.nibble,
+                stacked=leaf.codes.ndim - scanned != 2,
+                devices=mesh_devices(leaf.codes),
+            )
             out[key] = {"impl": impl, "source": source, "count": 1}
         return out
 
-    def decode_cost(self) -> dict:
-        """HLO cost (FLOPs / bytes / collectives) of the compiled greedy
-        decode step — the graph the continuous loop actually runs, and
-        the evidence that packed serving moves code bytes, not a
-        dequantized weight tree."""
-        from repro.launch.hlo_stats import compiled_cost
-
-        lowered = self._decode_greedy.lower(
+    def compiled_decode(self):
+        """The compiled greedy decode step — the graph the continuous
+        loop actually runs (``.as_text()`` shows which kernels it calls)."""
+        return self._decode_greedy.lower(
             self.params,
             jnp.zeros((self._sched.n_slots, 1), jnp.int32),
             jax.eval_shape(lambda: self._cache),
             jnp.asarray(np.array(self._pos)),
-        )
-        return compiled_cost(lowered.compile())
+        ).compile()
+
+    def decode_cost(self) -> dict:
+        """HLO cost (FLOPs / bytes / collectives) of the compiled greedy
+        decode step — the evidence that packed serving moves code bytes,
+        not a dequantized weight tree."""
+        from repro.launch.hlo_stats import compiled_cost
+
+        return compiled_cost(self.compiled_decode())
 
     # -- internals -----------------------------------------------------------
     def _dispatch_cache(self, cache: Any = None) -> Any:

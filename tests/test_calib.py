@@ -219,8 +219,19 @@ def test_serving_conversion_attaches_act_scales():
     assert bool(jnp.all(jnp.isfinite(logits)))
     rel = float(jnp.linalg.norm(logits - logits_ref) / jnp.linalg.norm(logits_ref))
     assert rel < 0.1  # 8-bit activation noise, not a wrong scale
-    # and greedy decoding is unchanged by calibrated activation quant
-    assert bool(jnp.all(jnp.argmax(logits, -1) == jnp.argmax(logits_ref, -1)))
+    # and greedy decoding is unchanged by calibrated activation quant at
+    # every position of both calibration batches (128 decisions) that is
+    # not a near-tie. The 8-bit noise is about a tenth of the logit scale
+    # (rel above), so a decision whose reference top-2 gap is under 0.1
+    # of that position's logit std may flip; random-init logits hold a few
+    # such ties (the smallest gap here is 0.006 against a std of 0.86).
+    fwd = jax.jit(lambda p, t: tr.forward(p, cfg, t))
+    got = np.concatenate([np.asarray(fwd(qp, t)).reshape(-1, cfg.vocab) for t in toks])
+    ref = np.concatenate([np.asarray(fwd(qp_noact, t)).reshape(-1, cfg.vocab) for t in toks])
+    top2 = np.sort(ref, -1)[:, -2:]
+    clear = top2[:, 1] - top2[:, 0] >= 0.1 * ref.std(-1)
+    assert clear.mean() >= 0.75, clear.mean()  # the check is not vacuous
+    np.testing.assert_array_equal(got.argmax(-1)[clear], ref.argmax(-1)[clear])
 
 
 # ---------------------------------------------------------------------------

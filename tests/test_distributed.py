@@ -38,6 +38,7 @@ def test_moe_ep_matches_dense():
     run_in_subprocess(
         """
         import jax, jax.numpy as jnp, numpy as np, dataclasses
+        from jax.sharding import AxisType
         from repro.configs.base import ArchConfig
         from repro.models import moe
         from repro.models.context import ParallelCtx
@@ -46,7 +47,7 @@ def test_moe_ep_matches_dense():
                          n_heads=4, n_kv_heads=4, d_ff=64, vocab=64, head_dim=8,
                          n_experts=8, topk=2, dtype_str="float32",
                          moe_capacity_factor=8.0)  # no drops -> exact match
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         pctx = ParallelCtx(mesh=mesh, batch_axes=("data",), model_axis="model")
         k = jax.random.PRNGKey(0)
         ks = jax.random.split(k, 5)
@@ -71,11 +72,12 @@ def test_flash_decode_matches_dot():
     run_in_subprocess(
         """
         import jax, jax.numpy as jnp, numpy as np, math
+        from jax.sharding import AxisType
         from repro.models.context import ParallelCtx
         from repro.models.flash_decode import flash_decode_attention
         from repro.models.layers import attention_dot, repeat_kv
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         pctx = ParallelCtx(mesh=mesh, batch_axes=("data",), model_axis="model",
                            flash_decode=True)
         k = jax.random.PRNGKey(1)
@@ -105,6 +107,7 @@ def test_sharded_train_step_matches_single_device():
     run_in_subprocess(
         """
         import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import AxisType
         from repro.configs.base import ArchConfig
         from repro.models import get_model
         from repro.optim import adamw
@@ -116,7 +119,7 @@ def test_sharded_train_step_matches_single_device():
                          n_heads=4, n_kv_heads=2, d_ff=128, vocab=128,
                          head_dim=16, dtype_str="float32")
         api = get_model(cfg)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         ds = LmDataset(cfg, seq_len=32, batch=8, seed=0)
         np_batch = ds.np_batch(0)
 
@@ -164,12 +167,13 @@ def test_flash_decode_int8_cache_matches_fp():
     run_in_subprocess(
         """
         import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import AxisType
         from repro.models.context import ParallelCtx
         from repro.models.flash_decode import flash_decode_attention
         from repro.models.layers import attention_dot, repeat_kv
         from repro.models.transformer import _cache_q
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         pctx = ParallelCtx(mesh=mesh, batch_axes=("data",), model_axis="model",
                            flash_decode=True)
         b, smax, h, kv, hd = 4, 64, 8, 2, 16

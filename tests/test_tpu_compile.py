@@ -1,0 +1,107 @@
+"""Compile the Pallas kernels for a described TPU v5e at real widths.
+
+Interpret mode cannot show what the chip's compiler refuses (8-bit
+vector shifts, unaligned code tiles, VMEM limits). These tests hand the
+kernels to the TPU compiler for a v5e that is described, not attached:
+qwen3-8b's four GEMM shapes at decode (M = 8 slots) and prefill
+(M = 512 tokens), in nibble (elp_bsd_a4) and u8 (elp_bsd_c6) codes, and
+one VGG-16 im2col conv through the ops wrapper. Nothing runs, so these
+say nothing about results or times.
+
+The topology is described inside a module fixture, never at import:
+only one process may load the TPU library at a time, and every test
+worker imports this file.
+"""
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from repro.core.elp_bsd import PRESET_FORMATS
+from repro.kernels.elp_bsd_matmul import elp_bsd_matmul
+from repro.kernels.fused_decode import fused_decode_matmul
+from repro.kernels.ops import PackedWeight, quantized_matmul
+
+QWEN3_8B_KN = [(4096, 4096), (4096, 1024), (4096, 12288), (12288, 4096)]
+LAYOUTS = [("elp_bsd_a4", True), ("elp_bsd_c6", False)]
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+        except Exception as e:  # no TPU compiler in this installation
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        # a compile for a described chip is written to the persistent
+        # cache but cannot be read back without one: keep it out
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            yield SingleDeviceSharding(topo.devices[0])
+        finally:
+            jax.config.update("jax_enable_compilation_cache", was)
+            compilation_cache.reset_cache()
+
+
+def _compiled_text(fn, *args) -> str:
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _kernel_args(sharding, m, k, n, nibble, x_dtype=jnp.bfloat16):
+    return (
+        jax.ShapeDtypeStruct((m, k), x_dtype, sharding=sharding),
+        jax.ShapeDtypeStruct((k // 2 if nibble else k, n), jnp.uint8, sharding=sharding),
+        jax.ShapeDtypeStruct((1, 1), jnp.float32, sharding=sharding),
+    )
+
+
+@pytest.mark.parametrize("fmt_name,nibble", LAYOUTS)
+@pytest.mark.parametrize("k,n", QWEN3_8B_KN)
+def test_fused_decode_compiles_at_qwen3_8b_decode(one_chip, k, n, fmt_name, nibble):
+    fmt = PRESET_FORMATS[fmt_name]
+    text = _compiled_text(
+        lambda x, c, s: fused_decode_matmul(x, c, s, fmt, nibble=nibble, interpret=False),
+        *_kernel_args(one_chip, 8, k, n, nibble),
+    )
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("m", [8, 512], ids=["decode", "prefill"])
+@pytest.mark.parametrize("fmt_name,nibble", LAYOUTS)
+@pytest.mark.parametrize("k,n", QWEN3_8B_KN)
+def test_tiled_kernel_compiles_at_qwen3_8b(one_chip, k, n, fmt_name, nibble, m):
+    fmt = PRESET_FORMATS[fmt_name]
+    block_m = min(m, 128)  # the decode strip rides as one (8, block_k) x block
+    text = _compiled_text(
+        lambda x, c, s: elp_bsd_matmul(
+            x, c, s, fmt, nibble=nibble, block_m=block_m, interpret=False
+        ),
+        *_kernel_args(one_chip, m, k, n, nibble),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_vgg16_im2col_conv_compiles(one_chip):
+    """VGG-16 conv1_2 at 224x224, batch 1: patches [50176, 3*3*64]
+    against nibble codes for [576, 64], padded by the ops wrapper."""
+    k, n = 3 * 3 * 64, 64
+    pw = PackedWeight(
+        codes=jax.ShapeDtypeStruct((k // 2, n), jnp.uint8, sharding=one_chip),
+        sf=jax.ShapeDtypeStruct((1, 1), jnp.float32, sharding=one_chip),
+        fmt_name="elp_bsd_a4",
+        nibble=True,
+        shape=(k, n),
+        source_shape=(3, 3, 64, n),
+    )
+    x = jax.ShapeDtypeStruct((224 * 224, k), jnp.float32, sharding=one_chip)
+    text = _compiled_text(
+        lambda x, pw: quantized_matmul(x, pw, impl="pallas", interpret=False), x, pw
+    )
+    assert "tpu_custom_call" in text
