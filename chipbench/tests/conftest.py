@@ -1,0 +1,7 @@
+"""Put the benchmark's own directory on the path for its tests."""
+import os
+import sys
+
+BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if BENCH_DIR not in sys.path:
+    sys.path.insert(0, BENCH_DIR)
