@@ -99,48 +99,49 @@ def quantized_conv2d(
     ``"auto"`` to resolve the im2col matmul shape through the autotune
     cache.
     """
-    if pw.source_shape is None or len(pw.source_shape) != 4:
-        raise ValueError("quantized_conv2d needs a pack_conv_weight-packed weight")
-    kh, kw, _, cout = pw.source_shape
-    out_dtype = out_dtype or x.dtype
-    if impl == "auto":
-        b, h, w = x.shape[0], x.shape[1], x.shape[2]
-        ho, _ = _out_size_and_pads(h, kh, stride, padding)
-        wo, _ = _out_size_and_pads(w, kw, stride, padding)
-        impl, sel_blocks, source = select_impl(
-            b * ho * wo,
-            pw.shape[0],
-            pw.shape[1],
-            fmt_name=pw.fmt_name,
-            nibble=pw.nibble,
-            stacked=pw.codes.ndim != 2,
-            devices=mesh_devices(pw.codes),
+    with jax.named_scope("elp_bsd_conv"):
+        if pw.source_shape is None or len(pw.source_shape) != 4:
+            raise ValueError("quantized_conv2d needs a pack_conv_weight-packed weight")
+        kh, kw, _, cout = pw.source_shape
+        out_dtype = out_dtype or x.dtype
+        if impl == "auto":
+            b, h, w = x.shape[0], x.shape[1], x.shape[2]
+            ho, _ = _out_size_and_pads(h, kh, stride, padding)
+            wo, _ = _out_size_and_pads(w, kw, stride, padding)
+            impl, sel_blocks, source = select_impl(
+                b * ho * wo,
+                pw.shape[0],
+                pw.shape[1],
+                fmt_name=pw.fmt_name,
+                nibble=pw.nibble,
+                stacked=pw.codes.ndim != 2,
+                devices=mesh_devices(pw.codes),
+            )
+            if source == "heuristic":
+                # Interim heuristic (no measurement for this shape): XLA.
+                impl = "xla"
+            elif sel_blocks is not None and (block_sizes is None or block_sizes == "auto"):
+                block_sizes = sel_blocks
+        if impl == "xla":
+            out = lax.conv_general_dilated(
+                x.astype(F32),
+                dequantize_nd(pw),
+                window_strides=(stride, stride),
+                padding=padding,
+                dimension_numbers=("NHWC", "HWIO", "NHWC"),
+            )
+            return out.astype(out_dtype)
+        if impl not in ("pallas", "pallas_fused"):
+            raise ValueError(f"unknown impl {impl!r}")
+        patches = extract_patches(x.astype(F32), kh, kw, stride=stride, padding=padding)
+        return quantized_matmul(
+            patches,
+            pw,
+            impl=impl,
+            block_m=block_m,
+            block_n=block_n,
+            block_k=block_k,
+            block_sizes=block_sizes,
+            out_dtype=out_dtype,
+            interpret=interpret,
         )
-        if source == "heuristic":
-            # Interim heuristic (no measurement for this shape): XLA.
-            impl = "xla"
-        elif sel_blocks is not None and (block_sizes is None or block_sizes == "auto"):
-            block_sizes = sel_blocks
-    if impl == "xla":
-        out = lax.conv_general_dilated(
-            x.astype(F32),
-            dequantize_nd(pw),
-            window_strides=(stride, stride),
-            padding=padding,
-            dimension_numbers=("NHWC", "HWIO", "NHWC"),
-        )
-        return out.astype(out_dtype)
-    if impl not in ("pallas", "pallas_fused"):
-        raise ValueError(f"unknown impl {impl!r}")
-    patches = extract_patches(x.astype(F32), kh, kw, stride=stride, padding=padding)
-    return quantized_matmul(
-        patches,
-        pw,
-        impl=impl,
-        block_m=block_m,
-        block_n=block_n,
-        block_k=block_k,
-        block_sizes=block_sizes,
-        out_dtype=out_dtype,
-        interpret=interpret,
-    )

@@ -239,26 +239,36 @@ def packed_tree_bytes(tree, *, packed_only: bool = False) -> int:
     return total
 
 
+# Code-to-weight decode outside a kernel runs under this scope in the
+# device trace; the Pallas kernels decode inside ``elp_bsd_matmul``.
+DECODE_SCOPE = "elp_bsd_decode"
+
+
 def dequantize(pw: PackedWeight) -> Array:
     """Decode a PackedWeight back to float32 ``[..., K, N]`` (XLA path)."""
-    codes = kref.unpack_nibbles_k(pw.codes) if pw.nibble else pw.codes
-    w = kref.decode_values(codes, pw.fmt) * pw.sf
-    return w[..., : pw.shape[0], : pw.shape[1]]
+    with jax.named_scope(DECODE_SCOPE):
+        codes = kref.unpack_nibbles_k(pw.codes) if pw.nibble else pw.codes
+        w = kref.decode_values(codes, pw.fmt) * pw.sf
+        return w[..., : pw.shape[0], : pw.shape[1]]
 
 
 def dequantize_shift_add(pw: PackedWeight) -> Array:
     """Decode via the shift-add decomposition — bit-identical to
     :func:`dequantize`, fewer vector ops (single-pass XLA form of the
     fused kernel datapath, DESIGN.md §14)."""
-    codes = kref.unpack_nibbles_k(pw.codes) if pw.nibble else pw.codes
-    w = kref.decode_values_shift_add(codes, pw.fmt) * pw.sf
-    return w[..., : pw.shape[0], : pw.shape[1]]
+    with jax.named_scope(DECODE_SCOPE):
+        codes = kref.unpack_nibbles_k(pw.codes) if pw.nibble else pw.codes
+        w = kref.decode_values_shift_add(codes, pw.fmt) * pw.sf
+        return w[..., : pw.shape[0], : pw.shape[1]]
 
 
 def dequantize_nd(pw: PackedWeight) -> Array:
     """Decode to the source layout (conv ``[kh, kw, cin, cout]``, etc.)."""
     w = dequantize(pw)
-    return w.reshape(pw.source_shape) if pw.source_shape is not None else w
+    if pw.source_shape is None:
+        return w
+    with jax.named_scope(DECODE_SCOPE):
+        return w.reshape(pw.source_shape)
 
 
 def dequantize_tree(tree):
@@ -369,7 +379,8 @@ def quantized_matmul(x: Array, pw: PackedWeight, *, impl: str = "pallas", **kw) 
     """
     if impl == "auto":
         impl = _FORCED_IMPL.get() or impl
-    return _quantized_matmul(x, pw, impl=impl, **kw)
+    with jax.named_scope("elp_bsd_matmul"):
+        return _quantized_matmul(x, pw, impl=impl, **kw)
 
 
 @functools.partial(

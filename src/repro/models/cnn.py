@@ -197,28 +197,29 @@ def forward(
     x = q(x, "input")
     for l in spec.layers:
         if isinstance(l, Conv):
-            w = params[f"conv{idx}_w"]
-            if isinstance(w, PackedWeight):
-                x = quantized_conv2d(
-                    x.astype(F32),
-                    w,
-                    stride=l.stride,
-                    padding="SAME",
-                    impl=impl,
-                    block_sizes=block_sizes,
-                    interpret=interpret,
-                    out_dtype=F32,
-                )
-            else:
-                x = jax.lax.conv_general_dilated(
-                    x.astype(F32),
-                    w.astype(F32),
-                    window_strides=(l.stride, l.stride),
-                    padding="SAME",
-                    dimension_numbers=("NHWC", "HWIO", "NHWC"),
-                )
-            x = x + params[f"conv{idx}_b"].astype(F32)
-            x = q(jax.nn.relu(x), f"conv{idx}")
+            with jax.named_scope(f"conv{idx}"):
+                w = params[f"conv{idx}_w"]
+                if isinstance(w, PackedWeight):
+                    x = quantized_conv2d(
+                        x.astype(F32),
+                        w,
+                        stride=l.stride,
+                        padding="SAME",
+                        impl=impl,
+                        block_sizes=block_sizes,
+                        interpret=interpret,
+                        out_dtype=F32,
+                    )
+                else:
+                    x = jax.lax.conv_general_dilated(
+                        x.astype(F32),
+                        w.astype(F32),
+                        window_strides=(l.stride, l.stride),
+                        padding="SAME",
+                        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+                    )
+                x = x + params[f"conv{idx}_b"].astype(F32)
+                x = q(jax.nn.relu(x), f"conv{idx}")
             idx += 1
         elif isinstance(l, Pool):
             x = jax.lax.reduce_window(
@@ -228,22 +229,23 @@ def forward(
             if not flat:
                 x = x.reshape(x.shape[0], -1)
                 flat = True
-            w = params[f"fc{idx}_w"]
-            if isinstance(w, PackedWeight):
-                x = quantized_matmul(
-                    x.astype(F32),
-                    w,
-                    impl=impl,
-                    block_sizes=block_sizes,
-                    interpret=interpret,
-                    out_dtype=F32,
-                )
-            else:
-                x = jnp.dot(x, w.astype(F32))
-            x = x + params[f"fc{idx}_b"].astype(F32)
+            with jax.named_scope(f"fc{idx}"):
+                w = params[f"fc{idx}_w"]
+                if isinstance(w, PackedWeight):
+                    x = quantized_matmul(
+                        x.astype(F32),
+                        w,
+                        impl=impl,
+                        block_sizes=block_sizes,
+                        interpret=interpret,
+                        out_dtype=F32,
+                    )
+                else:
+                    x = jnp.dot(x, w.astype(F32))
+                x = x + params[f"fc{idx}_b"].astype(F32)
+                if idx + 1 < n_layers:
+                    x = q(jax.nn.relu(x), f"fc{idx}")
             idx += 1
-            if idx < n_layers:
-                x = q(jax.nn.relu(x), f"fc{idx - 1}")
     return x
 
 

@@ -216,17 +216,18 @@ def mlp_apply(p: dict[str, Array], x: Array, kind: str, acts: dict | None = None
     ``acts`` (calibration collection, DESIGN.md §6) records the hidden
     activation entering ``w2`` under ``"ffn_hidden"``.
     """
-    if kind == "swiglu":
-        h = jax.nn.silu(matmul(x, p["w1"])) * matmul(x, p["w3"])
-    elif kind == "geglu":
-        h = jax.nn.gelu(matmul(x, p["w1"])) * matmul(x, p["w3"])
-    elif kind == "gelu":
-        h = jax.nn.gelu(matmul(x, p["w1"]))
-    else:
-        raise ValueError(kind)
-    if acts is not None:
-        acts["ffn_hidden"] = h
-    return matmul(h, p["w2"])
+    with jax.named_scope("mlp"):
+        if kind == "swiglu":
+            h = jax.nn.silu(matmul(x, p["w1"])) * matmul(x, p["w3"])
+        elif kind == "geglu":
+            h = jax.nn.gelu(matmul(x, p["w1"])) * matmul(x, p["w3"])
+        elif kind == "gelu":
+            h = jax.nn.gelu(matmul(x, p["w1"]))
+        else:
+            raise ValueError(kind)
+        if acts is not None:
+            acts["ffn_hidden"] = h
+        return matmul(h, p["w2"])
 
 
 def matmul(x: Array, w) -> Array:

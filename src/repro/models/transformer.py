@@ -174,21 +174,52 @@ def _attention(
     """
     b, s, d = x.shape
     h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
-    kv_src = x if kv_override is None else kv_override
-    q = matmul(x, lp[prefix + "q"]).reshape(b, s, h, hd)
-    k = matmul(kv_src, lp[prefix + "k"]).reshape(b, kv_src.shape[1], kv, hd)
-    v = matmul(kv_src, lp[prefix + "v"]).reshape(b, kv_src.shape[1], kv, hd)
-    if cfg.qk_norm and prefix == "w":
-        q = rms_norm(q, lp["qnorm"])
-        k = rms_norm(k, lp["knorm"])
-    if rope is not None and kv_override is None:
-        cos_q, sin_q, cos_k, sin_k = rope
-        q = apply_rope(q, cos_q, sin_q)
-        k = apply_rope(k, cos_k, sin_k)
-    if tap_kv and acts is not None:
-        acts["k_cache"] = k
-        acts["v_cache"] = v
+    with jax.named_scope("attn"):
+        kv_src = x if kv_override is None else kv_override
+        q = matmul(x, lp[prefix + "q"]).reshape(b, s, h, hd)
+        k = matmul(kv_src, lp[prefix + "k"]).reshape(b, kv_src.shape[1], kv, hd)
+        v = matmul(kv_src, lp[prefix + "v"]).reshape(b, kv_src.shape[1], kv, hd)
+        if cfg.qk_norm and prefix == "w":
+            q = rms_norm(q, lp["qnorm"])
+            k = rms_norm(k, lp["knorm"])
+        if rope is not None and kv_override is None:
+            cos_q, sin_q, cos_k, sin_k = rope
+            q = apply_rope(q, cos_q, sin_q)
+            k = apply_rope(k, cos_k, sin_k)
+        if tap_kv and acts is not None:
+            acts["k_cache"] = k
+            acts["v_cache"] = v
+        with jax.named_scope("attn_core"):
+            out, new_cache = _attend(
+                q, k, v, cfg, x.dtype, causal=causal, window=window, kv_cache=kv_cache,
+                kv_layout=kv_layout, cache_pos=cache_pos, pctx=pctx,
+            )
+        mix = out.reshape(b, s, h * hd)
+        if acts is not None:
+            acts["attn_mix"] = mix
+        return matmul(mix, lp[prefix + "o"]), new_cache
 
+
+def _attend(
+    q: Array,
+    k: Array,
+    v: Array,
+    cfg: ArchConfig,
+    dtype,
+    *,
+    causal: bool,
+    window: int,
+    kv_cache: tuple[Array, ...] | None,
+    kv_layout: str,
+    cache_pos: Array | None,
+    pctx: ParallelCtx | None,
+) -> tuple[Array, tuple[Array, ...] | None]:
+    """The core of :func:`_attention` between its projections: write this
+    step's k/v into the cache (if any), then attend ``q`` over the
+    cache's (or the run's own) k/v. Returns ``(out[b, s, h, hd],
+    updated cache tuple or None)``."""
+    s = q.shape[1]
+    h, kv = cfg.n_heads, cfg.n_kv_heads
     new_cache = None
     if kv_cache is not None and kv_layout == "quant":
         # int8-quantized cache (per-token-head scales)
@@ -210,9 +241,9 @@ def _attention(
             out = flash_decode_attention(
                 q, ck, cv, cache_pos, pctx=pctx, window=window, ks=cks, vs=cvs
             )
-            return matmul(out.reshape(b, s, h * hd), lp[prefix + "o"]), new_cache
-        k = _cache_dq(ck, cks, x.dtype)
-        v = _cache_dq(cv, cvs, x.dtype)
+            return out, new_cache
+        k = _cache_dq(ck, cks, dtype)
+        v = _cache_dq(cv, cvs, dtype)
     elif kv_cache is not None and kv_layout in ("paged", "paged_static"):
         # paged pool: write this step's k/v through the page table, then
         # gather the rows' logical views back for the read. Shared
@@ -232,8 +263,8 @@ def _attention(
         k = _paged_view(ck, pages)
         v = _paged_view(cv, pages)
         if kv_layout == "paged_static":
-            k = _static_dq(k, ksc, x.dtype)
-            v = _static_dq(v, vsc, x.dtype)
+            k = _static_dq(k, ksc, dtype)
+            v = _static_dq(v, vsc, dtype)
     elif kv_cache is not None and kv_layout == "static":
         # calibrated int8 cache: per-(layer, head) scales chosen offline
         # — quantize-on-write with ZERO runtime range reductions, the
@@ -242,8 +273,8 @@ def _attention(
         ck = _cache_set(ck, _static_q(k, ksc), cache_pos)
         cv = _cache_set(cv, _static_q(v, vsc), cache_pos)
         new_cache = (ck, cv)
-        k = _static_dq(ck, ksc, x.dtype)
-        v = _static_dq(cv, vsc, x.dtype)
+        k = _static_dq(ck, ksc, dtype)
+        v = _static_dq(cv, vsc, dtype)
     elif kv_cache is not None:
         ck, cv = kv_cache
         ck = _cache_set(ck, k, cache_pos)
@@ -263,7 +294,7 @@ def _attention(
         from repro.models.flash_decode import flash_decode_attention
 
         out = flash_decode_attention(q, k, v, cache_pos, pctx=pctx, window=window)
-        return matmul(out.reshape(b, s, h * hd), lp[prefix + "o"]), new_cache
+        return out, new_cache
     kf = repeat_kv(k, h // kv)
     vf = repeat_kv(v, h // kv)
     if decode:
@@ -276,10 +307,7 @@ def _attention(
         )
     else:
         out = attention_dot(q, kf, vf, causal=causal, window=window, q_offset=q_offset)
-    mix = out.reshape(b, s, h * hd)
-    if acts is not None:
-        acts["attn_mix"] = mix
-    return matmul(mix, lp[prefix + "o"]), new_cache
+    return out, new_cache
 
 
 def block_apply(
@@ -506,9 +534,10 @@ def embed_tokens(params, cfg: ArchConfig, tokens: Array, frontend: Array | None)
 
 
 def unembed(params, cfg: ArchConfig, x: Array) -> Array:
-    x = rms_norm(x, params["final_norm"])
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    return jnp.dot(x, head.astype(x.dtype), preferred_element_type=F32)
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["final_norm"])
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        return jnp.dot(x, head.astype(x.dtype), preferred_element_type=F32)
 
 
 def forward(

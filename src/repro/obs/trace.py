@@ -21,7 +21,9 @@ opt-in while the metrics registry can run always-on.
 :class:`ProfileHook` is the optional deep-dive: capture a
 ``jax.profiler`` trace around the first N decode dispatches of a run,
 so a slow step found in the histograms can be cross-examined at the
-XLA level without instrumenting anything by hand.
+XLA level without instrumenting anything by hand. :func:`span` puts the
+engine's own host work into that trace (``serve.step``, ``serve.admit``,
+``serve.decode``), beside the device ops it dispatches.
 """
 from __future__ import annotations
 
@@ -29,7 +31,17 @@ import json
 import time
 from typing import Any, IO
 
-__all__ = ["TraceLog", "ProfileHook"]
+__all__ = ["TraceLog", "ProfileHook", "span"]
+
+
+def span(name: str, **attrs: Any):
+    """A host span in the ``jax.profiler`` trace, on the device trace's
+    clock: a ``TraceAnnotation`` carrying ``attrs``. It records only
+    while a profiler captures (a check of a flag otherwise), so callers
+    open it unconditionally."""
+    import jax
+
+    return jax.profiler.TraceAnnotation(name, **attrs)
 
 
 class TraceLog:

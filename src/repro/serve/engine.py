@@ -91,7 +91,7 @@ from repro.core.energy import lm_token_energy
 from repro.models import ModelApi, get_model
 from repro.models.context import ParallelCtx
 from repro.obs.metrics import NULL_REGISTRY, Registry
-from repro.obs.trace import ProfileHook, TraceLog
+from repro.obs.trace import ProfileHook, TraceLog, span
 from repro.runtime import sharding as shr
 from repro.runtime.straggler import StragglerMonitor
 from repro.serve.scheduler import Request, SlotScheduler
@@ -1014,162 +1014,173 @@ class ServeEngine:
         pipeline stays full. A step with any sampled (keyed) request
         falls back to fetching logits.
         """
-        progressed = False
-        for slot, req in self._sched.ready():
-            req.t_admit = time.perf_counter()
+        with span("serve.step"):
+            progressed = False
+            for slot, req in self._sched.ready():
+                with span("serve.admit", rid=req.rid, slot=slot, prompt_len=int(req.prompt.size)):
+                    self._admit(slot, req)
+                progressed = True
+
+            self._m_queue.set(self._sched.queued)
+            self._m_live.set(len(self._sched.live))
             if self._paged:
-                # page-table admission (DESIGN.md §12): acquire the
-                # matched shared-prefix pages, allocate the rest, and
-                # prefill only the unmatched SUFFIX as one causal run
-                # starting past the shared tokens. The batch-1 pages row
-                # scopes the writes; no other slot's pages appear in it.
-                n_shared = self._pager.admit(slot, req.prompt)
-                self._m_prefix_hits.inc(n_shared // self.setup.page_size)
-                # to_device COPIES (the blessed crossing): the
-                # allocator mutates `table` in place on the next
-                # admit/release while the async dispatch may not have
-                # read this view yet
-                row = self._pager.to_device(slot)
-                pos0 = jnp.asarray([n_shared], jnp.int32)
+                self._m_pages_used.set(self._pager.pages_used)
+                self._m_pages_shared.set(self._pager.pages_shared)
+            live = self._sched.live
+            if live:
+                with span("serve.decode", live=len(live)):
+                    if self.spec_k:
+                        self._spec_round(live)
+                    else:
+                        self._decode_live(live)
+                progressed = True
+            self.steps += 1
+            return progressed
+
+    def _admit(self, slot: int, req: Request) -> None:
+        """Admit one queued request into ``slot``: prefill its prompt
+        (the unmatched suffix on a paged cache), emit its first token."""
+        req.t_admit = time.perf_counter()
+        if self._paged:
+            # page-table admission (DESIGN.md §12): acquire the
+            # matched shared-prefix pages, allocate the rest, and
+            # prefill only the unmatched SUFFIX as one causal run
+            # starting past the shared tokens. The batch-1 pages row
+            # scopes the writes; no other slot's pages appear in it.
+            n_shared = self._pager.admit(slot, req.prompt)
+            self._m_prefix_hits.inc(n_shared // self.setup.page_size)
+            # to_device COPIES (the blessed crossing): the
+            # allocator mutates `table` in place on the next
+            # admit/release while the async dispatch may not have
+            # read this view yet
+            row = self._pager.to_device(slot)
+            pos0 = jnp.asarray([n_shared], jnp.int32)
+            # repro: noqa[R001] prompt is frozen read-only at submit
+            suffix = jnp.asarray(req.prompt[None, n_shared:])
+            logits, newc = self._prefill(
+                self.params, suffix, {**self._cache, "pages": row}, pos0
+            )
+            self._cache = {**newc, "pages": self._pager.to_device()}
+            self._pager.register(slot, req.prompt)
+        else:
+            logits, self._cache = self._prefill(
+                self.params,
                 # repro: noqa[R001] prompt is frozen read-only at submit
-                suffix = jnp.asarray(req.prompt[None, n_shared:])
-                logits, newc = self._prefill(
-                    self.params, suffix, {**self._cache, "pages": row}, pos0
+                jnp.asarray(req.prompt[None]),
+                self._cache,
+                jnp.int32(slot),
+            )
+        self._prefills += 1
+        if self.spec_k and self.spec_draft == "model":
+            # the draft tier keeps its own cache in lockstep: same
+            # prompt, same slot. Its prefill logits are discarded —
+            # every EMITTED token, including the prefill token below,
+            # comes from the verify tier, which is what makes the
+            # output token-identical to non-speculative serving.
+            if self._paged:
+                _, newdc = self._draft_prefill(
+                    self.draft_params,
+                    suffix,
+                    {**self._draft_cache, "pages": row},
+                    pos0,
                 )
-                self._cache = {**newc, "pages": self._pager.to_device()}
-                self._pager.register(slot, req.prompt)
+                self._draft_cache = {**newdc, "pages": self._pager.to_device()}
             else:
-                logits, self._cache = self._prefill(
-                    self.params,
+                _, self._draft_cache = self._draft_prefill(
+                    self.draft_params,
                     # repro: noqa[R001] prompt is frozen read-only at submit
                     jnp.asarray(req.prompt[None]),
-                    self._cache,
+                    self._draft_cache,
                     jnp.int32(slot),
                 )
-            self._prefills += 1
-            if self.spec_k and self.spec_draft == "model":
-                # the draft tier keeps its own cache in lockstep: same
-                # prompt, same slot. Its prefill logits are discarded —
-                # every EMITTED token, including the prefill token below,
-                # comes from the verify tier, which is what makes the
-                # output token-identical to non-speculative serving.
-                if self._paged:
-                    _, newdc = self._draft_prefill(
-                        self.draft_params,
-                        suffix,
-                        {**self._draft_cache, "pages": row},
-                        pos0,
-                    )
-                    self._draft_cache = {**newdc, "pages": self._pager.to_device()}
-                else:
-                    _, self._draft_cache = self._draft_prefill(
-                        self.draft_params,
-                        # repro: noqa[R001] prompt is frozen read-only at submit
-                        jnp.asarray(req.prompt[None]),
-                        self._draft_cache,
-                        jnp.int32(slot),
-                    )
-            if req.key is None and self.spec_k and self.spec_draft == "ngram":
-                # the lookup drafter chains from the pending token's
-                # VALUE, so admission syncs it (one scalar fetch riding
-                # the prefill dispatch it already paid for)
-                # repro: noqa[R004] deliberate: ngram drafting needs the token value
-                first = int(np.asarray(jnp.argmax(logits, axis=-1))[0])
-                req.out.append(first)
-                self._pending[slot] = first
-            elif req.key is None:
-                first = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [1], device
-                req.out.append((first, 0))
-                self._tok_dev = self._tok_dev.at[slot, 0].set(first[0])
-            else:
-                # repro: noqa[R004] deliberate: sampling draws on host (§9)
-                tok = self._select(req, np.asarray(logits)[0])
-                req.out.append(tok)
-                self._tok_dev = self._tok_dev.at[slot, 0].set(tok)
-            self._tokens_generated += 1
-            self._pos[slot] = req.prompt.size
-            # the admission prefill emits the request's FIRST token, so
-            # this dispatch-clocked timestamp is its TTFT sample (the
-            # same sync-point clocking the straggler monitor uses)
-            req.t_first = time.perf_counter()
-            self._m_ttft.record(req.t_first - req.t_submit)
-            self._m_prefill.record(req.t_first - req.t_admit)
-            self._m_tokens.inc()
-            self._m_energy.inc(self.energy["total_nj"])
-            if self.trace is not None:
-                self.trace.event(
-                    "admit",
-                    req.rid,
-                    slot=slot,
-                    prompt_len=int(req.prompt.size),
-                    prefill_s=req.t_first - req.t_admit,
-                    ttft_s=req.t_first - req.t_submit,
-                )
-            self._maybe_finish(slot, req)
-            progressed = True
+        if req.key is None and self.spec_k and self.spec_draft == "ngram":
+            # the lookup drafter chains from the pending token's
+            # VALUE, so admission syncs it (one scalar fetch riding
+            # the prefill dispatch it already paid for)
+            # repro: noqa[R004] deliberate: ngram drafting needs the token value
+            first = int(np.asarray(jnp.argmax(logits, axis=-1))[0])
+            req.out.append(first)
+            self._pending[slot] = first
+        elif req.key is None:
+            first = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [1], device
+            req.out.append((first, 0))
+            self._tok_dev = self._tok_dev.at[slot, 0].set(first[0])
+        else:
+            # repro: noqa[R004] deliberate: sampling draws on host (§9)
+            tok = self._select(req, np.asarray(logits)[0])
+            req.out.append(tok)
+            self._tok_dev = self._tok_dev.at[slot, 0].set(tok)
+        self._tokens_generated += 1
+        self._pos[slot] = req.prompt.size
+        # the admission prefill emits the request's FIRST token, so
+        # this dispatch-clocked timestamp is its TTFT sample (the
+        # same sync-point clocking the straggler monitor uses)
+        req.t_first = time.perf_counter()
+        self._m_ttft.record(req.t_first - req.t_submit)
+        self._m_prefill.record(req.t_first - req.t_admit)
+        self._m_tokens.inc()
+        self._m_energy.inc(self.energy["total_nj"])
+        if self.trace is not None:
+            self.trace.event(
+                "admit",
+                req.rid,
+                slot=slot,
+                prompt_len=int(req.prompt.size),
+                prefill_s=req.t_first - req.t_admit,
+                ttft_s=req.t_first - req.t_submit,
+            )
+        self._maybe_finish(slot, req)
 
-        self._m_queue.set(self._sched.queued)
-        self._m_live.set(len(self._sched.live))
-        if self._paged:
-            self._m_pages_used.set(self._pager.pages_used)
-            self._m_pages_shared.set(self._pager.pages_shared)
-        live = self._sched.live
-        if live and self.spec_k:
-            self._spec_round(live)
-            self.steps += 1
-            return True
-        if live:
-            # hand the dispatch its OWN copy of the position vector:
-            # jnp.asarray can zero-copy-alias a host numpy buffer on
-            # CPU, and self._pos is mutated in place below while the
-            # (async) decode may not have read it yet
-            pos = jnp.asarray(np.array(self._pos))
-            n_live = len(live)  # snapshot: _maybe_finish pops from live
-            cache_in = self._dispatch_cache()
-            t0 = time.perf_counter()
-            if all(r.key is None for r in live.values()):
-                nxt, self._cache = self._decode_greedy(
-                    self.params, self._tok_dev, cache_in, pos
-                )
-                self._tok_dev = nxt
-                # dispatch-clocked: once the device queue back-pressures,
-                # dispatch wall-clock tracks true step time
-                dt = time.perf_counter() - t0
-                self.monitor.record(dt)
-                for slot, req in list(live.items()):
-                    req.out.append((nxt, slot))
-                    self._tokens_generated += 1
-                    self._m_itl.record(dt)
-                    self._pos[slot] += 1
-                    self._maybe_finish(slot, req)
-            else:
-                logits, self._cache = self._decode(
-                    self.params, self._tok_dev, cache_in, pos
-                )
-                # repro: noqa[R004] deliberate: sampled decode fetches logits (§9)
-                logits = np.asarray(jax.block_until_ready(logits))
-                dt = time.perf_counter() - t0
-                self.monitor.record(dt)
-                toks = np.zeros(self._sched.n_slots, np.int32)
-                for slot, req in list(live.items()):
-                    tok = self._select(req, logits[slot, -1])
-                    req.out.append(tok)
-                    toks[slot] = tok
-                    self._tokens_generated += 1
-                    self._m_itl.record(dt)
-                    self._pos[slot] += 1
-                    self._maybe_finish(slot, req)
-                self._tok_dev = jnp.asarray(toks[:, None])
-            self._m_tokens.inc(n_live)
-            self._m_energy.inc(self.energy["total_nj"] * n_live)
-            if self.profile is not None:
-                self.profile.step()
-            if self.trace is not None:
-                self.trace.event("decode", None, live=n_live, dt_s=dt)
-            self._decode_steps += 1
-            progressed = True
-        self.steps += 1
-        return progressed
+    def _decode_live(self, live: dict[int, Request]) -> None:
+        """One decode step for every live slot (no speculation)."""
+        # hand the dispatch its OWN copy of the position vector:
+        # jnp.asarray can zero-copy-alias a host numpy buffer on
+        # CPU, and self._pos is mutated in place below while the
+        # (async) decode may not have read it yet
+        pos = jnp.asarray(np.array(self._pos))
+        n_live = len(live)  # snapshot: _maybe_finish pops from live
+        cache_in = self._dispatch_cache()
+        t0 = time.perf_counter()
+        if all(r.key is None for r in live.values()):
+            nxt, self._cache = self._decode_greedy(
+                self.params, self._tok_dev, cache_in, pos
+            )
+            self._tok_dev = nxt
+            # dispatch-clocked: once the device queue back-pressures,
+            # dispatch wall-clock tracks true step time
+            dt = time.perf_counter() - t0
+            self.monitor.record(dt)
+            for slot, req in list(live.items()):
+                req.out.append((nxt, slot))
+                self._tokens_generated += 1
+                self._m_itl.record(dt)
+                self._pos[slot] += 1
+                self._maybe_finish(slot, req)
+        else:
+            logits, self._cache = self._decode(
+                self.params, self._tok_dev, cache_in, pos
+            )
+            # repro: noqa[R004] deliberate: sampled decode fetches logits (§9)
+            logits = np.asarray(jax.block_until_ready(logits))
+            dt = time.perf_counter() - t0
+            self.monitor.record(dt)
+            toks = np.zeros(self._sched.n_slots, np.int32)
+            for slot, req in list(live.items()):
+                tok = self._select(req, logits[slot, -1])
+                req.out.append(tok)
+                toks[slot] = tok
+                self._tokens_generated += 1
+                self._m_itl.record(dt)
+                self._pos[slot] += 1
+                self._maybe_finish(slot, req)
+            self._tok_dev = jnp.asarray(toks[:, None])
+        self._m_tokens.inc(n_live)
+        self._m_energy.inc(self.energy["total_nj"] * n_live)
+        if self.profile is not None:
+            self.profile.step()
+        if self.trace is not None:
+            self.trace.event("decode", None, live=n_live, dt_s=dt)
+        self._decode_steps += 1
 
     def run(self) -> None:
         """Drive :meth:`step` until queue and slots are empty."""
