@@ -5,7 +5,8 @@ This is the TPU adaptation of the paper's shift-based MAC unit
 each); per (block_m, block_n, block_k) tile the kernel
 
   1. streams a code block into VMEM,
-  2. expands codes to float32 *in VMEM* via shift-add decode
+  2. expands codes, a slab of K rows at a time, to float32 *in VMEM*
+     via shift-add decode
      (:func:`repro.kernels.ref.decode_values_shift_add`) — per digit:
      extract sign/index fields, map index → shift count (an affine
      ``a + b·index`` for arithmetic-progression LUTs, a ≤ 8-entry
@@ -13,8 +14,8 @@ each); per (block_m, block_n, block_k) tile the kernel
      in one integer write of the float32 sign+exponent fields (the VPU
      analogue of the barrel shift; bit-identical to the select-chain
      decoder, DESIGN.md §14),
-  3. feeds the decoded tile straight to the MXU
-     (``jnp.dot(..., preferred_element_type=float32)``),
+  3. feeds the decoded slab straight to the MXU, in bf16 where that is
+     exact (``jnp.dot(..., preferred_element_type=float32)``),
   4. accumulates in a float32 VMEM scratch across the K grid dimension.
 
 The HBM side therefore moves 2–4x fewer weight bytes than a bf16
@@ -26,23 +27,100 @@ Storage modes:
   * ``nibble``: FORMAT_A (4-bit) packed two-per-byte along K
     (``[K//2, N]``; low nibble = even row). Halves HBM bytes again.
 
-Block shapes default to MXU-aligned 128 multiples; the K block for
-nibble mode must be even. Validated in ``interpret=True`` on CPU against
-:mod:`repro.kernels.ref` (this container has no TPU).
+Block shapes default to 128 multiples; the K block for nibble mode
+must be even. On a TPU, ``quantized_matmul(impl="auto")`` sizes them
+from the shape at an autotune-cache miss
+(:func:`repro.kernels.ops.tpu_blocks`): code panels of about 1 MB per
+grid step, decoded ``SLAB_K`` rows of K at a time inside the step.
+The dot takes bf16 operands where that is exact
+(:func:`mxu_operand_dtype`), float32 otherwise; it accumulates in
+float32 either way. Validated in ``interpret=True`` on CPU against
+:mod:`repro.kernels.ref`.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.elp_bsd import ElpBsdFormat
-from repro.kernels.ref import decode_values_shift_add, unpack_nibbles_k
+from repro.core.elp_bsd import ElpBsdFormat, decode_codes
+from repro.kernels.ref import decode_values_shift_add
 
 Array = jax.Array
+
+# K rows decoded at a time inside a grid step: bounds the decode's VMEM
+# temporaries to a few [SLAB_K, block_n] tiles whatever block_k is.
+SLAB_K = 512
+
+
+@functools.lru_cache(maxsize=None)
+def decodes_exactly_to_bf16(fmt: ElpBsdFormat) -> bool:
+    """Whether every code of ``fmt`` decodes to a value bf16 holds exactly."""
+    vals = decode_codes(np.arange(1 << fmt.bits_per_weight), fmt)
+    return bool(np.all(vals.astype(jnp.bfloat16).astype(np.float64) == vals))
+
+
+def mxu_operand_dtype(fmt: ElpBsdFormat, x_dtype) -> jnp.dtype:
+    """The dtype both operands of the kernels' dot take.
+
+    bf16 where that is exact: ``x`` arrives in bf16 and every level of
+    ``fmt`` is a bf16 value, so every product is the float32 path's and
+    only the order of the float32 sums differs. Otherwise float32.
+    """
+    if jnp.dtype(x_dtype) == jnp.bfloat16 and decodes_exactly_to_bf16(fmt):
+        return jnp.dtype(jnp.bfloat16)
+    return jnp.dtype(jnp.float32)
+
+
+def slab_k(block_k: int) -> int:
+    """K rows decoded at a time in a ``block_k`` panel: ``SLAB_K``, or the
+    largest power of two below it that divides ``block_k``."""
+    return math.gcd(block_k, SLAB_K)
+
+
+def nibble_plane_order(x: Array, block_k: int) -> Array:
+    """``x[M, K]`` with each slab's even K columns first, then its odd
+    ones: the order in which :func:`accumulate_panel` stacks a nibble
+    slab's low and high planes."""
+    m, k = x.shape
+    slab = slab_k(block_k)
+    return x.reshape(m, k // slab, slab // 2, 2).swapaxes(2, 3).reshape(m, k)
+
+
+def accumulate_panel(x_ref, c_ref, acc_ref, *, fmt: ElpBsdFormat, nibble: bool):
+    """``acc_ref += x_ref @ decode(c_ref)`` for one code panel, decoded a
+    slab of K rows at a time, each slab straight into the dot.
+
+    Nibble codes unpack as two planes, the low nibbles (even K rows)
+    stacked above the high ones (odd K rows), against ``x`` in
+    :func:`nibble_plane_order`: the stack is sublane-aligned, where
+    :func:`repro.kernels.ref.unpack_nibbles_k`'s row interleave costs a
+    relayout of every code (3.5 times the kernel's time at qwen3-8b's
+    ``w1`` on a v5e, M = 16).
+    """
+    block_k = x_ref.shape[1]
+    slab = slab_k(block_k)
+    rows = slab // 2 if nibble else slab
+    operand = mxu_operand_dtype(fmt, x_ref.dtype)
+
+    def slab_dot(s):
+        codes = c_ref[pl.ds(pl.multiple_of(s * rows, rows), rows), :]
+        if nibble:
+            wide = codes.astype(jnp.int32)  # no 8-bit vector shift on v5e
+            codes = jnp.concatenate([wide & 0x0F, (wide >> 4) & 0x0F], axis=0)
+        w = decode_values_shift_add(codes, fmt).astype(operand)  # unscaled
+        x = x_ref[:, pl.ds(pl.multiple_of(s * slab, slab), slab)].astype(operand)
+        acc_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
+
+    if block_k == slab:
+        slab_dot(0)
+    else:
+        pl.loop(0, block_k // slab)(slab_dot)
 
 
 def _mm_kernel(x_ref, c_ref, sf_ref, o_ref, acc_ref, *, fmt: ElpBsdFormat, nibble: bool, n_k: int):
@@ -53,12 +131,7 @@ def _mm_kernel(x_ref, c_ref, sf_ref, o_ref, acc_ref, *, fmt: ElpBsdFormat, nibbl
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    codes = c_ref[...]
-    if nibble:
-        codes = unpack_nibbles_k(codes)
-    w = decode_values_shift_add(codes, fmt)  # [bk, bn] float32, unscaled
-    x = x_ref[...].astype(jnp.float32)
-    acc_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
+    accumulate_panel(x_ref, c_ref, acc_ref, fmt=fmt, nibble=nibble)
 
     @pl.when(k == n_k - 1)
     def _store():
@@ -122,6 +195,8 @@ def elp_bsd_matmul(
     out_dtype = out_dtype or x.dtype
     n_k = kdim // block_k
     grid = (m // block_m, n // block_n, n_k)
+    if nibble:
+        x = nibble_plane_order(x, block_k)
 
     return pl.pallas_call(
         functools.partial(_mm_kernel, fmt=fmt, nibble=nibble, n_k=n_k),

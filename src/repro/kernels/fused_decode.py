@@ -7,15 +7,22 @@ specializes the decode step:
 
   * the full M strip rides along in VMEM (no M grid dimension),
   * per (n, k) tile the packed codes are unpacked from their
-    VMEM-resident tiles and the level table is applied via *shift-add*
+    VMEM-resident tiles, a slab of K rows at a time, and the level
+    table is applied via *shift-add*
     (:func:`repro.kernels.ref.decode_values_shift_add`): each digit's
     ``±2^shift`` term is built by one integer construction of the
     float32 sign/exponent fields — the VPU reading of the paper's
     shift-add MAC (Sec. IV-4) — and the digit terms accumulate into the
-    weight tile, which feeds the MXU directly. No float weight tensor
-    ever exists outside the current VMEM tile,
+    weight slab, which feeds the MXU directly (bf16 operands where
+    exact, :func:`repro.kernels.elp_bsd_matmul.mxu_operand_dtype`). No
+    float weight tensor ever exists outside the current VMEM slab,
   * a float32 VMEM accumulator carries the K loop, scale applied once
     at the end.
+
+Tiles default to 128 multiples. On a TPU, ``quantized_matmul(impl=
+"auto")`` sizes them from the shape (:func:`repro.kernels.ops.tpu_blocks`):
+a grid step has a fixed cost (about 0.35 µs on a v5e), so each step
+carries a panel of about 1 MB of codes.
 
 On non-TPU backends the public entry lowers to the single-pass XLA form
 of the same datapath (see ``quantized_matmul(impl="pallas_fused")`` in
@@ -33,7 +40,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.elp_bsd import ElpBsdFormat
-from repro.kernels.ref import decode_values_shift_add, unpack_nibbles_k
+from repro.kernels.elp_bsd_matmul import accumulate_panel, nibble_plane_order
 
 Array = jax.Array
 
@@ -52,14 +59,7 @@ def _fused_kernel(
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    codes = c_ref[...]
-    if nibble:
-        codes = unpack_nibbles_k(codes)
-    # Shift-add decode in VMEM: per digit, sign/exponent-field construct
-    # the ±2^shift term and add — then one MXU dot against the M strip.
-    w = decode_values_shift_add(codes, fmt)  # [bk, bn] float32, unscaled
-    x = x_ref[...].astype(jnp.float32)
-    acc_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
+    accumulate_panel(x_ref, c_ref, acc_ref, fmt=fmt, nibble=nibble)
 
     @pl.when(k == n_k - 1)
     def _store():
@@ -125,6 +125,8 @@ def fused_decode_matmul(
     out_dtype = out_dtype or x.dtype
     n_k = kdim // block_k
     grid = (n // block_n, n_k)
+    if nibble:
+        x = nibble_plane_order(x, block_k)
 
     return pl.pallas_call(
         functools.partial(_fused_kernel, fmt=fmt, nibble=nibble, n_k=n_k),

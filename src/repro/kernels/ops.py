@@ -37,7 +37,7 @@ from repro.core.convert import convert_tensor, nibble_pack
 from repro.core.elp_bsd import ElpBsdFormat, PRESET_FORMATS
 from repro.kernels import ref as kref
 from repro.kernels.elp_bsd_matmul import elp_bsd_matmul
-from repro.kernels.fused_decode import fused_decode_matmul
+from repro.kernels.fused_decode import MAX_FUSED_M, fused_decode_matmul
 
 Array = jax.Array
 F32 = jnp.float32
@@ -299,6 +299,41 @@ def mesh_devices(x: Array) -> int:
     return 1 if mesh.empty else mesh.size
 
 
+# The TPU tile rule (DESIGN.md §14). A grid step costs about 0.35 µs on
+# a v5e whatever it holds, so each step carries about _TPU_PANEL_BYTES of
+# codes; the activation block stays under _TPU_X_BLOCK_BYTES counted at
+# 4 bytes an element, whatever x's dtype.
+_TPU_PANEL_BYTES = 1 << 20
+_TPU_X_BLOCK_BYTES = 2 << 20
+_TPU_MAX_BLOCK_N = 512
+_TPU_MAX_BLOCK_M = 512
+
+
+def _tile(dim: int, cap: int) -> int:
+    """The largest multiple of 128 up to ``cap`` that divides ``dim``
+    (rounded up to 128, which the ops wrapper pads to)."""
+    dim = -(-dim // 128) * 128
+    return max(t for t in range(128, cap + 1, 128) if dim % t == 0)
+
+
+def tpu_blocks(m: int, k: int, n: int, *, nibble: bool) -> tuple[str, tuple[int, int, int]]:
+    """``(impl, (block_m, block_n, block_k))`` for a packed matmul on TPU.
+
+    Decode-sized M (≤ ``MAX_FUSED_M``) takes the fused kernel, whose whole
+    M strip stays in VMEM unpadded; a larger M takes the tiled kernel with
+    M blocks of up to 512 rows. ``block_n`` and ``block_k`` are the
+    largest 128 multiples dividing N and K within the panel and
+    activation budgets, so the 128-aligned shapes of real layers pad
+    nothing.
+    """
+    impl = "pallas_fused" if m <= MAX_FUSED_M else "pallas"
+    block_m = m if impl == "pallas_fused" else min(-(-m // 16) * 16, _TPU_MAX_BLOCK_M)
+    block_n = _tile(n, _TPU_MAX_BLOCK_N)
+    row_bytes = block_n // 2 if nibble else block_n  # code bytes per K row
+    cap = min(_TPU_PANEL_BYTES // row_bytes, _TPU_X_BLOCK_BYTES // (4 * block_m))
+    return impl, (block_m, block_n, _tile(k, cap))
+
+
 def select_impl(
     m: int, k: int, n: int, *, fmt_name: str, nibble: bool, stacked: bool, devices: int
 ) -> tuple[str, tuple[int, int, int] | None, str]:
@@ -308,8 +343,9 @@ def select_impl(
     XLA path (``source="structural"``): the Pallas kernels take one
     ``[K, N]`` on one device, and a sharded decode must partition with
     its shards. Otherwise the autotune cache's measured winner decides
-    (``"autotuned"``, with its tuned blocks); a miss falls back to the
-    Pallas kernel on TPU and XLA elsewhere (``"heuristic"``, no blocks).
+    (``"autotuned"``, with its tuned blocks); a miss takes the kernel and
+    tiles :func:`tpu_blocks` derives from the shape on TPU, and XLA
+    elsewhere (``"heuristic"``; no blocks off TPU).
     """
     if stacked or devices > 1:
         return "xla", None, "structural"
@@ -317,7 +353,9 @@ def select_impl(
 
     sel, sel_blocks = lookup_impl(m, k, n, fmt_name=fmt_name, nibble=nibble)
     if sel is None:
-        return ("pallas" if jax.default_backend() == "tpu" else "xla"), None, "heuristic"
+        if jax.default_backend() == "tpu":
+            return (*tpu_blocks(m, k, n, nibble=nibble), "heuristic")
+        return "xla", None, "heuristic"
     return sel, tuple(sel_blocks), "autotuned"
 
 

@@ -1353,8 +1353,13 @@ class ServeEngine:
         matmul. Observability for "is the fused decode kernel actually
         on?" — keyed ``MxKxN|fmt|layout``, each value recording the
         impl, how it was chosen (``autotuned`` / ``heuristic`` /
-        ``structural``), and how many weights share the shape.
+        ``structural``), its ``(block_m, block_n, block_k)`` tiles (None
+        on the XLA path), the dtype the dot's operands take for the
+        model's activations (:func:`repro.kernels.elp_bsd_matmul.
+        mxu_operand_dtype`; float32 on the XLA path), and how many
+        weights share the shape.
         """
+        from repro.kernels.elp_bsd_matmul import mxu_operand_dtype
         from repro.kernels.ops import PackedWeight, mesh_devices, select_impl
 
         m = self._sched.n_slots
@@ -1370,7 +1375,7 @@ class ServeEngine:
                 out[key]["count"] += 1
                 continue
             scanned = getattr(path[0], "key", None) == "blocks"
-            impl, _, source = select_impl(
+            impl, blocks, source = select_impl(
                 m,
                 k,
                 n,
@@ -1379,7 +1384,15 @@ class ServeEngine:
                 stacked=leaf.codes.ndim - scanned != 2,
                 devices=mesh_devices(leaf.codes),
             )
-            out[key] = {"impl": impl, "source": source, "count": 1}
+            kernel = impl != "xla"
+            operand = mxu_operand_dtype(leaf.fmt, self.cfg.dtype) if kernel else jnp.float32
+            out[key] = {
+                "impl": impl,
+                "source": source,
+                "blocks": blocks if kernel else None,
+                "operand": jnp.dtype(operand).name,
+                "count": 1,
+            }
         return out
 
     def compiled_decode(self):

@@ -217,3 +217,124 @@ def test_pallas_fused_rejects_stacked_codes():
     pw, _ = pack_weight(w, fmt)
     with pytest.raises(ValueError, match="single \\[K, N\\] weight"):
         quantized_matmul(jnp.zeros((2, 4, 128)), pw, impl="pallas_fused")
+
+
+# ---------------------------------------------------------------------------
+# the TPU tile rule and bf16 operands (pure functions; interpret mode)
+# ---------------------------------------------------------------------------
+QWEN3_8B_KN = [(4096, 4096), (4096, 1024), (4096, 12288), (12288, 4096)]
+# a decode step's seven packed GEMMs per layer: wq, wk, wv, wo, w1, w3, w2
+QWEN3_8B_LAYER = [(4096, 4096), (4096, 1024), (4096, 1024), (4096, 4096),
+                  (4096, 12288), (4096, 12288), (12288, 4096)]
+
+
+@pytest.fixture
+def tpu_miss(monkeypatch, tmp_path):
+    """``select_impl`` as on a TPU whose autotune cache holds nothing
+    (the parsed cache is keyed by its path, so a fresh path is empty)."""
+    from repro.bench import autotune
+
+    monkeypatch.setenv(autotune.CACHE_ENV, str(tmp_path / "empty.json"))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+@pytest.mark.parametrize("nibble", [True, False], ids=["nib", "u8"])
+@pytest.mark.parametrize("m", [16, 512])
+@pytest.mark.parametrize("k,n", QWEN3_8B_KN)
+def test_tpu_rule_tiles_divide_qwen3_8b_shapes(tpu_miss, k, n, m, nibble):
+    from repro.kernels.ops import select_impl
+
+    impl, (bm, bn, bk), source = select_impl(
+        m, k, n, fmt_name="elp_bsd_a4" if nibble else "elp_bsd_c6", nibble=nibble,
+        stacked=False, devices=1,
+    )
+    assert source == "heuristic"
+    assert impl == ("pallas_fused" if m <= MAX_FUSED_M else "pallas")
+    # no pad of codes or activations on any call: every tile divides
+    assert k % bk == 0 and n % bn == 0 and bn % 128 == 0 and bk % 128 == 0
+    assert impl == "pallas_fused" or m % bm == 0
+    panel = bk * bn // (2 if nibble else 1)
+    assert panel >= (256 << 10)  # a grid step carries far more than 128³'s 8 KB
+
+
+@pytest.mark.parametrize("nibble", [True, False], ids=["nib", "u8"])
+def test_tpu_rule_decode_step_grid(nibble):
+    """At the cell's M = 16 a layer's seven GEMMs take at most 200 grid
+    steps (11,776 at 128³ tiles), each with at least 0.5 MB of codes."""
+    from repro.kernels.ops import tpu_blocks
+
+    steps = 0
+    for k, n in QWEN3_8B_LAYER:
+        impl, (_, bn, bk) = tpu_blocks(16, k, n, nibble=nibble)
+        assert impl == "pallas_fused"
+        assert bk * bn // (2 if nibble else 1) >= (512 << 10)
+        steps += (k // bk) * (n // bn)
+    assert steps <= 200
+
+
+def test_select_impl_off_tpu_keeps_xla_and_default_blocks(monkeypatch, tmp_path):
+    from repro.bench import autotune
+    from repro.kernels.ops import select_impl
+
+    monkeypatch.setenv(autotune.CACHE_ENV, str(tmp_path / "empty.json"))
+    got = select_impl(16, 4096, 4096, fmt_name="elp_bsd_a4", nibble=True,
+                      stacked=False, devices=1)
+    assert got == ("xla", None, "heuristic")
+    assert autotune.lookup_blocks(16, 4096, 4096, fmt_name="elp_bsd_a4", nibble=True) == (
+        autotune.DEFAULT_BLOCKS
+    )
+
+
+@pytest.mark.parametrize("fmt_name", sorted(PRESET_FORMATS))
+def test_bf16_operands_only_where_every_level_is_exact(fmt_name):
+    import ml_dtypes
+
+    from repro.core.elp_bsd import decode_codes
+    from repro.kernels.elp_bsd_matmul import decodes_exactly_to_bf16, mxu_operand_dtype
+
+    fmt = PRESET_FORMATS[fmt_name]
+    vals = decode_codes(np.arange(2**fmt.bits_per_weight), fmt)
+    exact = bool(np.all(vals.astype(ml_dtypes.bfloat16).astype(np.float64) == vals))
+    assert decodes_exactly_to_bf16(fmt) == exact
+    assert mxu_operand_dtype(fmt, jnp.bfloat16) == (jnp.bfloat16 if exact else jnp.float32)
+    assert mxu_operand_dtype(fmt, jnp.float32) == jnp.float32
+
+
+def test_bf16_operands_refused_for_an_inexact_format():
+    from repro.core.elp_bsd import DigitSpec, ElpBsdFormat
+    from repro.kernels.elp_bsd_matmul import decodes_exactly_to_bf16, mxu_operand_dtype
+
+    wide = ElpBsdFormat((DigitSpec((0, 1)), DigitSpec((9,))), name="wide_span")  # 2^9 ± 1
+    assert not decodes_exactly_to_bf16(wide)
+    assert mxu_operand_dtype(wide, jnp.bfloat16) == jnp.float32
+
+
+@pytest.mark.parametrize("fmt_alias,nibble", GRID, ids=GRID_IDS)
+def test_rule_geometry_matches_ref_in_interpret(fmt_alias, nibble):
+    """The kernel at the rule's geometry: a 2048-row K panel decoded in
+    four 512-row slabs, bf16 operands, through the ops wrapper's no-pad
+    path."""
+    from repro.kernels.ops import tpu_blocks
+
+    fmt = resolve_format(fmt_alias)
+    m, k, n = 16, 4096, 256
+    impl, blocks = tpu_blocks(m, k, n, nibble=nibble)
+    blocks = (blocks[0], blocks[1], 2048)  # two K panels at a small N
+    assert impl == "pallas_fused" and blocks[1] == 256
+    rng = np.random.default_rng(12)
+    x = jnp.asarray(rng.normal(size=(m, k)), jnp.bfloat16)
+    w = jnp.asarray(rng.normal(size=(k, n)) * 0.05, jnp.float32)
+    pw, _ = pack_weight(w, fmt, nibble=nibble)
+    got = quantized_matmul(
+        x, pw, impl=impl, block_sizes=blocks, out_dtype=jnp.float32, interpret=True
+    )
+    want = kref.elp_bsd_matmul_ref(x, pw.codes, pw.sf, fmt, nibble=nibble)
+    # Each product is exact in both (a bf16 x times a bf16 level), so the
+    # two differ only by the order of K float32 additions and the place
+    # the scale is applied: at most K·2⁻²⁴ of the sum of |terms| per
+    # output (the standard bound for a reordered float32 sum).
+    terms = np.abs(np.asarray(x, np.float32)) @ np.abs(np.asarray(kref.dequantize_ref(
+        pw.codes, pw.sf, fmt, nibble=nibble)))
+    bound = k * 2.0**-24 * terms
+    err = np.abs(np.asarray(got) - np.asarray(want))
+    assert np.all(err <= bound), float((err / bound).max())

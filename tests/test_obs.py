@@ -362,7 +362,7 @@ def test_engine_metrics_and_frozen_stats(params):
     assert set(st["cache"]) == CACHE_KEYS
     assert st["cache"]["layout"] == "dense" and st["cache"]["page_size"] == 0
     for shape, d in st["kernel_dispatch"].items():  # {} for float params
-        assert set(d) == {"impl", "source", "count"}, shape
+        assert set(d) == {"impl", "source", "blocks", "operand", "count"}, shape
 
     total_tokens = sum(n for _, n in reqs)
     h = reg.histograms()

@@ -211,7 +211,8 @@ def test_packed_decode_consumes_codes_not_dequant(params, packed_params):
     # dispatch observability: every packed [K, N] shape reports how the
     # decode-step matmul impl was resolved (stats()["kernel_dispatch"])
     disp = eng_p.kernel_dispatch()
-    assert disp and all(set(d) == {"impl", "source", "count"} for d in disp.values())
+    keys = {"impl", "source", "blocks", "operand", "count"}
+    assert disp and all(set(d) == keys for d in disp.values())
     assert all(d["source"] in ("structural", "autotuned", "heuristic") for d in disp.values())
     assert not eng_f.kernel_dispatch()  # float params: nothing packed to dispatch
 

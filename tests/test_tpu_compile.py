@@ -4,14 +4,19 @@ Interpret mode cannot show what the chip's compiler refuses (8-bit
 vector shifts, unaligned code tiles, VMEM limits). These tests hand the
 kernels to the TPU compiler for a v5e that is described, not attached:
 qwen3-8b's four GEMM shapes at decode (M = 8 slots) and prefill
-(M = 512 tokens), in nibble (elp_bsd_a4) and u8 (elp_bsd_c6) codes, and
-one VGG-16 im2col conv through the ops wrapper. Nothing runs, so these
-say nothing about results or times.
+(M = 512 tokens), in nibble (elp_bsd_a4) and u8 (elp_bsd_c6) codes, the
+same shapes through ``impl="auto"`` as a TPU resolves them at an
+autotune-cache miss (M = 16 and 512, the tile rule's blocks), and one
+VGG-16 im2col conv through the ops wrapper. Nothing runs, so these say
+nothing about results or times.
 
 The topology is described inside a module fixture, never at import:
 only one process may load the TPU library at a time, and every test
 worker imports this file.
 """
+import dataclasses
+import re
+
 import pytest
 
 import jax
@@ -20,7 +25,7 @@ import jax.numpy as jnp
 from repro.core.elp_bsd import PRESET_FORMATS
 from repro.kernels.elp_bsd_matmul import elp_bsd_matmul
 from repro.kernels.fused_decode import fused_decode_matmul
-from repro.kernels.ops import PackedWeight, quantized_matmul
+from repro.kernels.ops import PackedWeight, quantized_matmul, tpu_blocks
 
 QWEN3_8B_KN = [(4096, 4096), (4096, 1024), (4096, 12288), (12288, 4096)]
 LAYOUTS = [("elp_bsd_a4", True), ("elp_bsd_c6", False)]
@@ -48,6 +53,17 @@ def one_chip():
         finally:
             jax.config.update("jax_enable_compilation_cache", was)
             compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def tpu_miss(monkeypatch, tmp_path):
+    """The program's backend checks read a TPU, and the autotune cache
+    is empty. A CPU process otherwise takes its CPU branches, and the
+    compile for the described chip would not see the kernels."""
+    from repro.bench import autotune
+
+    monkeypatch.setenv(autotune.CACHE_ENV, str(tmp_path / "empty.json"))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
 
 def _compiled_text(fn, *args) -> str:
@@ -105,3 +121,50 @@ def test_vgg16_im2col_conv_compiles(one_chip):
         lambda x, pw: quantized_matmul(x, pw, impl="pallas", interpret=False), x, pw
     )
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("m", [16, 512], ids=["decode", "prefill"])
+@pytest.mark.parametrize("fmt_name,nibble", LAYOUTS)
+@pytest.mark.parametrize("k,n", QWEN3_8B_KN)
+def test_auto_dispatch_compiles_at_qwen3_8b(one_chip, tpu_miss, k, n, fmt_name, nibble, m):
+    """``impl="auto"`` at a TPU cache miss, as the serve step calls it:
+    the tile rule's blocks fit the v5e's VMEM, the GEMM is one kernel
+    named for ``_quantized_matmul``, and nothing pads or copies the codes."""
+    pw = PackedWeight(
+        codes=jax.ShapeDtypeStruct((k // 2 if nibble else k, n), jnp.uint8, sharding=one_chip),
+        sf=jax.ShapeDtypeStruct((1, 1), jnp.float32, sharding=one_chip),
+        fmt_name=fmt_name,
+        nibble=nibble,
+        shape=(k, n),
+    )
+    x = jax.ShapeDtypeStruct((m, k), jnp.bfloat16, sharding=one_chip)
+    text = _compiled_text(
+        lambda x, pw: quantized_matmul(x, pw, impl="auto", block_sizes="auto", out_dtype=x.dtype),
+        x,
+        pw,
+    )
+    assert re.search(r"%_quantized_matmul\S* = \S+ custom-call\(.*tpu_custom_call", text)
+    # No op pads or copies the codes in HBM. XLA may stage them into VMEM
+    # (memory space S(1)) ahead of the kernel, which then reads them there.
+    made = re.findall(r"^\s*(?:ROOT )?%\S+ = (u8\[\S+) ([\w-]+)\(", text, re.M)
+    assert all(op == "parameter" or (op != "pad" and "S(1)" in t) for t, op in made), made
+
+
+def test_kernel_dispatch_reports_tiles_and_operand_dtype(tpu_miss):
+    """The engine reports each decode GEMM's impl, tiles and operand
+    dtype as a TPU resolves them: the fused kernel at the rule's tiles,
+    bf16 operands for elp4 codes under bf16 activations."""
+    from repro.api_schemes import init_packed_lm_params
+    from repro.configs import get_config
+    from repro.serve import ServeEngine
+
+    cfg = dataclasses.replace(get_config("qwen3_8b").reduced(), dtype_str="bfloat16")
+    params = init_packed_lm_params(cfg, jax.random.PRNGKey(0), "elp4")
+    engine = ServeEngine(cfg, params, n_slots=16, max_len=16, mesh=None)
+    dispatch = engine.kernel_dispatch()
+    assert dispatch
+    for key, d in dispatch.items():
+        m, k, n = map(int, key.split("|")[0].split("x"))
+        assert d["impl"] == "pallas_fused" and d["source"] == "heuristic", key
+        assert d["blocks"] == tpu_blocks(m, k, n, nibble=key.endswith("|nib"))[1], key
+        assert d["operand"] == "bfloat16", key
