@@ -33,6 +33,7 @@ class ArchConfig:
     mlp_kind: str = "swiglu"  # swiglu | gelu
     qk_norm: bool = False
     rope_theta: float = 1e4
+    norm_eps: float = 1e-6  # every RMSNorm's epsilon (the published rms_norm_eps)
     tie_embeddings: bool = False
     # --- MoE ---
     n_experts: int = 0

@@ -24,7 +24,6 @@ from jax import lax
 from repro.kernels.ops import (
     PackedWeight,
     dequantize_nd,
-    mesh_devices,
     quantized_matmul,
     select_impl,
 )
@@ -115,7 +114,6 @@ def quantized_conv2d(
                 fmt_name=pw.fmt_name,
                 nibble=pw.nibble,
                 stacked=pw.codes.ndim != 2,
-                devices=mesh_devices(pw.codes),
             )
             if source == "heuristic":
                 # Interim heuristic (no measurement for this shape): XLA.

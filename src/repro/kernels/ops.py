@@ -14,8 +14,12 @@ execution paths:
   * ``impl="pallas"`` — the fused decode+matmul kernel (TPU target,
     interpret-mode on CPU),
   * ``impl="xla"``    — dequantize-then-dot in plain jnp. Same HBM story
-    (codes are the stored operand), used inside pjit'd serve steps where
-    we want XLA to fuse the decode into the matmul across shards.
+    (codes are the stored operand), used for stacked (expert) weights,
+    off TPU, and as the reference datapath.
+
+A 2-D weight on a mesh of several devices runs one of these per device
+under ``jax.shard_map``, on the device's own shard of the codes
+(:func:`quantized_matmul`, DESIGN.md §5).
 
 Convolution weights pack through :func:`pack_conv_weight` (the 4-D
 ``[H, W, Cin, Cout]`` tensor flattens to ``[H*W*Cin, Cout]`` im2col
@@ -28,10 +32,12 @@ import contextlib
 import contextvars
 import dataclasses
 import functools
+from typing import Any
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro.core.convert import convert_tensor, nibble_pack
 from repro.core.elp_bsd import ElpBsdFormat, PRESET_FORMATS
@@ -70,6 +76,14 @@ class PackedWeight:
         reduction in the decode graph. Set by
         ``repro.api_schemes.pack_lm_params`` from a
         :class:`~repro.calib.policy.CalibrationTable`.
+      mesh / split: where the weight was placed
+        (:func:`repro.runtime.sharding.mark_splits`): the mesh, and the
+        mesh axes the codes' trailing ``(K-row, N)`` dims are split
+        over — ``(None, "model")`` column-parallel, ``("model", None)``
+        row-parallel, ``(None, None)`` whole on each device. A traced
+        array under an ``Auto`` mesh carries no spec (and no mesh, when
+        it was lowered from bare shapes), so the placement rides here,
+        as static data, to the matmul that runs the weight per shard.
     """
 
     codes: Array
@@ -80,6 +94,8 @@ class PackedWeight:
     source_shape: tuple[int, ...] | None = None
     act_scale: float | None = None
     act_bits: int | None = None
+    split: tuple[str | None, str | None] | None = None
+    mesh: Any = None
 
     @property
     def fmt(self) -> ElpBsdFormat:
@@ -98,6 +114,8 @@ class PackedWeight:
             self.source_shape,
             self.act_scale,
             self.act_bits,
+            self.split,
+            self.mesh,
         )
 
     def tree_flatten(self):
@@ -108,6 +126,8 @@ class PackedWeight:
             self.source_shape,
             self.act_scale,
             self.act_bits,
+            self.split,
+            self.mesh,
         )
 
     @classmethod
@@ -286,17 +306,23 @@ def dequantize_tree(tree):
     )
 
 
-def mesh_devices(x: Array) -> int:
-    """Devices of the mesh ``x`` lives on, read at trace time.
+def weight_mesh(x: Array):
+    """The mesh ``x`` lives on, read at trace time, or None for one device.
 
-    An array committed to a mesh names it in its own sharding; inside a
-    ``jit`` given mesh shardings the context mesh names it. Anything
-    else lives on one device, however many the host has.
+    An array committed to a mesh names it in its own sharding, and so
+    does its tracer; a context mesh (``jax.sharding.use_mesh``) names it
+    otherwise. Axes that a ``shard_map`` has made manual hold one shard
+    per device, so a value inside one lives on one device. Anything else
+    lives on one device, however many the host has.
     """
     mesh = jax.typeof(x).sharding.mesh
     if mesh.empty:
         mesh = jax.sharding.get_abstract_mesh()
-    return 1 if mesh.empty else mesh.size
+    if mesh.empty:
+        return None
+    manual = jax.sharding.AxisType.Manual
+    auto = [n for n, t in zip(mesh.axis_names, mesh.axis_types) if t != manual]
+    return mesh if int(np.prod([mesh.shape[n] for n in auto])) > 1 else None
 
 
 # The TPU tile rule (DESIGN.md §14). A grid step costs about 0.35 µs on
@@ -335,19 +361,19 @@ def tpu_blocks(m: int, k: int, n: int, *, nibble: bool) -> tuple[str, tuple[int,
 
 
 def select_impl(
-    m: int, k: int, n: int, *, fmt_name: str, nibble: bool, stacked: bool, devices: int
+    m: int, k: int, n: int, *, fmt_name: str, nibble: bool, stacked: bool
 ) -> tuple[str, tuple[int, int, int] | None, str]:
     """Resolve ``impl="auto"`` for one packed matmul: ``(impl, blocks, source)``.
 
-    Stacked weights and weights sharded over several devices take the
-    XLA path (``source="structural"``): the Pallas kernels take one
-    ``[K, N]`` on one device, and a sharded decode must partition with
-    its shards. Otherwise the autotune cache's measured winner decides
-    (``"autotuned"``, with its tuned blocks); a miss takes the kernel and
-    tiles :func:`tpu_blocks` derives from the shape on TPU, and XLA
-    elsewhere (``"heuristic"``; no blocks off TPU).
+    ``m, k, n`` are the shape one device computes: a weight on a mesh
+    runs per shard (:func:`quantized_matmul`), so its local shape. Stacked
+    weights take the XLA path (``source="structural"``): the Pallas
+    kernels take one ``[K, N]``. Otherwise the autotune cache's measured
+    winner decides (``"autotuned"``, with its tuned blocks); a miss takes
+    the kernel and tiles :func:`tpu_blocks` derives from the shape on TPU,
+    and XLA elsewhere (``"heuristic"``; no blocks off TPU).
     """
-    if stacked or devices > 1:
+    if stacked:
         return "xla", None, "structural"
     from repro.bench.autotune import lookup_impl
 
@@ -391,7 +417,6 @@ def _resolve_auto_impl(m0: int, k: int, n: int, pw: PackedWeight, block_sizes):
         fmt_name=pw.fmt_name,
         nibble=pw.nibble,
         stacked=pw.codes.ndim != 2,
-        devices=mesh_devices(pw.codes),
     )
     if blocks is not None and (block_sizes is None or block_sizes == "auto"):
         return impl, blocks
@@ -413,12 +438,101 @@ def quantized_matmul(x: Array, pw: PackedWeight, *, impl: str = "pallas", **kw) 
     See :func:`_quantized_matmul` for the arguments. ``impl="auto"``
     becomes the impl of an enclosing :func:`force_impl` here, before the
     jit, so that a forced and an automatic trace never share a cache
-    entry.
+    entry. A 2-D weight on a mesh of several devices runs per shard
+    (:func:`sharded_matmul`).
     """
     if impl == "auto":
         impl = _FORCED_IMPL.get() or impl
+    mesh = None
+    if pw.codes.ndim == 2:
+        mesh = weight_mesh(pw.codes) if pw.mesh is None else pw.mesh
+    if mesh is not None and mesh.size > 1:
+        return sharded_matmul(x, pw, mesh, impl=impl, **kw)
     with jax.named_scope("elp_bsd_matmul"):
         return _quantized_matmul(x, pw, impl=impl, **kw)
+
+
+# The partial sums of a row-parallel GEMM are added across the mesh under
+# this scope in the device trace.
+COLLECTIVE_SCOPE = "tp_collective"
+
+
+def _rows_spec(mesh, m: int, used: tuple) -> tuple[str, ...] | None:
+    """Mesh axes the ``m`` activation rows split over: every axis the
+    weight does not, when their devices divide ``m``."""
+    rest = tuple(a for a in mesh.axis_names if a not in used)
+    if not rest or m % int(np.prod([mesh.shape[a] for a in rest])):
+        return None
+    return rest
+
+
+def shard_shape(m: int, pw: PackedWeight, mesh) -> tuple[int, int, int]:
+    """``(M, K, N)`` that one device computes in :func:`sharded_matmul`
+    of ``x[m, K]`` against ``pw`` (a per-layer slice, if stacked)."""
+    k_axis, n_axis = pw.split or (None, None)
+    k, n = pw.shape
+    if k_axis is not None:
+        rows = pw.codes.shape[-2] // mesh.shape[k_axis]
+        k = 2 * rows if pw.nibble else rows
+    if n_axis is not None:
+        n //= mesh.shape[n_axis]
+    rows = _rows_spec(mesh, m, (k_axis, n_axis)) or ()
+    return m // int(np.prod([mesh.shape[a] for a in rows])), k, n
+
+
+def sharded_matmul(x: Array, pw: PackedWeight, mesh, *, impl: str, out_dtype=None, **kw) -> Array:
+    """``quantized_matmul`` of a 2-D weight on a mesh, one shard per device.
+
+    Each device runs :func:`_quantized_matmul` (the kernel, at tiles of
+    its local shape) on its own codes under ``jax.shard_map``. The
+    weight's :attr:`PackedWeight.split` says how the codes are laid out:
+
+    * column-parallel (N over an axis): each device computes its output
+      columns, and the output stays split over that axis;
+    * row-parallel (K over an axis): each device contracts its K rows
+      (whole nibble pairs: code row ``r`` holds rows ``2r`` and ``2r+1``)
+      with its slice of ``x`` into a float32 partial sum, and a ``psum``
+      over the axis adds them;
+    * neither: the whole weight on every device.
+
+    Activation rows split over the mesh's other axes where they divide.
+    Scales follow the weight: per-channel ``sf`` splits with N, and is
+    whole on each device otherwise (``runtime/sharding._scale_spec``).
+    """
+    k_axis, n_axis = pw.split or (None, None)
+    k, n = pw.shape
+    out_dtype = out_dtype or x.dtype
+    lead = x.shape[:-1]
+    x2 = x.reshape(-1, x.shape[-1])
+    if k_axis is not None and pw.nibble:
+        x2 = _pad_to(x2, 1, 2 * pw.codes.shape[0])  # an odd K's pad row, zero
+    rows = _rows_spec(mesh, x2.shape[0], (k_axis, n_axis))
+    split_sf = n_axis is not None and pw.sf.ndim and pw.sf.shape[-1] == n > 1
+    sf_spec = P(*([None] * (pw.sf.ndim - 1)), n_axis) if split_sf else P()
+
+    def per_shard(xs, codes, sf):
+        kl = xs.shape[1] if k_axis is not None else k
+        local = dataclasses.replace(
+            pw, codes=codes, sf=sf, shape=(kl, codes.shape[1]), split=None, mesh=None
+        )
+        with jax.named_scope("elp_bsd_matmul"):
+            out = _quantized_matmul(
+                xs, local, impl=impl, out_dtype=F32 if k_axis else out_dtype, **kw
+            )
+        if k_axis is None:
+            return out
+        with jax.named_scope(COLLECTIVE_SCOPE):
+            out = jax.lax.psum(out, k_axis)
+        return out.astype(out_dtype)
+
+    out = jax.shard_map(
+        per_shard,
+        mesh=mesh,
+        in_specs=(P(rows, k_axis), P(k_axis, n_axis), sf_spec),
+        out_specs=P(rows, n_axis),
+        check_vma=False,  # the Pallas kernels' outputs state no varying axes
+    )(x2, pw.codes, pw.sf)
+    return out.reshape(*lead, n)
 
 
 @functools.partial(

@@ -42,7 +42,7 @@ def encode(params, cfg: ArchConfig, src: Array, *, pctx=None, remat: bool = Fals
     """src: precomputed frame embeddings [B, S_enc, D] (stub frontend)."""
     x = matmul(src.astype(cfg.dtype), params["frontend_proj"])
     x, _ = T.stack_apply(params["encoder"], cfg, x, causal=False, pctx=pctx, remat=remat)
-    return rms_norm(x, params["enc_norm"])
+    return rms_norm(x, params["enc_norm"], cfg.norm_eps)
 
 
 def forward(
@@ -59,7 +59,7 @@ def forward(
     x, _ = T.stack_apply(
         params["decoder"], cfg, x, causal=True, enc_out=enc_out, pctx=pctx, remat=remat
     )
-    x = rms_norm(x, params["final_norm"])
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return jnp.dot(x, params["lm_head"].astype(x.dtype), preferred_element_type=F32)
 
 
@@ -87,7 +87,7 @@ def prefill(params, cfg: ArchConfig, tokens: Array, cache, *, frontend, pctx=Non
         enc_out=enc_out,
         pctx=pctx,
     )
-    x = rms_norm(x[:, -1:], params["final_norm"])
+    x = rms_norm(x[:, -1:], params["final_norm"], cfg.norm_eps)
     logits = jnp.dot(x, params["lm_head"].astype(x.dtype), preferred_element_type=F32)
     return logits, (cache, enc_out)
 
@@ -106,6 +106,6 @@ def decode_step(params, cfg: ArchConfig, token: Array, state, pos, *, pctx=None,
         enc_out=enc_out,
         pctx=pctx,
     )
-    x = rms_norm(x, params["final_norm"])
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = jnp.dot(x, params["lm_head"].astype(x.dtype), preferred_element_type=F32)
     return logits, (cache, enc_out)

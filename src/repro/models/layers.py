@@ -237,10 +237,12 @@ def matmul(x: Array, w) -> Array:
     decoded in-graph via ``impl="auto"`` — the autotune cache's measured
     winner per (shape, layout, backend) picks between the tiled Pallas
     kernel, the fused decode-step kernel, and the XLA dequant path, with
-    tuned block sizes resolved at trace time. Stacked (expert) weights
-    and weights sharded over a multi-device mesh always stay on the XLA
-    path (pjit must keep the decode in XLA so it partitions with the
-    shards); a one-device weight uses the kernels on any host.
+    tuned block sizes resolved at trace time. A 2-D weight on a mesh of
+    several devices runs that choice per shard, at the shape one device
+    computes, under ``shard_map``: a column-parallel weight (N split)
+    returns its output split, a row-parallel one (K split) adds its
+    partial sums with a ``psum`` (``kernels/ops.sharded_matmul``,
+    DESIGN.md §5). Stacked (expert) weights stay on the XLA path.
     Either way HBM moves only the code bytes.
     """
     from repro.kernels.ops import PackedWeight, quantized_matmul

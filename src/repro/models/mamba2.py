@@ -169,7 +169,7 @@ def block_apply(
     enables single-token decode; ``None`` runs the chunked parallel form."""
     d_in, nh, hd, st = dims(cfg)
     res = x
-    xn = rms_norm(x, lp["ln"])
+    xn = rms_norm(x, lp["ln"], cfg.norm_eps)
     proj = matmul(xn, lp["in_proj"])
     z, xs, bm, cm, dt = jnp.split(
         proj, [d_in, 2 * d_in, 2 * d_in + st, 2 * d_in + 2 * st], axis=-1
@@ -207,7 +207,7 @@ def block_apply(
 
     y = y + xh * lp["D"][None, None, :, None]
     y = y.reshape(b, s, d_in).astype(x.dtype)
-    y = rms_norm(y * jax.nn.silu(z.astype(F32)).astype(x.dtype), lp["gnorm"])
+    y = rms_norm(y * jax.nn.silu(z.astype(F32)).astype(x.dtype), lp["gnorm"], cfg.norm_eps)
     out = res + matmul(y, lp["out_proj"])
     return out, new_state
 
@@ -233,7 +233,7 @@ def _run_stack(params, cfg: ArchConfig, x: Array, state=None, remat: bool = Fals
 def forward(params, cfg: ArchConfig, tokens: Array, *, remat: bool = False, **_) -> Array:
     x = params["embed"][tokens].astype(cfg.dtype)
     x, _ = _run_stack(params, cfg, x, remat=remat)
-    x = rms_norm(x, params["final_norm"])
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     return jnp.dot(x, head.astype(x.dtype), preferred_element_type=F32)
 
@@ -254,7 +254,7 @@ def prefill(params, cfg: ArchConfig, tokens: Array, state, **_):
     def body2(carry, xs):
         lp, conv_l, ssd_l = xs
         xin = carry
-        xn = rms_norm(xin, lp["ln"])
+        xn = rms_norm(xin, lp["ln"], cfg.norm_eps)
         proj = matmul(xn, lp["in_proj"])
         z, xs_, bm, cm, dtp = jnp.split(
             proj, [d_in, 2 * d_in, 2 * d_in + st, 2 * d_in + 2 * st], axis=-1
@@ -271,12 +271,12 @@ def prefill(params, cfg: ArchConfig, tokens: Array, state, **_):
         y, h_fin = _ssd_chunked(xh, dtv, A, bm2.astype(F32), cm2.astype(F32))
         y = y + xh * lp["D"][None, None, :, None]
         y = y.reshape(b, s, d_in).astype(xin.dtype)
-        y = rms_norm(y * jax.nn.silu(z.astype(F32)).astype(xin.dtype), lp["gnorm"])
+        y = rms_norm(y * jax.nn.silu(z.astype(F32)).astype(xin.dtype), lp["gnorm"], cfg.norm_eps)
         out = xin + matmul(y, lp["out_proj"])
         return out, (new_conv, h_fin)
 
     x, (conv_out, ssd_out) = jax.lax.scan(body2, x, (params["blocks"], conv, ssd))
-    x = rms_norm(x[:, -1:], params["final_norm"])
+    x = rms_norm(x[:, -1:], params["final_norm"], cfg.norm_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = jnp.dot(x, head.astype(x.dtype), preferred_element_type=F32)
     return logits, (conv_out, ssd_out)
@@ -285,6 +285,6 @@ def prefill(params, cfg: ArchConfig, tokens: Array, state, **_):
 def decode_step(params, cfg: ArchConfig, token: Array, state, pos=None, **_):
     x = params["embed"][token].astype(cfg.dtype)
     x, state = _run_stack(params, cfg, x, state=state)
-    x = rms_norm(x, params["final_norm"])
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     return jnp.dot(x, head.astype(x.dtype), preferred_element_type=F32), state

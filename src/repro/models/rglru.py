@@ -146,7 +146,7 @@ def rec_block(
     state: tuple[Array, Array] | None = None,
 ) -> tuple[Array, tuple[Array, Array] | None]:
     """Recurrent block + MLP. state = (conv window [B,cw-1,lru], h [B,lru])."""
-    xn = rms_norm(x, lp["ln1"])
+    xn = rms_norm(x, lp["ln1"], cfg.norm_eps)
     gate = jax.nn.gelu(matmul(xn, lp["w_gate"]).astype(F32)).astype(x.dtype)
     y = matmul(xn, lp["w_rec"])
     new_state = None
@@ -179,7 +179,7 @@ def rec_block(
         new_state = (new_win, h)
     y = matmul(y * gate, lp["w_out"])
     x = x + y
-    x = x + mlp_apply(lp, rms_norm(x, lp["ln2"]), "geglu")
+    x = x + mlp_apply(lp, rms_norm(x, lp["ln2"], cfg.norm_eps), "geglu")
     return x, new_state
 
 
@@ -264,7 +264,7 @@ def attn_block(
         and kv_cache[0].shape[1] <= cfg.window
     )
     if ring:
-        xn = rms_norm(x, lp["ln1"])
+        xn = rms_norm(x, lp["ln1"], cfg.norm_eps)
         if x.shape[1] == 1:
             out, new_cache = _ring_decode(lp, cfg, xn, kv_cache, cache_pos)
         else:
@@ -273,7 +273,7 @@ def attn_block(
         out, new_cache = T._attention(
             lp,
             cfg,
-            rms_norm(x, lp["ln1"]),
+            rms_norm(x, lp["ln1"], cfg.norm_eps),
             rope=rope,
             causal=True,
             window=cfg.window,
@@ -281,7 +281,7 @@ def attn_block(
             cache_pos=cache_pos,
         )
     x = x + out
-    x = x + mlp_apply(lp, rms_norm(x, lp["ln2"]), cfg.mlp_kind)
+    x = x + mlp_apply(lp, rms_norm(x, lp["ln2"], cfg.norm_eps), cfg.mlp_kind)
     return x, new_cache
 
 
@@ -429,7 +429,7 @@ def init_cache(cfg: ArchConfig, batch: int, max_len: int) -> dict[str, Array]:
 def forward(params, cfg: ArchConfig, tokens: Array, *, remat: bool = False, **_) -> Array:
     x = params["embed"][tokens].astype(cfg.dtype)
     x, _ = _run(params, cfg, x, remat=remat)
-    x = rms_norm(x, params["final_norm"])
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return jnp.dot(x, params["lm_head"].astype(x.dtype), preferred_element_type=F32)
 
 
@@ -441,7 +441,7 @@ def loss_fn(params, cfg: ArchConfig, tokens, labels, *, remat=True, **_) -> Arra
 def prefill(params, cfg: ArchConfig, tokens: Array, cache, **_):
     x = params["embed"][tokens].astype(cfg.dtype)
     x, cache = _run(params, cfg, x, cache=cache, cache_pos=jnp.int32(0))
-    x = rms_norm(x[:, -1:], params["final_norm"])
+    x = rms_norm(x[:, -1:], params["final_norm"], cfg.norm_eps)
     return (
         jnp.dot(x, params["lm_head"].astype(x.dtype), preferred_element_type=F32),
         cache,
@@ -451,7 +451,7 @@ def prefill(params, cfg: ArchConfig, tokens: Array, cache, **_):
 def decode_step(params, cfg: ArchConfig, token: Array, cache, pos, **_):
     x = params["embed"][token].astype(cfg.dtype)
     x, cache = _run(params, cfg, x, cache=cache, cache_pos=pos)
-    x = rms_norm(x, params["final_norm"])
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return (
         jnp.dot(x, params["lm_head"].astype(x.dtype), preferred_element_type=F32),
         cache,

@@ -180,8 +180,8 @@ def _attention(
         k = matmul(kv_src, lp[prefix + "k"]).reshape(b, kv_src.shape[1], kv, hd)
         v = matmul(kv_src, lp[prefix + "v"]).reshape(b, kv_src.shape[1], kv, hd)
         if cfg.qk_norm and prefix == "w":
-            q = rms_norm(q, lp["qnorm"])
-            k = rms_norm(k, lp["knorm"])
+            q = rms_norm(q, lp["qnorm"], cfg.norm_eps)
+            k = rms_norm(k, lp["knorm"], cfg.norm_eps)
         if rope is not None and kv_override is None:
             cos_q, sin_q, cos_k, sin_k = rope
             q = apply_rope(q, cos_q, sin_q)
@@ -343,7 +343,7 @@ def block_apply(
         x = jax.lax.with_sharding_constraint(
             x, _P(pctx.batch_axes, pctx.model_axis, None)
         )
-    attn_in = rms_norm(x, lp["ln1"])
+    attn_in = rms_norm(x, lp["ln1"], cfg.norm_eps)
     if acts is not None:
         acts["attn_in"] = attn_in
     attn_out, new_cache = _attention(
@@ -370,12 +370,12 @@ def block_apply(
             x, _P(pctx.batch_axes, pctx.model_axis, None)
         )
     if enc_out is not None:
-        xa_in = rms_norm(x, lp["ln_x"])
+        xa_in = rms_norm(x, lp["ln_x"], cfg.norm_eps)
         xa_out, _ = _attention(
             lp, cfg, xa_in, rope=None, causal=False, prefix="x", kv_override=enc_out
         )
         x = x + xa_out
-    ffn_in = rms_norm(x, lp["ln2"])
+    ffn_in = rms_norm(x, lp["ln2"], cfg.norm_eps)
     if acts is not None:
         acts["ffn_in"] = ffn_in
     if cfg.is_moe:
@@ -535,7 +535,7 @@ def embed_tokens(params, cfg: ArchConfig, tokens: Array, frontend: Array | None)
 
 def unembed(params, cfg: ArchConfig, x: Array) -> Array:
     with jax.named_scope("lm_head"):
-        x = rms_norm(x, params["final_norm"])
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
         return jnp.dot(x, head.astype(x.dtype), preferred_element_type=F32)
 

@@ -18,6 +18,7 @@ Baseline layout (the paper-faithful starting point; §Perf iterates):
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Any
 
 import jax
@@ -152,6 +153,29 @@ def param_specs(abstract_params: Any, mesh: Mesh, model_axis: str = "model") -> 
     )
 
 
+def mark_splits(tree: Any, spec_tree: Any, mesh: Mesh) -> Any:
+    """Record on each ``PackedWeight`` of a tree placed on ``mesh`` where it is.
+
+    ``spec_tree`` is the tree's :func:`param_specs`. Each packed weight's
+    ``mesh`` becomes ``mesh`` and its ``split`` its codes' spec over the
+    trailing ``(K-row, N)`` dims — what
+    :func:`repro.kernels.ops.sharded_matmul` runs per shard. Stack dims
+    in front drop out, so the per-layer slices a scan hands the matmul
+    carry it too. Apply it to the placed tree and to its abstract twin
+    alike: the mark is static data, so it is part of the tree's
+    structure.
+    """
+    from repro.kernels.ops import PackedWeight
+
+    def mark(leaf, spec):
+        if not isinstance(leaf, PackedWeight):
+            return leaf
+        entries = tuple(spec.codes) + (None,) * (leaf.codes.ndim - len(spec.codes))
+        return dataclasses.replace(leaf, split=entries[-2:], mesh=mesh)
+
+    return jax.tree.map(mark, tree, spec_tree, is_leaf=lambda x: isinstance(x, PackedWeight))
+
+
 def batch_axes(mesh: Mesh) -> tuple[str, ...]:
     """Axes carrying the global batch: ("pod","data") when pod exists."""
     return tuple(a for a in ("pod", "data") if a in mesh.shape)
@@ -232,8 +256,13 @@ def cache_spec(
         if shape[sdim] % msize == 0:
             spec[sdim] = model_axis
             return P(*spec)
-    # model axis: prefer later dims (heads/features), walk backwards
-    for d in range(len(shape) - 1, 1, -1):
+    # model axis: later dims first (features, then seq); a KV leaf
+    # [L, B, S, KV, hd] takes its heads before head_dim, so each device
+    # attends with whole heads, the ones its column-parallel k/v make
+    order = list(range(len(shape) - 1, 1, -1))
+    if name in ("k", "v", "ks", "vs") and len(shape) == 5:
+        order = [3, 4, 2]
+    for d in order:
         if spec[d] is None and shape[d] % msize == 0 and shape[d] >= msize:
             spec[d] = model_axis
             break

@@ -137,6 +137,16 @@ class ServeSetup:
 # ---------------------------------------------------------------------------
 # Jitted step builders
 # ---------------------------------------------------------------------------
+def _place(params: Any, mesh: Mesh) -> Any:
+    """``params`` laid out on ``mesh`` by the sharding rules, each packed
+    weight marked with its split (:func:`repro.runtime.sharding.mark_splits`).
+    Arrays already in their shardings stay where they are."""
+    from repro.runtime.elastic import reshard
+
+    specs = shr.param_specs(jax.eval_shape(lambda: params), mesh)
+    return shr.mark_splits(reshard(params, mesh, specs), specs, mesh)
+
+
 def _abstract_params(setup: ServeSetup, api: ModelApi, aparams):
     """Abstract tree the shardings are derived from.
 
@@ -780,12 +790,9 @@ class ServeEngine:
             kv_bits=int(kv_bits),
         )
         self._api = get_model(cfg)
-        aparams = jax.eval_shape(lambda: params)
         if mesh is not None:
-            from repro.runtime.elastic import reshard
-
-            self.pspecs = shr.param_specs(aparams, mesh)
-            params = reshard(params, mesh, self.pspecs)
+            params = _place(params, mesh)
+        aparams = jax.eval_shape(lambda: params)
         self.params = params
         if self._paged:
             self._prefill = build_paged_prefill(self.setup, self._api, aparams=aparams)
@@ -803,20 +810,17 @@ class ServeEngine:
                     jnp.asarray(kv_scales[0], jnp.float32),
                     jnp.asarray(kv_scales[1], jnp.float32),
                 )
-            cache = transformer.init_paged_cache(
-                cfg, n_slots, max_len, page_size=self.setup.page_size, kv_scales=scales
+            cache = self._new_cache(
+                lambda: transformer.init_paged_cache(
+                    cfg, n_slots, max_len, page_size=self.setup.page_size, kv_scales=scales
+                )
             )
             self._pager = PageTable(
                 n_slots, max_len, self.setup.page_size, n_pages=cache["k"].shape[1]
             )
         else:
-            cache = self._api.init_cache(cfg, n_slots, max_len)
+            cache = self._new_cache(lambda: self._api.init_cache(cfg, n_slots, max_len))
             self._pager = None
-        if mesh is not None:
-            cspecs = shr.cache_specs_tree(
-                jax.eval_shape(lambda: cache), mesh, prefer_seq=flash_decode
-            )
-            cache = jax.device_put(cache, shr.named(mesh, cspecs))
         self._cache = cache
         # speculative state: the verify step always runs on the target
         # params. A "model" drafter additionally gets its own jitted
@@ -830,13 +834,9 @@ class ServeEngine:
             self._verify = build_verify_step(self.setup, self._api, aparams=aparams)
             self._spec_width = self.spec_k
             if self.spec_draft == "model":
-                adraft = jax.eval_shape(lambda: draft_params)
                 if mesh is not None:
-                    from repro.runtime.elastic import reshard
-
-                    self.draft_params = reshard(
-                        draft_params, mesh, shr.param_specs(adraft, mesh)
-                    )
+                    self.draft_params = _place(draft_params, mesh)
+                adraft = jax.eval_shape(lambda: self.draft_params)
                 if self._paged:
                     # the draft tier gets its OWN physical pool but maps
                     # it through the SAME page table: logical positions
@@ -845,21 +845,21 @@ class ServeEngine:
                     self._draft_prefill = build_paged_prefill(
                         self.setup, self._api, aparams=adraft
                     )
-                    dcache = transformer.init_paged_cache(
-                        cfg,
-                        n_slots,
-                        max_len,
-                        page_size=self.setup.page_size,
-                        kv_scales=scales,
+                    dcache = self._new_cache(
+                        lambda: transformer.init_paged_cache(
+                            cfg,
+                            n_slots,
+                            max_len,
+                            page_size=self.setup.page_size,
+                            kv_scales=scales,
+                        )
                     )
                 else:
                     self._draft_prefill = build_slot_prefill(
                         self.setup, self._api, aparams=adraft
                     )
-                    dcache = self._api.init_cache(cfg, n_slots, max_len)
+                    dcache = self._new_cache(lambda: self._api.init_cache(cfg, n_slots, max_len))
                 self._draft_run = build_draft_run(self.setup, self._api, aparams=adraft)
-                if mesh is not None:
-                    dcache = jax.device_put(dcache, shr.named(mesh, cspecs))
                 self._draft_cache = dcache
             else:
                 self._ngram = np.full(cfg.vocab, -1, np.int32)
@@ -911,6 +911,9 @@ class ServeEngine:
         # step to step without ever fetching it)
         self._pos = np.zeros(n_slots, np.int32)
         self._tok_dev = jnp.zeros((n_slots, 1), jnp.int32)
+        if mesh is not None:  # where the decode step leaves it: one admission program
+            tok_spec = shr.input_spec((n_slots, 1), mesh)
+            self._tok_dev = jax.device_put(self._tok_dev, NamedSharding(mesh, tok_spec))
         self.steps = 0
         self._decode_steps = 0
         self._prefills = 0
@@ -1344,25 +1347,29 @@ class ServeEngine:
         Walks the packed weight tree and resolves every distinct
         ``[K, N]`` decode-step GEMM (``M`` = the slot batch) exactly the
         way ``layers.matmul(impl="auto")`` does at trace time
-        (:func:`repro.kernels.ops.select_impl`): XLA when the mesh the
-        placed codes live on spans several devices (read by the same
-        :func:`repro.kernels.ops.mesh_devices` the trace reads), else the
-        autotune cache's measured winner, or the backend heuristic on a
-        miss. Layer weights are
-        judged per layer slice, as the layer scan hands them to the
-        matmul. Observability for "is the fused decode kernel actually
-        on?" — keyed ``MxKxN|fmt|layout``, each value recording the
-        impl, how it was chosen (``autotuned`` / ``heuristic`` /
-        ``structural``), its ``(block_m, block_n, block_k)`` tiles (None
-        on the XLA path), the dtype the dot's operands take for the
-        model's activations (:func:`repro.kernels.elp_bsd_matmul.
-        mxu_operand_dtype`; float32 on the XLA path), and how many
-        weights share the shape.
+        (:func:`repro.kernels.ops.select_impl`): the autotune cache's
+        measured winner, or the backend heuristic on a miss; XLA for
+        stacked (expert) weights. Layer weights are judged per layer
+        slice, as the layer scan hands them to the matmul. On a mesh of
+        several devices a 2-D weight runs per shard
+        (:func:`repro.kernels.ops.sharded_matmul`) and is resolved at the
+        shape one device computes. Observability for "is the fused decode
+        kernel actually on?" — keyed ``MxKxN|fmt|layout``, each value
+        recording the impl, how it was chosen (``autotuned`` /
+        ``heuristic`` / ``structural``, or ``sharded`` per shard), its
+        ``(block_m, block_n, block_k)`` tiles (None on the XLA path), the
+        dtype the dot's operands take for the model's activations
+        (:func:`repro.kernels.elp_bsd_matmul.mxu_operand_dtype`; float32
+        on the XLA path), and how many weights share the shape. A sharded
+        shape also records its ``split`` (``col``: N over the mesh,
+        ``row``: K over the mesh and a sum after, ``whole``: the weight on
+        every device) and its ``local`` ``MxKxN``.
         """
         from repro.kernels.elp_bsd_matmul import mxu_operand_dtype
-        from repro.kernels.ops import PackedWeight, mesh_devices, select_impl
+        from repro.kernels.ops import PackedWeight, select_impl, shard_shape
 
         m = self._sched.n_slots
+        sharded = self.mesh is not None and self.mesh.size > 1
         out: dict[str, dict] = {}
         for path, leaf in jax.tree_util.tree_leaves_with_path(
             self.params, is_leaf=lambda l: isinstance(l, PackedWeight)
@@ -1375,14 +1382,10 @@ class ServeEngine:
                 out[key]["count"] += 1
                 continue
             scanned = getattr(path[0], "key", None) == "blocks"
+            stacked = leaf.codes.ndim - scanned != 2
+            local = (m, k, n) if stacked or not sharded else shard_shape(m, leaf, self.mesh)
             impl, blocks, source = select_impl(
-                m,
-                k,
-                n,
-                fmt_name=leaf.fmt_name,
-                nibble=leaf.nibble,
-                stacked=leaf.codes.ndim - scanned != 2,
-                devices=mesh_devices(leaf.codes),
+                *local, fmt_name=leaf.fmt_name, nibble=leaf.nibble, stacked=stacked
             )
             kernel = impl != "xla"
             operand = mxu_operand_dtype(leaf.fmt, self.cfg.dtype) if kernel else jnp.float32
@@ -1393,6 +1396,13 @@ class ServeEngine:
                 "operand": jnp.dtype(operand).name,
                 "count": 1,
             }
+            if sharded and not stacked:
+                k_axis, n_axis = leaf.split or (None, None)
+                out[key].update(
+                    source="sharded",
+                    split="row" if k_axis else "col" if n_axis else "whole",
+                    local="x".join(map(str, local)),
+                )
         return out
 
     def compiled_decode(self):
@@ -1414,6 +1424,16 @@ class ServeEngine:
         return compiled_cost(self.compiled_decode())
 
     # -- internals -----------------------------------------------------------
+    def _new_cache(self, make) -> Any:
+        """``make()``'s cache tree, built straight into its shardings on the
+        engine's mesh: no device ever holds the whole cache."""
+        if self.mesh is None:
+            return make()
+        cspecs = shr.cache_specs_tree(
+            jax.eval_shape(make), self.mesh, prefer_seq=self.setup.flash_decode
+        )
+        return jax.jit(make, out_shardings=shr.named(self.mesh, cspecs))()
+
     def _dispatch_cache(self, cache: Any = None) -> Any:
         """The cache tree a dispatch consumes.
 

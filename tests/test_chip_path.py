@@ -124,10 +124,12 @@ def test_init_packed_lm_params_equals_pack_of_init():
 
 
 def test_select_impl_structural_cases():
+    """Only stacked weights are structural: a weight on a mesh is
+    resolved at its per-device shape like any one-device weight."""
     kw = dict(fmt_name="elp_bsd_a4", nibble=True)
-    assert select_impl(8, 64, 64, stacked=True, devices=1, **kw)[::2] == ("xla", "structural")
-    assert select_impl(8, 64, 64, stacked=False, devices=4, **kw)[::2] == ("xla", "structural")
-    assert select_impl(8, 64, 64, stacked=False, devices=1, **kw)[2] != "structural"
+    assert select_impl(8, 64, 64, stacked=True, **kw)[::2] == ("xla", "structural")
+    assert select_impl(8, 64, 64, stacked=False, **kw)[2] != "structural"
+    assert select_impl(8, 64, 16, stacked=False, **kw)[2] != "structural"
 
 
 def test_force_impl_pins_the_traced_impl():
@@ -149,14 +151,16 @@ def test_force_impl_pins_the_traced_impl():
 
 def test_dispatch_follows_the_weights_mesh_not_the_device_count():
     """On a 4-device host a one-device engine keeps the per-weight
-    choice; an engine whose mesh spans the devices goes structural."""
+    choice; an engine whose mesh spans the devices runs every packed
+    GEMM per shard: column-parallel weights at N / 4, row-parallel ones
+    at K / 4."""
     out = _run(
         """
         import jax, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.api_schemes import init_packed_lm_params
         from repro.configs import get_config
-        from repro.kernels.ops import mesh_devices
+        from repro.kernels.ops import weight_mesh
         from repro.runtime.elastic import make_mesh
         from repro.serve import ServeEngine
 
@@ -164,14 +168,24 @@ def test_dispatch_follows_the_weights_mesh_not_the_device_count():
         mesh = make_mesh()
         a = jax.device_put(np.ones((8, 16), np.float32), NamedSharding(mesh, P()))
         b = jax.device_put(np.ones((8, 16), np.float32), jax.devices()[2])
-        assert jax.jit(lambda v: mesh_devices(v) + 0 * v.sum())(a) == 4
-        assert jax.jit(lambda v: mesh_devices(v) + 0 * v.sum())(b) == 1
+        seen = []
+        jax.jit(lambda v: seen.append(weight_mesh(v)) or v)(a)
+        jax.jit(lambda v: seen.append(weight_mesh(v)) or v)(b)
+        assert seen[0].size == 4 and seen[1] is None
         cfg = get_config("qwen3_8b").reduced()
         params = init_packed_lm_params(cfg, jax.random.PRNGKey(0), "elp4")
         one = ServeEngine(cfg, params, n_slots=2, max_len=16, mesh=None)
         many = ServeEngine(cfg, params, n_slots=2, max_len=16, mesh=mesh)
         assert {d["source"] for d in one.kernel_dispatch().values()} == {"heuristic"}
-        assert {d["source"] for d in many.kernel_dispatch().values()} == {"structural"}
+        disp = many.kernel_dispatch()
+        assert {d["source"] for d in disp.values()} == {"sharded"}
+        for key, d in disp.items():
+            m, k, n = map(int, key.split("|")[0].split("x"))
+            want = (m, k, n // 4) if d["split"] == "col" else (m, k // 4, n)
+            assert d["split"] in ("col", "row") and d["local"] == "x".join(map(str, want)), d
+        assert {d["split"] for d in disp.values()} == {"col", "row"}
+        assert many.params["blocks"]["wo"].split == ("model", None)
+        assert many.params["blocks"]["w1"].split == (None, "model")
         print("OK")
         """,
         devices=4,
@@ -255,27 +269,48 @@ def test_chip_smoke_kernel_check(chip_smoke, impl, hlo, error):
 
 
 def test_chip_smoke_structural_check(chip_smoke):
-    ok = {"8x64x64|elp_bsd_a4|nib": {"impl": "xla", "source": "structural", "count": 1}}
-    chip_smoke.check_structural("lm_sharded", ok, "fusion(...)")
-    with pytest.raises(RuntimeError, match="calls a Pallas kernel"):
-        chip_smoke.check_structural("lm_sharded", ok, "custom_call_target=\"tpu_custom_call\"")
-    heur = {k: dict(d, source="heuristic") for k, d in ok.items()}
-    with pytest.raises(RuntimeError, match="not on the structural XLA path"):
-        chip_smoke.check_structural("lm_sharded", heur, "fusion(...)")
+    """The four-chip phase's check: every GEMM on a Pallas kernel per
+    shard, and the compiled decode step calls one."""
+    ok = {"8x64x64|elp_bsd_a4|nib": {"impl": "pallas_fused", "source": "sharded", "count": 1}}
+    chip_smoke.check_sharded("lm_sharded", ok, "custom_call_target=\"tpu_custom_call\"")
+    with pytest.raises(RuntimeError, match="no tpu_custom_call"):
+        chip_smoke.check_sharded("lm_sharded", ok, "fusion(...)")
+    xla = {k: dict(d, impl="xla") for k, d in ok.items()}
+    with pytest.raises(RuntimeError, match="not on a Pallas kernel"):
+        chip_smoke.check_sharded("lm_sharded", xla, "custom_call_target=\"tpu_custom_call\"")
+    whole = {k: dict(d, source="heuristic") for k, d in ok.items()}
+    with pytest.raises(RuntimeError, match="not run per shard"):
+        chip_smoke.check_sharded("lm_sharded", whole, "custom_call_target=\"tpu_custom_call\"")
 
 
 def test_chip_smoke_sharded_phase_rehearsal():
+    """Reduced qwen3-8b on a 4-device mesh through the Pallas kernels
+    per shard (interpret mode) against the one-device engine. Interpret
+    mode emits no ``tpu_custom_call`` and the CPU heuristic reports XLA,
+    so the phase's check must refuse what it sees here."""
     out = _run(
         """
         import dataclasses, importlib.util
         from repro.configs import get_config
+        from repro.kernels.ops import force_impl
         spec = importlib.util.spec_from_file_location("chip_smoke", "chip_smoke.py")
         cs = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(cs)
+        check, seen = cs.check_sharded, []
+        cs.check_sharded = lambda *a: seen.append(a)
         cfg = dataclasses.replace(get_config("qwen3_8b").reduced(), dtype_str="bfloat16")
-        res = cs.phase_lm_sharded(cfg, seed=0, n_slots=4, prompt_lens=(16, 40, 24, 16),
-                                  max_new=4, check_len=16, chips=4)
+        with force_impl("pallas"):
+            res = cs.phase_lm_sharded(cfg, seed=0, n_slots=4, prompt_lens=(16, 40, 24, 16),
+                                      max_new=4, check_len=16, chips=4)
         assert max(res["errs"].values()) <= cs.LM_TOL
+        [(tag, dispatch, hlo)] = seen
+        assert {d["source"] for d in dispatch.values()} == {"sharded"}
+        try:
+            check(tag, dispatch, hlo)
+        except RuntimeError as e:
+            assert "not on a Pallas kernel" in str(e), e
+        else:
+            raise AssertionError("the CPU's XLA dispatch passed the kernel check")
         print("OK", res["token_match"])
         """,
         devices=4,

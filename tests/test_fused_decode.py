@@ -246,7 +246,7 @@ def test_tpu_rule_tiles_divide_qwen3_8b_shapes(tpu_miss, k, n, m, nibble):
 
     impl, (bm, bn, bk), source = select_impl(
         m, k, n, fmt_name="elp_bsd_a4" if nibble else "elp_bsd_c6", nibble=nibble,
-        stacked=False, devices=1,
+        stacked=False,
     )
     assert source == "heuristic"
     assert impl == ("pallas_fused" if m <= MAX_FUSED_M else "pallas")
@@ -277,8 +277,7 @@ def test_select_impl_off_tpu_keeps_xla_and_default_blocks(monkeypatch, tmp_path)
     from repro.kernels.ops import select_impl
 
     monkeypatch.setenv(autotune.CACHE_ENV, str(tmp_path / "empty.json"))
-    got = select_impl(16, 4096, 4096, fmt_name="elp_bsd_a4", nibble=True,
-                      stacked=False, devices=1)
+    got = select_impl(16, 4096, 4096, fmt_name="elp_bsd_a4", nibble=True, stacked=False)
     assert got == ("xla", None, "heuristic")
     assert autotune.lookup_blocks(16, 4096, 4096, fmt_name="elp_bsd_a4", nibble=True) == (
         autotune.DEFAULT_BLOCKS

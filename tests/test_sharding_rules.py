@@ -142,6 +142,43 @@ def test_cache_spec_head_then_hd_then_seq():
     assert tuple(s2) == (None, "data", "model", None, None)
 
 
+def test_cache_spec_kv_leaves_take_heads_first():
+    """A dense cache's k/v put whole KV heads on each device when the
+    heads divide the model axis (yi-34b: 8 over 4), so attention over
+    the cache needs no collective; else head_dim, as before."""
+    mesh = FakeMesh({"data": 1, "model": 4})
+    for leaf in ("k", "v"):
+        path = (jax.tree_util.DictKey(leaf),)
+        s = shr.cache_spec(path, (60, 32, 1280, 8, 128), mesh)
+        assert tuple(s) == (None, "data", None, "model", None)
+        s = shr.cache_spec(path, (2, 4, 48, 2, 16), mesh)
+        assert tuple(s) == (None, "data", None, None, "model")
+
+
+def test_mark_splits_records_each_packed_weights_split():
+    from repro.core.elp_bsd import FORMAT_A
+    from repro.kernels.ops import pack_weight
+
+    def build():
+        w_col = jax.random.normal(jax.random.PRNGKey(0), (3, 64, 128))
+        w_row = jax.random.normal(jax.random.PRNGKey(1), (3, 128, 64))
+        experts = jax.random.normal(jax.random.PRNGKey(2), (3, 4, 64, 32))
+        return {
+            "wq": pack_weight(w_col, FORMAT_A, granularity="per_slice")[0],
+            "wo": pack_weight(w_row, FORMAT_A, granularity="per_slice")[0],
+            "we1": pack_weight(experts, FORMAT_A, granularity="per_slice")[0],
+            "ln1": jax.numpy.zeros((3, 64)),
+        }
+
+    atree = jax.eval_shape(build)
+    mesh = FakeMesh({"data": 2, "model": 4})
+    marked = shr.mark_splits(atree, shr.param_specs(atree, mesh), mesh)
+    assert marked["wq"].split == (None, "model") and marked["wq"].mesh is mesh
+    assert marked["wo"].split == ("model", None)
+    assert marked["we1"].split == (None, None)  # the expert dim is split, not K or N
+    assert marked["ln1"] is atree["ln1"] and atree["wq"].split is None
+
+
 def test_zero1_extends_over_data():
     base = P(None, None, "model")
     z = shr.zero1_spec(base, (40, 4096, 11008), MESH)

@@ -6,8 +6,10 @@ kernels to the TPU compiler for a v5e that is described, not attached:
 qwen3-8b's four GEMM shapes at decode (M = 8 slots) and prefill
 (M = 512 tokens), in nibble (elp_bsd_a4) and u8 (elp_bsd_c6) codes, the
 same shapes through ``impl="auto"`` as a TPU resolves them at an
-autotune-cache miss (M = 16 and 512, the tile rule's blocks), and one
-VGG-16 im2col conv through the ops wrapper. Nothing runs, so these say
+autotune-cache miss (M = 16 and 512, the tile rule's blocks), one
+VGG-16 im2col conv through the ops wrapper, and yi-34b's GEMMs as one
+chip of a 1x4 model mesh computes them: each local shape alone, and the
+per-shard path whole on four described chips. Nothing runs, so these say
 nothing about results or times.
 
 The topology is described inside a module fixture, never at import:
@@ -28,14 +30,15 @@ from repro.kernels.fused_decode import fused_decode_matmul
 from repro.kernels.ops import PackedWeight, quantized_matmul, tpu_blocks
 
 QWEN3_8B_KN = [(4096, 4096), (4096, 1024), (4096, 12288), (12288, 4096)]
+# yi-34b's per-chip GEMMs on a 1x4 model mesh: wq, wk/wv, wo, w1/w3, w2
+YI_34B_LOCAL_KN = [(7168, 1792), (7168, 256), (1792, 7168), (7168, 5120), (5120, 7168)]
 LAYOUTS = [("elp_bsd_a4", True), ("elp_bsd_c6", False)]
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
 
     with pytest.MonkeyPatch.context() as mp:
         mp.setenv("TPU_LOG_DIR", "disabled")
@@ -49,10 +52,17 @@ def one_chip():
         jax.config.update("jax_enable_compilation_cache", False)
         compilation_cache.reset_cache()
         try:
-            yield SingleDeviceSharding(topo.devices[0])
+            yield topo
         finally:
             jax.config.update("jax_enable_compilation_cache", was)
             compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
 
 
 @pytest.fixture
@@ -168,3 +178,60 @@ def test_kernel_dispatch_reports_tiles_and_operand_dtype(tpu_miss):
         assert d["impl"] == "pallas_fused" and d["source"] == "heuristic", key
         assert d["blocks"] == tpu_blocks(m, k, n, nibble=key.endswith("|nib"))[1], key
         assert d["operand"] == "bfloat16", key
+
+
+@pytest.mark.parametrize("m", [32, 1024], ids=["decode", "prefill"])
+@pytest.mark.parametrize("k,n", YI_34B_LOCAL_KN)
+def test_auto_dispatch_compiles_at_yi_34b_shard(one_chip, tpu_miss, k, n, m):
+    """One chip's share of each yi-34b GEMM on a 1x4 model mesh, through
+    ``impl="auto"`` as a TPU resolves the local shape: 32 slots decoding,
+    a 1024-token prefill."""
+    pw = PackedWeight(
+        codes=jax.ShapeDtypeStruct((k // 2, n), jnp.uint8, sharding=one_chip),
+        sf=jax.ShapeDtypeStruct((1, 1), jnp.float32, sharding=one_chip),
+        fmt_name="elp_bsd_a4",
+        nibble=True,
+        shape=(k, n),
+    )
+    x = jax.ShapeDtypeStruct((m, k), jnp.bfloat16, sharding=one_chip)
+    text = _compiled_text(
+        lambda x, pw: quantized_matmul(x, pw, impl="auto", block_sizes="auto", out_dtype=x.dtype),
+        x,
+        pw,
+    )
+    assert re.search(r"%_quantized_matmul\S* = \S+ custom-call\(.*tpu_custom_call", text)
+
+
+@pytest.mark.parametrize("name,k,n", [("wq", 7168, 7168), ("wo", 7168, 7168)])
+def test_sharded_gemm_compiles_on_four_chips(topo, tpu_miss, name, k, n):
+    """The per-shard path on a described 1x4 mesh: a column-parallel
+    weight runs the kernel on each chip's columns; a row-parallel one on
+    each chip's rows, then one all-reduce of the partial sums."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.runtime import sharding as shr
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(1, 4), ("data", "model"))
+    apw = PackedWeight(
+        codes=jax.ShapeDtypeStruct((k // 2, n), jnp.uint8),
+        sf=jax.ShapeDtypeStruct((1, 1), jnp.float32),
+        fmt_name="elp_bsd_a4",
+        nibble=True,
+        shape=(k, n),
+    )
+    specs = shr.param_specs({name: apw}, mesh)
+    placed = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=NamedSharding(mesh, s)),
+        {name: apw}, specs,
+    )
+    pw = shr.mark_splits(placed, specs, mesh)[name]
+    x = jax.ShapeDtypeStruct((32, k), jnp.bfloat16, sharding=NamedSharding(mesh, P()))
+    text = _compiled_text(
+        lambda x, pw: quantized_matmul(x, pw, impl="auto", block_sizes="auto", out_dtype=x.dtype),
+        x,
+        pw,
+    )
+    assert "tpu_custom_call" in text
+    reduces = re.findall(r"= \S+ all-reduce(?:-start)?\(", text)
+    assert len(reduces) == (1 if name == "wo" else 0), reduces
