@@ -100,13 +100,64 @@ def apply_rope(x: Array, cos: Array, sin: Array) -> Array:
 # Attention
 # ---------------------------------------------------------------------------
 def repeat_kv(k: Array, n_rep: int) -> Array:
-    """[B, S, KV, hd] -> [B, S, KV*n_rep, hd] for GQA."""
+    """[B, S, KV, hd] -> [B, S, KV*n_rep, hd]: the MHA view of a GQA cache.
+
+    The attention functions below take k/v un-repeated; this is the
+    reference the tests compare them against.
+    """
     if n_rep == 1:
         return k
     b, s, kv, hd = k.shape
     return jnp.broadcast_to(k[:, :, :, None, :], (b, s, kv, n_rep, hd)).reshape(
         b, s, kv * n_rep, hd
     )
+
+
+def gqa_scores(q: Array, k: Array) -> Array:
+    """Scaled scores ``[B, KV, H/KV, Sq, Sk]`` of ``q[B, Sq, H, hd]``
+    against ``k[B, Sk, KV, hd]``; query head ``h`` attends KV head
+    ``h // (H/KV)``, the order :func:`repeat_kv` makes (``KV == H`` is MHA).
+
+    Both operands enter in their stored dtype and accumulate in f32: a
+    bf16 product is exact in f32, so this rounds nothing an f32 upcast
+    would keep, and each KV head is read once per query group, with no
+    repeated or f32 copy of ``k`` written out.
+    """
+    b, sq, h, hd = q.shape
+    kv = k.shape[2]
+    qg = q.reshape(b, sq, kv, h // kv, hd)
+    logits = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k, preferred_element_type=F32)
+    return logits / math.sqrt(hd)
+
+
+def gqa_values(p: Array, v: Array) -> Array:
+    """``p[B, KV, H/KV, Sq, Sk]`` (f32 probabilities) times
+    ``v[B, Sk, KV, hd]`` -> ``[B, Sq, H, hd]`` in f32.
+
+    ``HIGHEST`` keeps the probabilities unrounded (a single bf16 pass
+    would round them); ``v``'s upcast fuses into the product.
+    """
+    b, kv, rep, sq, _ = p.shape
+    out = jnp.einsum(
+        "bgrqk,bkgd->bqgrd", p, v, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=F32,
+    )
+    return out.reshape(b, sq, kv * rep, v.shape[-1])
+
+
+def _query_mask(q_offset: int | Array, sq: int, kpos: Array, causal: bool, window: int) -> Array:
+    """Which keys each query may see, broadcastable against scores
+    ``[B, KV, H/KV, Sq, Sk]``. A scalar ``q_offset`` places every row's
+    queries alike (mask ``[Sq, Sk]``); a ``[B]`` vector places each row
+    at its own depth (mask ``[B, 1, 1, Sq, Sk]``)."""
+    q_offset = jnp.asarray(q_offset)
+    qpos = (q_offset[:, None] if q_offset.ndim == 1 else q_offset) + jnp.arange(sq)
+    mask = jnp.ones(qpos.shape + kpos.shape, bool)
+    if causal:
+        mask &= qpos[..., None] >= kpos
+    if window:
+        mask &= qpos[..., None] - kpos < window
+    return mask[:, None, None] if mask.ndim == 3 else mask
 
 
 def attention_dot(
@@ -118,30 +169,20 @@ def attention_dot(
     window: int = 0,
     q_offset: int | Array = 0,
 ) -> Array:
-    """Plain O(S^2) attention. q[B,Sq,H,hd], k/v[B,Sk,H,hd].
+    """Plain O(S^2) attention. q[B,Sq,H,hd], k/v[B,Sk,KV,hd], KV | H.
 
+    GQA attends per query group over k/v as stored (:func:`gqa_scores`,
+    :func:`gqa_values`); softmax in f32, the output in q's dtype.
     ``q_offset`` positions the queries for causal/window masking: a
     scalar offsets every row identically; a ``[B]`` vector gives each
     row its own offset (continuous-batching decode, where every slot
     sits at a different depth into its own cache).
     """
-    b, sq, h, hd = q.shape
-    sk = k.shape[1]
-    scale = 1.0 / math.sqrt(hd)
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q.astype(F32), k.astype(F32)) * scale
-    q_offset = jnp.asarray(q_offset)
-    # qpos: [sq] (shared offset) or [B, sq] (per-row offsets)
-    qpos = (q_offset[:, None] if q_offset.ndim == 1 else q_offset) + jnp.arange(sq)
-    kpos = jnp.arange(sk)
-    mask = jnp.ones(qpos.shape + (sk,), bool)
-    if causal:
-        mask &= qpos[..., None] >= kpos
-    if window:
-        mask &= qpos[..., None] - kpos < window
-    logits = jnp.where(mask[:, None] if mask.ndim == 3 else mask[None, None], logits, -1e30)
+    sq, sk = q.shape[1], k.shape[1]
+    mask = _query_mask(q_offset, sq, jnp.arange(sk), causal, window)
+    logits = jnp.where(mask, gqa_scores(q, k), -1e30)
     probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhqk,bkhd->bqhd", probs, v.astype(F32))
-    return out.astype(q.dtype)
+    return gqa_values(probs, v).astype(q.dtype)
 
 
 def attention_chunked(
@@ -156,47 +197,38 @@ def attention_chunked(
 ) -> Array:
     """Flash-style attention: scan over KV chunks with running max/sum.
 
-    Memory O(Sq · chunk); HLO is one scan body regardless of S. Equals
-    :func:`attention_dot` to float tolerance (property-tested).
+    Memory O(Sq · chunk); HLO is one scan body regardless of S. Takes
+    k/v ``[B, Sk, KV, hd]`` and equals :func:`attention_dot` to float
+    tolerance (property-tested), at the same precision.
     ``q_offset`` positions the queries exactly as in
     :func:`attention_dot` (scalar shared offset or per-row ``[B]``).
     """
     b, sq, h, hd = q.shape
-    sk = k.shape[1]
+    sk, kv = k.shape[1], k.shape[2]
     assert sk % chunk == 0, (sk, chunk)
-    scale = 1.0 / math.sqrt(hd)
-    qf = q.astype(F32) * scale
     n_chunks = sk // chunk
-    kc = k.reshape(b, n_chunks, chunk, h, hd)
-    vc = v.reshape(b, n_chunks, chunk, h, hd)
-    q_offset = jnp.asarray(q_offset)
-    # qpos: [sq] (shared offset) or [B, sq] (per-row offsets)
-    qpos = (q_offset[:, None] if q_offset.ndim == 1 else q_offset) + jnp.arange(sq)
+    kc = k.reshape(b, n_chunks, chunk, kv, hd)
+    vc = v.reshape(b, n_chunks, chunk, kv, hd)
 
     def body(carry, inp):
         m, l, acc = carry
         kb, vb, c_idx = inp
-        logits = jnp.einsum("bqhd,bkhd->bhqk", qf, kb.astype(F32))
-        kpos = c_idx * chunk + jnp.arange(chunk)
-        msk = jnp.ones(qpos.shape + (chunk,), bool)
-        if causal:
-            msk &= qpos[..., None] >= kpos
-        if window:
-            msk &= qpos[..., None] - kpos < window
-        logits = jnp.where(msk[:, None] if msk.ndim == 3 else msk[None, None], logits, -1e30)
+        msk = _query_mask(q_offset, sq, c_idx * chunk + jnp.arange(chunk), causal, window)
+        logits = jnp.where(msk, gqa_scores(q, kb), -1e30)
         m_new = jnp.maximum(m, logits.max(-1))
         p = jnp.exp(logits - m_new[..., None])
         corr = jnp.exp(m - m_new)
         l_new = l * corr + p.sum(-1)
-        acc_new = acc * corr[..., None] + jnp.einsum("bhqk,bkhd->bhqd", p, vb.astype(F32))
-        return (m_new, l_new, acc_new), None
+        pv = jnp.einsum(
+            "bgrqk,bkgd->bgrqd", p, vb, precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=F32,
+        )
+        return (m_new, l_new, acc * corr[..., None] + pv), None
 
-    m0 = jnp.full((b, h, sq), -1e30, F32)
-    l0 = jnp.zeros((b, h, sq), F32)
-    a0 = jnp.zeros((b, h, sq, hd), F32)
+    stat = (b, kv, h // kv, sq)
     (m, l, acc), _ = jax.lax.scan(
         body,
-        (m0, l0, a0),
+        (jnp.full(stat, -1e30, F32), jnp.zeros(stat, F32), jnp.zeros(stat + (hd,), F32)),
         (
             jnp.moveaxis(kc, 1, 0),
             jnp.moveaxis(vc, 1, 0),
@@ -204,7 +236,7 @@ def attention_chunked(
         ),
     )
     out = acc / jnp.maximum(l, 1e-30)[..., None]
-    return jnp.einsum("bhqd->bqhd", out).astype(q.dtype)
+    return jnp.einsum("bgrqd->bqgrd", out).reshape(b, sq, h, hd).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------

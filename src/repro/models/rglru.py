@@ -24,7 +24,16 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
-from repro.models.layers import cross_entropy, dense_init, matmul, mlp_apply, rms_norm, rope_embed
+from repro.models.layers import (
+    cross_entropy,
+    dense_init,
+    gqa_scores,
+    gqa_values,
+    matmul,
+    mlp_apply,
+    rms_norm,
+    rope_embed,
+)
 from repro.models import transformer as T
 
 Array = jax.Array
@@ -198,18 +207,16 @@ def _ring_qkv(lp, cfg, xn, positions):
 def _ring_prefill(lp, cfg, xn, kv_cache, ) -> tuple[Array, tuple[Array, Array]]:
     """Windowed prefill: normal local attention over S, then fill the
     ring with the last W keys/values (slot for position p = p mod W)."""
-    from repro.models.layers import attention_chunked, attention_dot, repeat_kv
+    from repro.models.layers import attention_chunked, attention_dot
 
     ck, cv = kv_cache
     w = ck.shape[1]
     b, s, _ = xn.shape
     q, k, v = _ring_qkv(lp, cfg, xn, jnp.arange(s)[None])
-    kf = repeat_kv(k, cfg.n_heads // cfg.n_kv_heads)
-    vf = repeat_kv(v, cfg.n_heads // cfg.n_kv_heads)
     if s >= T.CHUNKED_ATTN_THRESHOLD:
-        out = attention_chunked(q, kf, vf, causal=True, window=cfg.window)
+        out = attention_chunked(q, k, v, causal=True, window=cfg.window)
     else:
-        out = attention_dot(q, kf, vf, causal=True, window=cfg.window)
+        out = attention_dot(q, k, v, causal=True, window=cfg.window)
     if s >= w:
         ring_k = jnp.roll(k[:, -w:], s % w, axis=1).astype(ck.dtype)
         ring_v = jnp.roll(v[:, -w:], s % w, axis=1).astype(cv.dtype)
@@ -225,12 +232,9 @@ def _ring_prefill(lp, cfg, xn, kv_cache, ) -> tuple[Array, tuple[Array, Array]]:
 def _ring_decode(lp, cfg, xn, kv_cache, pos) -> tuple[Array, tuple[Array, Array]]:
     """One-token decode against the W-slot ring (O(window) memory —
     what makes recurrentgemma long_500k constant-state)."""
-    import math as _math
-
     ck, cv = kv_cache
     w = ck.shape[1]
     b = xn.shape[0]
-    h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     q, k, v = _ring_qkv(lp, cfg, xn, pos[None, None] if jnp.ndim(pos) == 0 else pos)
     slot = pos % w
     ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype), (0, slot, 0, 0))
@@ -239,12 +243,9 @@ def _ring_decode(lp, cfg, xn, kv_cache, pos) -> tuple[Array, tuple[Array, Array]
     i = jnp.arange(w)
     p_i = pos - jnp.mod(pos - i, w)
     valid = p_i >= 0
-    kf = jnp.repeat(ck.astype(F32), h // kv, axis=2)  # [B, W, H, hd]
-    vf = jnp.repeat(cv.astype(F32), h // kv, axis=2)
-    logits = jnp.einsum("bqhd,bwhd->bhqw", q.astype(F32), kf) / _math.sqrt(hd)
-    logits = jnp.where(valid[None, None, None, :], logits, -1e30)
+    logits = jnp.where(valid, gqa_scores(q, ck), -1e30)
     probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhqw,bwhd->bqhd", probs, vf).astype(xn.dtype)
+    out = gqa_values(probs, cv).astype(xn.dtype)
     return matmul(out.reshape(b, 1, -1), lp["wo"]), (ck, cv)
 
 

@@ -50,7 +50,6 @@ from repro.models.layers import (
     dense_init,
     matmul,
     mlp_apply,
-    repeat_kv,
     rms_norm,
     rope_embed,
 )
@@ -191,7 +190,7 @@ def _attention(
             acts["v_cache"] = v
         with jax.named_scope("attn_core"):
             out, new_cache = _attend(
-                q, k, v, cfg, x.dtype, causal=causal, window=window, kv_cache=kv_cache,
+                q, k, v, x.dtype, causal=causal, window=window, kv_cache=kv_cache,
                 kv_layout=kv_layout, cache_pos=cache_pos, pctx=pctx,
             )
         mix = out.reshape(b, s, h * hd)
@@ -204,7 +203,6 @@ def _attend(
     q: Array,
     k: Array,
     v: Array,
-    cfg: ArchConfig,
     dtype,
     *,
     causal: bool,
@@ -219,7 +217,6 @@ def _attend(
     cache's (or the run's own) k/v. Returns ``(out[b, s, h, hd],
     updated cache tuple or None)``."""
     s = q.shape[1]
-    h, kv = cfg.n_heads, cfg.n_kv_heads
     new_cache = None
     if kv_cache is not None and kv_layout == "quant":
         # int8-quantized cache (per-token-head scales)
@@ -295,18 +292,15 @@ def _attend(
 
         out = flash_decode_attention(q, k, v, cache_pos, pctx=pctx, window=window)
         return out, new_cache
-    kf = repeat_kv(k, h // kv)
-    vf = repeat_kv(v, h // kv)
-    if decode:
-        # decode: s queries per row against the cache, each masked to
-        # its own row's depth
-        out = attention_dot(q, kf, vf, causal=causal, window=window, q_offset=q_offset)
-    elif kf.shape[1] >= CHUNKED_ATTN_THRESHOLD:
+    # GQA attends per query group over k/v as they are (the cache as
+    # stored): no repeated or f32 copy of either is made. Decode's s
+    # queries per row are each masked to their own row's depth.
+    if not decode and k.shape[1] >= CHUNKED_ATTN_THRESHOLD:
         out = attention_chunked(
-            q, kf, vf, causal=causal, window=window, chunk=ATTN_CHUNK, q_offset=q_offset
+            q, k, v, causal=causal, window=window, chunk=ATTN_CHUNK, q_offset=q_offset
         )
     else:
-        out = attention_dot(q, kf, vf, causal=causal, window=window, q_offset=q_offset)
+        out = attention_dot(q, k, v, causal=causal, window=window, q_offset=q_offset)
     return out, new_cache
 
 

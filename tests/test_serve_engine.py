@@ -389,9 +389,10 @@ def test_multi_device_engine_parity():
     """On a fake 4-device mesh the engine (auto elastic mesh, sharded
     packed weights, flash-decode variant) is token-identical to
     single-device per-request static generation, and the decode step
-    consumes sharded uint8 code leaves."""
+    consumes sharded uint8 code leaves and makes no dequantized f32 tree."""
     run_in_subprocess(
         """
+        import math, re
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh
         from repro.configs.base import ArchConfig
@@ -428,12 +429,24 @@ def test_multi_device_engine_parity():
             print(tag, "parity OK")
 
         # decode consumes SHARDED uint8 codes (no dequantized tree)
-        eng = ServeEngine(CFG, packed, n_slots=2, max_len=64, mesh="auto")
+        # (a short cache, so no cache value is as large as an FFN weight)
+        eng = ServeEngine(CFG, packed, n_slots=2, max_len=16, mesh="auto")
         wq = eng.params["blocks"]["wq"]
         assert wq.codes.dtype == jnp.uint8
         assert "model" in tuple(wq.codes.sharding.spec)
-        engf = ServeEngine(CFG, params, n_slots=2, max_len=64, mesh="auto")
-        assert eng.decode_cost()["bytes_accessed"] < engf.decode_cost()["bytes_accessed"]
+        engf = ServeEngine(CFG, params, n_slots=2, max_len=16, mesh="auto")
+        # ... and the packed decode program materialises no f32 value as
+        # large as a chip's share of a stacked FFN weight, which the float
+        # program takes in whole
+        w1 = engf.params["blocks"]["w1"]
+        n = math.prod(w1.sharding.shard_shape(w1.shape))
+
+        def f32_values_of_at_least(e):
+            dims = re.findall(r"\\bf32\\[([\\d,]+)\\]", e.compiled_decode().as_text())
+            return {d for d in dims if math.prod(map(int, d.split(","))) >= n}
+
+        assert not f32_values_of_at_least(eng), f32_values_of_at_least(eng)
+        assert f32_values_of_at_least(engf)
 
         # elastic reshard onto a different mesh layout
         mesh22 = Mesh(np.asarray(jax.devices()).reshape(2, 2), ("data", "model"))

@@ -9,14 +9,17 @@ same shapes through ``impl="auto"`` as a TPU resolves them at an
 autotune-cache miss (M = 16 and 512, the tile rule's blocks), one
 VGG-16 im2col conv through the ops wrapper, and yi-34b's GEMMs as one
 chip of a 1x4 model mesh computes them: each local shape alone, and the
-per-shard path whole on four described chips. Nothing runs, so these say
-nothing about results or times.
+per-shard path whole on four described chips; and the decode attention
+over the dense bf16 cache at both LM cells' shapes, where GQA must read
+the cache as stored. Nothing runs, so these say nothing about results
+or times.
 
 The topology is described inside a module fixture, never at import:
 only one process may load the TPU library at a time, and every test
 worker imports this file.
 """
 import dataclasses
+import math
 import re
 
 import pytest
@@ -235,3 +238,66 @@ def test_sharded_gemm_compiles_on_four_chips(topo, tpu_miss, name, k, n):
     assert "tpu_custom_call" in text
     reduces = re.findall(r"= \S+ all-reduce(?:-start)?\(", text)
     assert len(reduces) == (1 if name == "wo" else 0), reduces
+
+
+# the LM cells' decode attention: slots, max_len, query heads, KV heads,
+# and the mesh's model-axis width (yi-34b: 14 query, 2 KV heads a chip)
+ATTEND_DECODE = {"qwen3-8b": (16, 1024, 32, 8, 1), "yi-34b": (32, 1280, 56, 8, 4)}
+
+
+def _tensors_of_at_least(text: str, n: int) -> set[tuple[str, str]]:
+    """``(dtype[shape], opcode)`` of every value of ``n`` or more
+    elements in compiled HLO text, fused computations included."""
+    found = set()
+    for line in text.splitlines():
+        op = re.search(r"= \S+ ([\w-]+)\(", line)
+        for dt, dims in re.findall(r"\b([a-z]+\d*)\[([\d,]+)\]", line):
+            if math.prod(int(d) for d in dims.split(",")) >= n:
+                found.add((f"{dt}[{dims}]", op.group(1) if op else None))
+    return found
+
+
+@pytest.mark.parametrize("model,bound_gb", [("qwen3-8b", 0.25), ("yi-34b", 0.16)])
+def test_gqa_decode_attention_reads_the_cache_as_stored(topo, model, bound_gb):
+    """``_attend``'s decode call (write this step's k/v, attend at per-row
+    depths) over the dense bf16 cache: qwen3-8b on one chip, yi-34b as
+    each chip of a 1x4 model mesh runs it, heads on ``model``. No f32
+    value the size of a chip's cache or larger is made, nothing that
+    size is broadcast, and no collective is added. The attention over the
+    written cache reads under ``bound_gb`` per layer per chip by the
+    compiler's count (repeating K and V to every head in f32 read 1.28 GB
+    and 1.46 GB)."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.models import transformer as T
+    from repro.models.layers import attention_dot
+
+    b, s, h, kv, tp = ATTEND_DECODE[model]
+    mesh = Mesh(np.asarray(topo.devices[:tp]).reshape(1, tp), ("data", "model"))
+    heads = NamedSharding(mesh, P(None, None, "model", None))
+    bf16 = jnp.bfloat16
+    q = jax.ShapeDtypeStruct((b, 1, h, 128), bf16, sharding=heads)
+    new = jax.ShapeDtypeStruct((b, 1, kv, 128), bf16, sharding=heads)
+    cache = jax.ShapeDtypeStruct((b, s, kv, 128), bf16, sharding=heads)
+    pos = jax.ShapeDtypeStruct((b,), jnp.int32, sharding=NamedSharding(mesh, P()))
+
+    def attend(q, k, v, ck, cv, pos):
+        return T._attend(
+            q, k, v, bf16, causal=True, window=0, kv_cache=(ck, cv), kv_layout="dense",
+            cache_pos=pos, pctx=None,
+        )
+
+    text = _compiled_text(attend, q, new, new, cache, cache, pos)
+    big = _tensors_of_at_least(text, b * s * (kv // tp) * 128)
+    assert not [t for t in big if t[0].startswith("f32") or t[1] == "broadcast"], big
+    assert not re.search(r"all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all", text)
+
+    cost = (
+        jax.jit(lambda q, ck, cv, pos: attention_dot(q, ck, cv, q_offset=pos))
+        .lower(q, cache, cache, pos)
+        .compile()
+        .cost_analysis()
+    )
+    cost = cost[0] if isinstance(cost, list) else cost
+    assert cost["bytes accessed"] < bound_gb * 1e9, cost["bytes accessed"]
