@@ -1,10 +1,7 @@
 #!/usr/bin/env bash
-# Full benchmark refresh: re-tunes the kernel block-size cache, then
-# runs BOTH workload tiers (smoke + full) of both suites and rewrites
-# the committed baselines at the repo root:
-#
-#   src/repro/bench/autotune_cache.json   block-size autotune cache
-#   BENCH_kernels.json / BENCH_e2e.json   benchmark baselines
+# Full benchmark refresh: runs BOTH workload tiers (smoke + full) of
+# both suites and rewrites the committed baselines at the repo root,
+# BENCH_kernels.json and BENCH_e2e.json.
 #
 # Run this (and commit the result) whenever a PR intentionally changes
 # performance or adds workloads; CI's bench-smoke job gates every PR's
@@ -16,8 +13,8 @@ cd "$(dirname "$0")/.."
 
 export PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH}
 
-echo "== autotune + full benchmark run =="
-python -m repro.bench --autotune --out-dir . "$@"
+echo "== full benchmark run =="
+python -m repro.bench --out-dir . "$@"
 
 echo "== validate emitted artifacts =="
 python -m repro.bench --validate BENCH_kernels.json BENCH_e2e.json

@@ -93,8 +93,6 @@ class ConversionReport:
     bit-packed at ``bits_per_weight`` (the HBM story an ELP_BSD decoder
     in hardware would see). ``energy_nj`` is the Table II network
     energy estimate (CNNs only — it needs a MAC count).
-    ``tuned_blocks`` records the autotune-cache tilings that matched
-    this artifact's weight shapes when ``block_sizes="auto"``.
     """
 
     fmt: str
@@ -107,7 +105,6 @@ class ConversionReport:
     baseline_accuracy: float | None = None
     accuracy: float | None = None
     energy_nj: float | None = None
-    tuned_blocks: tuple = ()
 
     @property
     def compression(self) -> float:
@@ -120,16 +117,12 @@ class ConversionReport:
         return self.baseline_accuracy - self.accuracy
 
     def to_json(self) -> dict:
-        doc = dataclasses.asdict(self)
-        doc["tuned_blocks"] = [[k, list(b)] for k, b in self.tuned_blocks]
-        return doc
+        return dataclasses.asdict(self)
 
     @classmethod
     def from_json(cls, doc: Mapping[str, Any]) -> "ConversionReport":
         kw = dict(doc)
-        kw["tuned_blocks"] = tuple(
-            (str(k), tuple(int(x) for x in b)) for k, b in kw.get("tuned_blocks", [])
-        )
+        kw.pop("tuned_blocks", None)  # written by artifacts of older versions
         return cls(**kw)
 
 
@@ -145,32 +138,6 @@ def _encoded_bytes(tree: Any) -> int:
         else:
             total += int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
     return total
-
-
-def _tuned_blocks_for(packed: Any) -> tuple:
-    """Autotune-cache entries applying to this tree's (K, N, fmt) shapes."""
-    from repro.bench.autotune import cache_entries
-
-    backend = jax.default_backend()
-    shapes = {
-        (leaf.shape, leaf.fmt_name, leaf.nibble)
-        for leaf in jax.tree.leaves(packed, is_leaf=lambda x: isinstance(x, PackedWeight))
-        if isinstance(leaf, PackedWeight)
-    }
-    out = []
-    for key, ent in cache_entries().items():
-        try:
-            bk, fmt_name, mode, mkn = key.split("|")
-            _m, kdim, ndim = (int(v) for v in mkn.split("x"))
-        except ValueError:
-            continue
-        if bk != backend:
-            continue
-        for (kn, fn, nib) in shapes:
-            if fn == fmt_name and mode == ("nib" if nib else "u8") and kn == (kdim, ndim):
-                out.append((key, tuple(ent["blocks"])))
-                break
-    return tuple(sorted(out))
 
 
 # ---------------------------------------------------------------------------
@@ -302,7 +269,6 @@ def quantize(
         baseline_accuracy=baseline_acc,
         accuracy=accuracy,
         energy_nj=energy,
-        tuned_blocks=_tuned_blocks_for(packed) if scheme.block_sizes == "auto" else (),
     )
     return QuantizedModel(
         packed, adapter, scheme, table=table, report=report, verify_params=verify_params

@@ -82,8 +82,8 @@ class QuantScheme:
 
     Execution:
       block_sizes: kernel tiling for the packed matmul/conv paths —
-        ``None`` (defaults), ``"auto"`` (autotune cache, DESIGN.md §7),
-        or an explicit ``(block_m, block_n, block_k)``.
+        ``None`` (the kernels' defaults, and the shape rule's tiles under
+        ``impl="auto"``) or an explicit ``(block_m, block_n, block_k)``.
 
     Accuracy-constraint search (Sec. V steps 1+5; active when
     :func:`repro.api.quantize` receives an ``eval_fn``):
@@ -122,7 +122,7 @@ class QuantScheme:
     pct: float = 99.9
     rho_threshold: float = 0.25
     fold_bias: bool = True
-    block_sizes: tuple[int, int, int] | str | None = None
+    block_sizes: tuple[int, int, int] | None = None
     ac: float = 0.01
     bw_max: int = 8
     bw_min: int = 4
@@ -162,15 +162,14 @@ class QuantScheme:
         if isinstance(bs, list):
             bs = tuple(bs)
             object.__setattr__(self, "block_sizes", bs)
-        ok = (
-            bs is None
-            or bs == "auto"
-            or (isinstance(bs, tuple) and len(bs) == 3 and all(isinstance(b, int) for b in bs))
+        ok = bs is None or (
+            isinstance(bs, tuple) and len(bs) == 3 and all(isinstance(b, int) for b in bs)
         )
         if not ok:
             raise ValueError(
-                f'block_sizes must be None, "auto", or a (block_m, block_n, block_k) '
-                f"tuple; got {self.block_sizes!r}"
+                f"block_sizes must be None or a (block_m, block_n, block_k) tuple; got "
+                f"{self.block_sizes!r} (the tiles under impl='auto' come from the shape "
+                f"rule, kernels/ops.select_impl)"
             )
         if self.act_bits is not None and self.act_bits < 2:
             raise ValueError(f"act_bits must be >= 2, got {self.act_bits}")

@@ -1,25 +1,19 @@
-"""Benchmark subsystem: registry-run workloads, BENCH_*.json artifacts,
-and the kernel block-size autotuner (DESIGN.md §7).
+"""Benchmark subsystem: registry-run workloads and BENCH_*.json artifacts
+(DESIGN.md §7).
 
 Entry points:
 
   * ``python -m repro.bench [--smoke]`` — run the suites, write
     ``BENCH_kernels.json`` / ``BENCH_e2e.json`` (see ``--help``).
-  * ``scripts/bench.sh`` — full refresh of baselines + autotune cache.
-  * ``quantized_matmul(..., block_sizes="auto")`` — consume the tuner's
-    persistent cache from any packed call site.
+  * ``scripts/bench.sh`` — full refresh of the baselines.
 """
-from repro.bench.autotune import DEFAULT_BLOCKS, autotune_matmul, lookup_blocks
 from repro.bench.registry import WorkloadSpec, register, specs
 from repro.bench.schema import SCHEMA_VERSION, SchemaError, validate
 
 __all__ = [
-    "DEFAULT_BLOCKS",
     "SCHEMA_VERSION",
     "SchemaError",
     "WorkloadSpec",
-    "autotune_matmul",
-    "lookup_blocks",
     "register",
     "specs",
     "validate",

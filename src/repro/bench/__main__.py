@@ -8,7 +8,6 @@ Modes:
     --only SUBSTR    filter workloads by name substring
     --out-dir DIR    where BENCH_*.json land (default: repo root)
     --iters/--warmup harness budget per measurement
-    --autotune       refresh the block-size autotune cache first
 
   --list             print registered workload names and exit
   --validate F [F..] schema-check existing BENCH json files and exit
@@ -53,7 +52,6 @@ import jax
 import numpy as np
 
 from repro.bench import registry, schema
-from repro.bench.autotune import autotune_shapes, invalidate_memory_cache
 from repro.runtime.compile_cache import enable_compile_cache
 
 REPO_ROOT = os.path.normpath(os.path.join(os.path.dirname(__file__), "..", "..", ".."))
@@ -98,7 +96,7 @@ def _collect_ratios(new_doc: dict, base: dict, min_us: float) -> list[tuple]:
     the x1.8 cliff only doubles one noisy timing's flake exposure. It
     still votes in the group geomean — and when the dispatch FLIPPED
     impls between runs, the full check applies: a flip that loses 80%
-    is exactly the autotune regression the ``selected`` key exists to
+    is exactly the dispatch regression the ``selected`` key exists to
     catch."""
     skip_pallas = base.get("backend") == "cpu"
     out = []
@@ -194,7 +192,6 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--gate-against", default=None, metavar="DIR")
     ap.add_argument("--tolerance", type=float, default=0.20)
     ap.add_argument("--min-us", type=float, default=200.0)
-    ap.add_argument("--autotune", action="store_true")
     args = ap.parse_args(argv)
     enable_compile_cache()
 
@@ -216,17 +213,6 @@ def main(argv: list[str] | None = None) -> int:
 
     iters = args.iters if args.iters is not None else 5
     warmup = args.warmup if args.warmup is not None else (1 if args.smoke else 2)
-
-    if args.autotune:
-        from repro.bench.workloads import autotune_shape_specs
-
-        shapes = autotune_shape_specs()
-        print(f"[autotune] tuning {len(shapes)} shapes", file=sys.stderr)
-        for res in autotune_shapes(shapes, iters=iters, warmup=warmup):
-            print(f"[autotune] {res['key']} -> {res['blocks']} "
-                  f"({res['wall_us']:.0f}us, {res['candidates']} candidates)",
-                  file=sys.stderr)
-        invalidate_memory_cache()
 
     failures: list[str] = []
     ratios: list[tuple] = []

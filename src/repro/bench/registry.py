@@ -30,12 +30,6 @@ class WorkloadSpec:
     returns the entry body: a dict with ``workload``, ``shape``,
     ``wall_us`` (impl-keyed timings), and optional ``hlo`` / ``quality``
     / ``bytes`` blocks (see :mod:`repro.bench.schema`).
-
-    ``autotune_shape`` is the matmul problem this workload drives
-    through the packed kernel — ``(m, k, n, fmt_name, nibble)``, the
-    im2col shape for convs — declared explicitly at registration so
-    the autotuner never has to reverse-engineer it from ``run``'s
-    closure. ``None`` means "nothing to tune" (e.g. float forwards).
     """
 
     name: str
@@ -43,7 +37,6 @@ class WorkloadSpec:
     tier: str
     run: Callable[[int, int], dict]
     tags: tuple[str, ...] = ()
-    autotune_shape: tuple[int, int, int, str, bool] | None = None
 
     def __post_init__(self):
         if self.suite not in SUITES:

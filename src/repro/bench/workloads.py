@@ -105,25 +105,15 @@ def fc_layer_shapes(spec) -> list[tuple[int, int, int]]:
 # ---------------------------------------------------------------------------
 # kernels suite
 # ---------------------------------------------------------------------------
-def _selected_impl(m, k, n, fmt_name, nibble, miss: str | None = None) -> str:
-    """The impl ``impl="auto"`` resolves to for a shape (cache winner, or
-    the fallback on a miss) — recorded next to the ``selected`` timing
-    so an autotune flip is visible in the bench entry instead of
-    masquerading as a wall-clock change. ``miss`` overrides the matmul
-    backend heuristic (the conv path falls back to ``"xla"``)."""
-    from repro.bench import autotune
-
-    sel, _ = autotune.lookup_impl(m, k, n, fmt_name=fmt_name, nibble=nibble)
-    return sel or miss or ("pallas" if jax.default_backend() == "tpu" else "xla")
-
-
-def _time_selected(fn, m, k, n, fmt_name, nibble, iters, warmup, miss: str | None = None):
+def _time_selected(fn, m, k, n, sel, iters, warmup):
     """``{"selected": timing + {"impl": name}}`` for the auto-dispatch path.
 
-    Skipped (``None``) only when auto resolves to an interpret-mode
-    Pallas grid too large to time on CPU — mirroring the bare ``pallas``
-    key's policy."""
-    sel = _selected_impl(m, k, n, fmt_name, nibble, miss=miss)
+    ``sel`` is the impl ``impl="auto"`` resolves to for the shape,
+    recorded next to the timing so a dispatch flip is visible in the
+    bench entry instead of masquerading as a wall-clock change. Skipped
+    (``None``) only when auto resolves to an interpret-mode Pallas grid
+    too large to time on CPU — mirroring the bare ``pallas`` key's
+    policy."""
     if sel == "pallas" and not _measure_pallas_cpu(m, k, n):
         return None
     t = harness.time_fn(fn, iters=iters, warmup=warmup).to_json()
@@ -132,7 +122,7 @@ def _time_selected(fn, m, k, n, fmt_name, nibble, iters, warmup, miss: str | Non
 
 def _run_matmul(m, k, n, fmt_name, nibble, iters, warmup):
     from repro.core.elp_bsd import PRESET_FORMATS
-    from repro.kernels.ops import pack_weight, quantized_matmul
+    from repro.kernels.ops import pack_weight, quantized_matmul, select_impl
 
     fmt = PRESET_FORMATS[fmt_name]
     rng = np.random.default_rng(7)
@@ -143,12 +133,13 @@ def _run_matmul(m, k, n, fmt_name, nibble, iters, warmup):
     xla_fn = lambda: quantized_matmul(x, pw, impl="xla")  # noqa: E731
     wall = {"xla": harness.time_fn(xla_fn, iters=iters, warmup=warmup).to_json()}
     if _measure_pallas_cpu(m, k, n):
-        pallas_fn = lambda: quantized_matmul(x, pw, impl="pallas", block_sizes="auto")  # noqa: E731
+        pallas_fn = lambda: quantized_matmul(x, pw, impl="pallas")  # noqa: E731
         wall["pallas"] = harness.time_fn(pallas_fn, iters=iters, warmup=warmup).to_json()
     else:
         wall["pallas"] = None
-    auto_fn = lambda: quantized_matmul(x, pw, impl="auto", block_sizes="auto")  # noqa: E731
-    wall["selected"] = _time_selected(auto_fn, m, k, n, fmt_name, nibble, iters, warmup)
+    auto_fn = lambda: quantized_matmul(x, pw, impl="auto")  # noqa: E731
+    sel = select_impl(m, k, n, nibble=nibble, stacked=False)[0]
+    wall["selected"] = _time_selected(auto_fn, m, k, n, sel, iters, warmup)
 
     bf16_bytes = k * n * 2
     return {
@@ -178,7 +169,7 @@ def _run_decode_step_fused(m, k, n, fmt_name, nibble, iters, warmup):
     exactly 0.0 off-TPU, where both impls decode bit-identically.
     """
     from repro.core.elp_bsd import PRESET_FORMATS
-    from repro.kernels.ops import pack_weight, quantized_matmul
+    from repro.kernels.ops import pack_weight, quantized_matmul, select_impl
 
     fmt = PRESET_FORMATS[fmt_name]
     rng = np.random.default_rng(13)
@@ -199,8 +190,9 @@ def _run_decode_step_fused(m, k, n, fmt_name, nibble, iters, warmup):
         wall["pallas"] = harness.time_fn(kernel_fn, iters=iters, warmup=warmup).to_json()
     else:
         wall["pallas"] = None
-    auto_fn = lambda: quantized_matmul(x, pw, impl="auto", block_sizes="auto")  # noqa: E731
-    wall["selected"] = _time_selected(auto_fn, m, k, n, fmt_name, pw.nibble, iters, warmup)
+    auto_fn = lambda: quantized_matmul(x, pw, impl="auto")  # noqa: E731
+    sel = select_impl(m, k, n, nibble=pw.nibble, stacked=False)[0]
+    wall["selected"] = _time_selected(auto_fn, m, k, n, sel, iters, warmup)
 
     ref = np.asarray(dequant_fn())
     fused_diff = float(np.max(np.abs(np.asarray(fused_fn()) - ref)))
@@ -244,16 +236,13 @@ def _run_conv2d(net, idx, layer_k, stride, batch, hw, cin, cout, fmt_name, iters
     m_im2col = batch * _ceil_div(hw, stride) ** 2
     kdim = layer_k * layer_k * cin
     if _measure_pallas_cpu(m_im2col, kdim, cout):
-        pallas_fn = lambda: quantized_conv2d(  # noqa: E731
-            x, pw, stride=stride, impl="pallas", block_sizes="auto"
-        )
+        pallas_fn = lambda: quantized_conv2d(x, pw, stride=stride, impl="pallas")  # noqa: E731
         wall["pallas"] = harness.time_fn(pallas_fn, iters=iters, warmup=warmup).to_json()
     else:
         wall["pallas"] = None
     auto_fn = lambda: quantized_conv2d(x, pw, stride=stride, impl="auto")  # noqa: E731
-    wall["selected"] = _time_selected(
-        auto_fn, m_im2col, kdim, cout, fmt_name, pw.nibble, iters, warmup, miss="xla"
-    )
+    # conv impl="auto" is XLA's conv (kernels/conv.quantized_conv2d)
+    wall["selected"] = _time_selected(auto_fn, m_im2col, kdim, cout, "xla", iters, warmup)
 
     ref = jax.lax.conv_general_dilated(
         x, w, (stride, stride), "SAME", dimension_numbers=("NHWC", "HWIO", "NHWC")
@@ -308,7 +297,6 @@ def _register_kernel_suite() -> None:
                     tier=tier,
                     run=functools.partial(_run_matmul, m, k, n, fmt_name, nibble),
                     tags=("matmul", fmt_name),
-                    autotune_shape=(m, k, n, fmt_name, nibble),
                 )
             )
 
@@ -324,7 +312,6 @@ def _register_kernel_suite() -> None:
                 tier="smoke",
                 run=functools.partial(_run_decode_step_fused, 4, 2048, 2048, fmt_name, nibble),
                 tags=("decode_step_fused", "matmul", fmt_name),
-                autotune_shape=(4, 2048, 2048, fmt_name, nibble),
             )
         )
 
@@ -334,7 +321,6 @@ def _register_kernel_suite() -> None:
         for idx, layer, hw, cin in conv_layer_shapes(spec):
             for tier, batch in (("smoke", 2), ("full", 32)):
                 name = f"conv2d/{spec.name}/conv{idx}/elp_bsd_a4/b{batch}"
-                m_im2col = batch * _ceil_div(hw, layer.stride) ** 2
                 register(
                     WorkloadSpec(
                         name=name,
@@ -353,13 +339,6 @@ def _register_kernel_suite() -> None:
                             "elp_bsd_a4",
                         ),
                         tags=("conv2d", spec.name),
-                        autotune_shape=(
-                            m_im2col,
-                            layer.k * layer.k * cin,
-                            layer.ch,
-                            "elp_bsd_a4",
-                            True,
-                        ),
                     )
                 )
 
@@ -392,13 +371,10 @@ def _run_cnn_fwd(spec_name, batch, variant, iters, warmup):
         float_logits = jax.jit(lambda p, a: cnn.forward(p, spec, a))(params, x)
         if variant == "packed":
             qm = api.quantize(spec, params, api.QuantScheme(fmt="elp_bsd_a4"))
-            # On TPU the packed forward drives the fused kernel with
-            # autotuned blocks; on CPU impl="xla" ignores block_sizes
-            # (interpret-mode pallas would swamp the e2e timing).
+            # On TPU the packed forward drives the fused kernel; on CPU
+            # impl="xla" (interpret-mode pallas would swamp the e2e timing).
             impl = "pallas" if jax.default_backend() == "tpu" else "xla"
-            fwd = jax.jit(
-                lambda p, a: cnn.forward(p, spec, a, impl=impl, block_sizes="auto")
-            )
+            fwd = jax.jit(lambda p, a: cnn.forward(p, spec, a, impl=impl))
         elif variant == "packed_dynamic_act":
             qm = api.quantize(
                 spec, params, api.QuantScheme(fmt="elp_bsd_a4", act="dynamic", act_bits=8)
@@ -907,22 +883,3 @@ def _register_e2e_suite() -> None:
 
 _register_kernel_suite()
 _register_e2e_suite()
-
-
-def autotune_shape_specs() -> list[tuple]:
-    """``(m, k, n, fmt, nibble)`` specs covering every registered matmul
-    and im2col'd conv shape — what ``scripts/bench.sh --autotune`` tunes.
-
-    Reads the ``autotune_shape`` each spec declared at registration (on
-    CPU, shapes whose kernel grid is too large for interpret-mode
-    timing are skipped; on TPU everything tunes)."""
-    from repro.bench.registry import specs
-
-    out = set()
-    for s in specs("kernels"):
-        if s.autotune_shape is None:
-            continue
-        m, k, n, _fmt, _nib = s.autotune_shape
-        if _measure_pallas_cpu(m, k, n):
-            out.add(s.autotune_shape)
-    return sorted(out)

@@ -21,12 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.kernels.ops import (
-    PackedWeight,
-    dequantize_nd,
-    quantized_matmul,
-    select_impl,
-)
+from repro.kernels.ops import PackedWeight, dequantize_nd, quantized_matmul
 
 Array = jax.Array
 F32 = jnp.float32
@@ -79,7 +74,7 @@ def quantized_conv2d(
     block_m: int = 128,
     block_n: int = 128,
     block_k: int = 128,
-    block_sizes: tuple[int, int, int] | str | None = None,
+    block_sizes: tuple[int, int, int] | None = None,
     out_dtype=None,
     interpret: bool | None = None,
 ) -> Array:
@@ -89,14 +84,9 @@ def quantized_conv2d(
     (``source_shape`` carries the conv layout). ``impl="pallas"`` /
     ``"pallas_fused"`` run patch extraction into the corresponding
     decode+matmul kernel; ``impl="xla"`` dequantizes and calls
-    ``lax.conv_general_dilated``. The default ``impl="auto"`` resolves
-    the im2col matmul shape through the autotune cache's measured
-    winner; on a cache miss it falls back to ``"xla"`` — never to an
-    unmeasured Pallas tiling (the seed's Pallas-by-default heuristic is
-    how the conv0-class 10x cliffs happened; DESIGN.md §14).
-    ``block_sizes`` forwards to :func:`quantized_matmul` — a tuple, or
-    ``"auto"`` to resolve the im2col matmul shape through the autotune
-    cache.
+    ``lax.conv_general_dilated``. The default ``impl="auto"`` is
+    ``"xla"``. ``block_sizes`` forwards a ``(block_m, block_n, block_k)``
+    tuple to :func:`quantized_matmul`.
     """
     with jax.named_scope("elp_bsd_conv"):
         if pw.source_shape is None or len(pw.source_shape) != 4:
@@ -104,22 +94,9 @@ def quantized_conv2d(
         kh, kw, _, cout = pw.source_shape
         out_dtype = out_dtype or x.dtype
         if impl == "auto":
-            b, h, w = x.shape[0], x.shape[1], x.shape[2]
-            ho, _ = _out_size_and_pads(h, kh, stride, padding)
-            wo, _ = _out_size_and_pads(w, kw, stride, padding)
-            impl, sel_blocks, source = select_impl(
-                b * ho * wo,
-                pw.shape[0],
-                pw.shape[1],
-                fmt_name=pw.fmt_name,
-                nibble=pw.nibble,
-                stacked=pw.codes.ndim != 2,
-            )
-            if source == "heuristic":
-                # Interim heuristic (no measurement for this shape): XLA.
-                impl = "xla"
-            elif sel_blocks is not None and (block_sizes is None or block_sizes == "auto"):
-                block_sizes = sel_blocks
+            # No chip measurement has chosen a conv route yet, so none
+            # replaces XLA's own conv (DESIGN.md §14).
+            impl = "xla"
         if impl == "xla":
             out = lax.conv_general_dilated(
                 x.astype(F32),

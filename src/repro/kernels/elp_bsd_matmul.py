@@ -29,7 +29,7 @@ Storage modes:
 
 Block shapes default to 128 multiples; the K block for nibble mode
 must be even. On a TPU, ``quantized_matmul(impl="auto")`` sizes them
-from the shape at an autotune-cache miss
+from the shape alone, by one rule
 (:func:`repro.kernels.ops.tpu_blocks`): code panels of about 1 MB per
 grid step, decoded ``SLAB_K`` rows of K at a time inside the step.
 The dot takes bf16 operands where that is exact

@@ -361,28 +361,22 @@ def tpu_blocks(m: int, k: int, n: int, *, nibble: bool) -> tuple[str, tuple[int,
 
 
 def select_impl(
-    m: int, k: int, n: int, *, fmt_name: str, nibble: bool, stacked: bool
+    m: int, k: int, n: int, *, nibble: bool, stacked: bool
 ) -> tuple[str, tuple[int, int, int] | None, str]:
     """Resolve ``impl="auto"`` for one packed matmul: ``(impl, blocks, source)``.
 
     ``m, k, n`` are the shape one device computes: a weight on a mesh
     runs per shard (:func:`quantized_matmul`), so its local shape. Stacked
     weights take the XLA path (``source="structural"``): the Pallas
-    kernels take one ``[K, N]``. Otherwise the autotune cache's measured
-    winner decides (``"autotuned"``, with its tuned blocks); a miss takes
-    the kernel and tiles :func:`tpu_blocks` derives from the shape on TPU,
-    and XLA elsewhere (``"heuristic"``; no blocks off TPU).
+    kernels take one ``[K, N]``. Otherwise the shape decides
+    (``"heuristic"``): on TPU the kernel and tiles :func:`tpu_blocks`
+    derives, elsewhere XLA with no blocks.
     """
     if stacked:
         return "xla", None, "structural"
-    from repro.bench.autotune import lookup_impl
-
-    sel, sel_blocks = lookup_impl(m, k, n, fmt_name=fmt_name, nibble=nibble)
-    if sel is None:
-        if jax.default_backend() == "tpu":
-            return (*tpu_blocks(m, k, n, nibble=nibble), "heuristic")
-        return "xla", None, "heuristic"
-    return sel, tuple(sel_blocks), "autotuned"
+    if jax.default_backend() == "tpu":
+        return (*tpu_blocks(m, k, n, nibble=nibble), "heuristic")
+    return "xla", None, "heuristic"
 
 
 _FORCED_IMPL: contextvars.ContextVar[str | None] = contextvars.ContextVar(
@@ -403,24 +397,6 @@ def force_impl(impl: str):
         yield
     finally:
         _FORCED_IMPL.reset(token)
-
-
-def _resolve_auto_impl(m0: int, k: int, n: int, pw: PackedWeight, block_sizes):
-    """Trace-time resolution of ``impl="auto"`` (see :func:`select_impl`).
-    When the caller left blocks to "auto"/default, the winner's tuned
-    blocks ride along — that is the exact configuration the cache timed.
-    """
-    impl, blocks, _ = select_impl(
-        m0,
-        k,
-        n,
-        fmt_name=pw.fmt_name,
-        nibble=pw.nibble,
-        stacked=pw.codes.ndim != 2,
-    )
-    if blocks is not None and (block_sizes is None or block_sizes == "auto"):
-        return impl, blocks
-    return impl, block_sizes
 
 
 def _pad_to(x: Array, axis: int, mult: int) -> Array:
@@ -555,7 +531,7 @@ def _quantized_matmul(
     block_m: int = 128,
     block_n: int = 128,
     block_k: int = 128,
-    block_sizes: tuple[int, int, int] | str | None = None,
+    block_sizes: tuple[int, int, int] | None = None,
     out_dtype=None,
     interpret: bool | None = None,
 ) -> Array:
@@ -570,17 +546,13 @@ def _quantized_matmul(
     kernel), ``"pallas_fused"`` (decode-step kernel — shift-add decode,
     whole-M strip; lowers to the single-pass XLA shift-add form on
     non-TPU backends, bit-identical to ``"xla"``), ``"xla"``
-    (dequantize-then-matmul fallback), or ``"auto"`` to resolve the
-    shape through the autotune cache's measured winner
-    (:func:`repro.bench.autotune.lookup_impl`; a miss falls back to
-    Pallas-on-TPU/XLA-elsewhere).
+    (dequantize-then-matmul fallback), or ``"auto"`` for the kernel and
+    tiles :func:`select_impl` derives from the shape.
 
-    ``block_sizes`` overrides the individual ``block_*`` args: a
-    ``(block_m, block_n, block_k)`` tuple, or ``"auto"`` to resolve the
-    shape through the persistent autotune cache
-    (:mod:`repro.bench.autotune`; falls back to the defaults on a cache
-    miss). Shapes are static under jit, so impl and block lookups happen
-    at trace time and cost nothing per call.
+    ``block_sizes`` overrides the individual ``block_*`` args, and the
+    rule's tiles under ``"auto"``, with a ``(block_m, block_n, block_k)``
+    tuple. Shapes are static under jit, so the rule runs at trace time
+    and costs nothing per call.
     """
     if pw.act_scale is not None:
         from repro.core.quantize import fake_quant_uniform
@@ -592,29 +564,18 @@ def _quantized_matmul(
     m0 = x2.shape[0]
     out_dtype = out_dtype or x.dtype
     if impl == "auto":
-        impl, block_sizes = _resolve_auto_impl(m0, k, n, pw, block_sizes)
-    # Resolve and validate block_sizes for every impl (the xla path
-    # ignores blocks, but a typo'd value or an odd nibble block_k must
-    # not succeed there and only blow up later on the TPU path).
+        impl, blocks, _ = select_impl(m0, k, n, nibble=pw.nibble, stacked=pw.codes.ndim != 2)
+        block_sizes = block_sizes or blocks
+    # Validate block_sizes for every impl (the xla path ignores blocks,
+    # but a typo'd value or an odd nibble block_k must not succeed there
+    # and only blow up later on the TPU path).
     if block_sizes is not None:
-        if block_sizes == "auto":
-            from repro.bench.autotune import lookup_blocks
-
-            block_m, block_n, block_k = lookup_blocks(
-                m0,
-                k,
-                n,
-                fmt_name=pw.fmt_name,
-                nibble=pw.nibble,
-                impl=impl if impl in ("pallas", "pallas_fused") else "pallas",
-            )
-        elif isinstance(block_sizes, tuple) and len(block_sizes) == 3:
-            block_m, block_n, block_k = block_sizes
-        else:
+        if not (isinstance(block_sizes, tuple) and len(block_sizes) == 3):
             raise ValueError(
-                f'block_sizes must be a (block_m, block_n, block_k) tuple, "auto", or None; '
+                f"block_sizes must be a (block_m, block_n, block_k) tuple or None; "
                 f"got {block_sizes!r}"
             )
+        block_m, block_n, block_k = block_sizes
     if pw.nibble and block_k % 2 != 0:
         raise ValueError(
             f"nibble-packed weights need an even block_k (two codes per byte along K); "

@@ -149,7 +149,7 @@ def forward(
     calib=None,
     tap=None,
     impl: str = "xla",
-    block_sizes: tuple[int, int, int] | str | None = None,
+    block_sizes: tuple[int, int, int] | None = None,
     interpret: bool | None = None,
 ) -> Array:
     """x: [B, H, W, C] images → logits [B, n_classes].
@@ -175,9 +175,8 @@ def forward(
     through ``quantized_matmul``, so the whole network executes on
     ELP_BSD codes end-to-end. ``impl`` selects the packed execution path
     ("xla" dequant-fused fallback, "pallas" fused decode+matmul kernel);
-    ``block_sizes`` forwards to the packed kernels (a tuple, or
-    ``"auto"`` to resolve each layer's matmul shape through the
-    autotune cache, DESIGN.md §7).
+    ``block_sizes`` forwards a ``(block_m, block_n, block_k)`` tuple to
+    the packed kernels.
     """
     from repro.core.quantize import fake_quant_dynamic, fake_quant_uniform
     from repro.kernels.conv import quantized_conv2d

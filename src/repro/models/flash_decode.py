@@ -65,9 +65,7 @@ def _local_attn(
     ``pos[b] + i`` and is masked causally within the run.
 
     ``block_s`` streams the shard-local slice through the softmax-stats
-    combine in seq chunks (the tuned flash-decode block size); ``None``
-    is the one-shot slice — the untuned default, byte-identical to the
-    pre-autotune behavior."""
+    combine in seq chunks; ``None`` is the one-shot slice."""
     b, sq, h, hd = q.shape
     s_loc = k.shape[1]
     idx = jax.lax.axis_index(axis)
@@ -125,19 +123,11 @@ def flash_decode_attention(
     (dequantized per shard, inside the map — HBM moves int8).
 
     ``block_s`` chunks each shard's seq slice through a streaming
-    softmax combine; the default ``None`` consults the autotune cache
-    (``flash_decode`` entries, :func:`repro.bench.autotune.
-    lookup_flash_block_s`) and falls back to the one-shot slice on a
-    miss — shapes are static under jit, so the lookup happens at trace
-    time."""
+    softmax combine; the default ``None`` is the one-shot slice."""
     axis = pctx.model_axis
     h = q.shape[2]
     kv = ck.shape[2]
     n_rep = h // kv
-    if block_s is None:
-        from repro.bench.autotune import lookup_flash_block_s
-
-        block_s = lookup_flash_block_s(q.shape[0], h, q.shape[3], ck.shape[1])
     ba = pctx.batch_axes
     b = q.shape[0]
     import numpy as np

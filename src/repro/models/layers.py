@@ -266,10 +266,10 @@ def matmul(x: Array, w) -> Array:
     """x[..., in] @ w[in, out] with f32 accumulation, output in x.dtype.
 
     ``w`` may be a packed ELP_BSD weight (serving path): the codes are
-    decoded in-graph via ``impl="auto"`` — the autotune cache's measured
-    winner per (shape, layout, backend) picks between the tiled Pallas
-    kernel, the fused decode-step kernel, and the XLA dequant path, with
-    tuned block sizes resolved at trace time. A 2-D weight on a mesh of
+    decoded in-graph via ``impl="auto"``: the shape rule
+    (``kernels/ops.select_impl``) picks the fused decode-step kernel or
+    the tiled Pallas kernel and their tiles on TPU, the XLA dequant path
+    elsewhere, at trace time. A 2-D weight on a mesh of
     several devices runs that choice per shard, at the shape one device
     computes, under ``shard_map``: a column-parallel weight (N split)
     returns its output split, a row-parallel one (K split) adds its
@@ -280,7 +280,7 @@ def matmul(x: Array, w) -> Array:
     from repro.kernels.ops import PackedWeight, quantized_matmul
 
     if isinstance(w, PackedWeight):
-        return quantized_matmul(x, w, impl="auto", block_sizes="auto", out_dtype=x.dtype)
+        return quantized_matmul(x, w, impl="auto", out_dtype=x.dtype)
     return jnp.dot(x, w.astype(x.dtype), preferred_element_type=F32).astype(x.dtype)
 
 

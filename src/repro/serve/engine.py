@@ -196,6 +196,56 @@ def _abstract_cache(setup: ServeSetup, api: ModelApi):
     return jax.eval_shape(lambda: api.init_cache(cfg, setup.batch, setup.max_len))
 
 
+def _jit_step(
+    fn,
+    setup: ServeSetup,
+    api: ModelApi,
+    aparams,
+    ins: tuple,
+    outs: tuple,
+    *,
+    static_argnums=(),
+    donate_one_chip=False,
+):
+    """``jax.jit(fn)`` placed for ``setup``: the one place a serve step's
+    shardings and donation are decided.
+
+    ``ins`` and ``outs`` name what each argument (static ones left out)
+    and output is: ``"params"``, ``"tokens"`` (``[batch, ...]``),
+    ``"cache"``, ``"state"`` (the cache, and for enc-dec families a
+    batch-sharded ``enc_out``), ``"replicated"``, or None (unplaced).
+    On one device nothing is placed and only a ``donate_one_chip`` step
+    donates its cache; on a mesh the specs come from the sharding rules
+    and every step donates its cache, which it returns in the same
+    sharding.
+    """
+    donate = (ins.index("cache"),)
+    if setup.mesh is None:
+        return jax.jit(
+            fn, static_argnums=static_argnums, donate_argnums=donate if donate_one_chip else ()
+        )
+    mesh = setup.mesh
+    pspecs = shr.param_specs(_abstract_params(setup, api, aparams), mesh)
+    cspecs = shr.cache_specs_tree(_abstract_cache(setup, api), mesh, prefer_seq=setup.flash_decode)
+    cache = shr.named(mesh, cspecs)
+    encdec = setup.cfg.family in ("encdec", "audio")
+    place = {
+        None: None,
+        "params": shr.named(mesh, pspecs),
+        "tokens": NamedSharding(mesh, shr.input_spec((setup.batch, 1), mesh)),
+        "cache": cache,
+        "state": (cache, NamedSharding(mesh, P(shr.batch_axes(mesh)))) if encdec else cache,
+        "replicated": NamedSharding(mesh, P()),
+    }
+    return jax.jit(
+        fn,
+        static_argnums=static_argnums,
+        in_shardings=tuple(place[a] for a in ins),
+        out_shardings=tuple(place[o] for o in outs),
+        donate_argnums=donate,
+    )
+
+
 def build_serve_fns(setup: ServeSetup, api: ModelApi | None = None, aparams: Any = None):
     """Jitted (prefill, decode) pair for a whole-batch serving step.
 
@@ -214,45 +264,11 @@ def build_serve_fns(setup: ServeSetup, api: ModelApi | None = None, aparams: Any
     def decode_fn(params, token, cache, pos):
         return api.decode_step(params, cfg, token, cache, pos, pctx=pctx)
 
-    if setup.mesh is None:
-        return jax.jit(prefill_fn), jax.jit(decode_fn)
-
-    mesh = setup.mesh
-    ap = _abstract_params(setup, api, aparams)
-    pspecs = shr.param_specs(ap, mesh)
-    acache = _abstract_cache(setup, api)
-    cspecs = shr.cache_specs_tree(acache, mesh, prefer_seq=setup.flash_decode)
-    tok_spec = shr.input_spec((setup.batch, 1), mesh)
-
-    prefill_j = jax.jit(
-        prefill_fn,
-        in_shardings=(shr.named(mesh, pspecs), None, shr.named(mesh, cspecs)),
-        out_shardings=(NamedSharding(mesh, P()), _cache_out(api, cfg, mesh, cspecs)),
-        donate_argnums=(2,),
+    outs = ("replicated", "state")
+    return (
+        _jit_step(prefill_fn, setup, api, aparams, ("params", None, "cache"), outs),
+        _jit_step(decode_fn, setup, api, aparams, ("params", "tokens", "cache", None), outs),
     )
-    decode_j = jax.jit(
-        decode_fn,
-        in_shardings=(
-            shr.named(mesh, pspecs),
-            NamedSharding(mesh, tok_spec),
-            shr.named(mesh, cspecs),
-            None,
-        ),
-        out_shardings=(NamedSharding(mesh, P()), _cache_out(api, cfg, mesh, cspecs)),
-        donate_argnums=(2,),
-    )
-    return prefill_j, decode_j
-
-
-def _cache_out(api, cfg, mesh, cspecs):
-    """Cache out-sharding matches in-sharding (donated round trip).
-
-    For enc-dec archs the serve state is (cache, enc_out) — enc_out gets
-    batch sharding.
-    """
-    if cfg.family in ("encdec", "audio"):
-        return (shr.named(mesh, cspecs), NamedSharding(mesh, P(shr.batch_axes(mesh))))
-    return shr.named(mesh, cspecs)
 
 
 def build_slot_prefill(setup: ServeSetup, api: ModelApi | None = None, aparams: Any = None):
@@ -279,18 +295,8 @@ def build_slot_prefill(setup: ServeSetup, api: ModelApi | None = None, aparams: 
         )
         return logits[:, -1], cache
 
-    if setup.mesh is None:
-        return jax.jit(prefill_slot)
-    mesh = setup.mesh
-    ap = _abstract_params(setup, api, aparams)
-    pspecs = shr.param_specs(ap, mesh)
-    acache = _abstract_cache(setup, api)
-    cspecs = shr.cache_specs_tree(acache, mesh, prefer_seq=setup.flash_decode)
-    return jax.jit(
-        prefill_slot,
-        in_shardings=(shr.named(mesh, pspecs), None, shr.named(mesh, cspecs), None),
-        out_shardings=(NamedSharding(mesh, P()), shr.named(mesh, cspecs)),
-        donate_argnums=(2,),
+    return _jit_step(
+        prefill_slot, setup, api, aparams, ("params", None, "cache", None), ("replicated", "cache")
     )
 
 
@@ -315,18 +321,8 @@ def build_paged_prefill(setup: ServeSetup, api: ModelApi | None = None, aparams:
         logits, cache = api.decode_step(params, cfg, tokens, cache, pos0, pctx=pctx)
         return logits[:, -1], cache
 
-    if setup.mesh is None:
-        return jax.jit(prefill_slot)
-    mesh = setup.mesh
-    ap = _abstract_params(setup, api, aparams)
-    pspecs = shr.param_specs(ap, mesh)
-    acache = _abstract_cache(setup, api)
-    cspecs = shr.cache_specs_tree(acache, mesh, prefer_seq=setup.flash_decode)
-    return jax.jit(
-        prefill_slot,
-        in_shardings=(shr.named(mesh, pspecs), None, shr.named(mesh, cspecs), None),
-        out_shardings=(NamedSharding(mesh, P()), shr.named(mesh, cspecs)),
-        donate_argnums=(2,),
+    return _jit_step(
+        prefill_slot, setup, api, aparams, ("params", None, "cache", None), ("replicated", "cache")
     )
 
 
@@ -348,24 +344,8 @@ def build_greedy_decode(setup: ServeSetup, api: ModelApi | None = None, aparams:
         nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
         return nxt, cache
 
-    if setup.mesh is None:
-        return jax.jit(decode_greedy)
-    mesh = setup.mesh
-    ap = _abstract_params(setup, api, aparams)
-    pspecs = shr.param_specs(ap, mesh)
-    acache = _abstract_cache(setup, api)
-    cspecs = shr.cache_specs_tree(acache, mesh, prefer_seq=setup.flash_decode)
-    tok_spec = shr.input_spec((setup.batch, 1), mesh)
-    return jax.jit(
-        decode_greedy,
-        in_shardings=(
-            shr.named(mesh, pspecs),
-            NamedSharding(mesh, tok_spec),
-            shr.named(mesh, cspecs),
-            None,
-        ),
-        out_shardings=(NamedSharding(mesh, tok_spec), _cache_out(api, cfg, mesh, cspecs)),
-        donate_argnums=(2,),
+    return _jit_step(
+        decode_greedy, setup, api, aparams, ("params", "tokens", "cache", None), ("tokens", "state")
     )
 
 
@@ -398,25 +378,15 @@ def build_draft_run(setup: ServeSetup, api: ModelApi | None = None, aparams: Any
         (_, cache), fed = jax.lax.scan(body, (token, cache), jnp.arange(width))
         return jnp.moveaxis(fed[:, :, 0], 0, 1), cache  # [B, width]
 
-    if setup.mesh is None:
-        return jax.jit(draft, static_argnums=(4,), donate_argnums=(2,))
-    mesh = setup.mesh
-    ap = _abstract_params(setup, api, aparams)
-    pspecs = shr.param_specs(ap, mesh)
-    acache = _abstract_cache(setup, api)
-    cspecs = shr.cache_specs_tree(acache, mesh, prefer_seq=setup.flash_decode)
-    tok_spec = shr.input_spec((setup.batch, 1), mesh)
-    return jax.jit(
+    return _jit_step(
         draft,
+        setup,
+        api,
+        aparams,
+        ("params", "tokens", "cache", None),
+        ("tokens", "state"),
         static_argnums=(4,),
-        in_shardings=(
-            shr.named(mesh, pspecs),
-            NamedSharding(mesh, tok_spec),
-            shr.named(mesh, cspecs),
-            None,
-        ),
-        out_shardings=(NamedSharding(mesh, tok_spec), _cache_out(api, cfg, mesh, cspecs)),
-        donate_argnums=(2,),
+        donate_one_chip=True,
     )
 
 
@@ -457,29 +427,13 @@ def build_verify_step(setup: ServeSetup, api: ModelApi | None = None, aparams: A
         ptok = jnp.take_along_axis(vtok, acc[:, None] - 1, axis=1)
         return vtok, acc, ptok, cache
 
-    if setup.mesh is None:
-        return jax.jit(verify)
-    mesh = setup.mesh
-    ap = _abstract_params(setup, api, aparams)
-    pspecs = shr.param_specs(ap, mesh)
-    acache = _abstract_cache(setup, api)
-    cspecs = shr.cache_specs_tree(acache, mesh, prefer_seq=setup.flash_decode)
-    tok_spec = shr.input_spec((setup.batch, 1), mesh)
-    return jax.jit(
+    return _jit_step(
         verify,
-        in_shardings=(
-            shr.named(mesh, pspecs),
-            NamedSharding(mesh, tok_spec),
-            shr.named(mesh, cspecs),
-            None,
-        ),
-        out_shardings=(
-            NamedSharding(mesh, tok_spec),
-            NamedSharding(mesh, P()),
-            NamedSharding(mesh, tok_spec),
-            _cache_out(api, cfg, mesh, cspecs),
-        ),
-        donate_argnums=(2,),
+        setup,
+        api,
+        aparams,
+        ("params", "tokens", "cache", None),
+        ("tokens", "replicated", "tokens", "state"),
     )
 
 
@@ -1347,16 +1301,16 @@ class ServeEngine:
         Walks the packed weight tree and resolves every distinct
         ``[K, N]`` decode-step GEMM (``M`` = the slot batch) exactly the
         way ``layers.matmul(impl="auto")`` does at trace time
-        (:func:`repro.kernels.ops.select_impl`): the autotune cache's
-        measured winner, or the backend heuristic on a miss; XLA for
-        stacked (expert) weights. Layer weights are judged per layer
+        (:func:`repro.kernels.ops.select_impl`): the shape rule's kernel
+        and tiles on TPU, XLA elsewhere and for stacked (expert)
+        weights. Layer weights are judged per layer
         slice, as the layer scan hands them to the matmul. On a mesh of
         several devices a 2-D weight runs per shard
         (:func:`repro.kernels.ops.sharded_matmul`) and is resolved at the
         shape one device computes. Observability for "is the fused decode
         kernel actually on?" — keyed ``MxKxN|fmt|layout``, each value
-        recording the impl, how it was chosen (``autotuned`` /
-        ``heuristic`` / ``structural``, or ``sharded`` per shard), its
+        recording the impl, how it was chosen (``heuristic`` /
+        ``structural``, or ``sharded`` per shard), its
         ``(block_m, block_n, block_k)`` tiles (None on the XLA path), the
         dtype the dot's operands take for the model's activations
         (:func:`repro.kernels.elp_bsd_matmul.mxu_operand_dtype`; float32
@@ -1384,9 +1338,7 @@ class ServeEngine:
             scanned = getattr(path[0], "key", None) == "blocks"
             stacked = leaf.codes.ndim - scanned != 2
             local = (m, k, n) if stacked or not sharded else shard_shape(m, leaf, self.mesh)
-            impl, blocks, source = select_impl(
-                *local, fmt_name=leaf.fmt_name, nibble=leaf.nibble, stacked=stacked
-            )
+            impl, blocks, source = select_impl(*local, nibble=leaf.nibble, stacked=stacked)
             kernel = impl != "xla"
             operand = mxu_operand_dtype(leaf.fmt, self.cfg.dtype) if kernel else jnp.float32
             out[key] = {

@@ -314,6 +314,9 @@ def test_cnn_save_load_roundtrip_bit_identical(cnn_setup, tmp_path):
     assert qm2.scheme == qm.scheme
     assert qm2.table == qm.table
     assert qm2.report == qm.report
+    # a report saved before the tile cache went still loads
+    old = {**qm.report.to_json(), "tuned_blocks": [["cpu|pallas|elp_bsd_a4|nib|8x64x64", [128]]]}
+    assert api.ConversionReport.from_json(old) == qm.report
     assert qm2.model == SPEC
     assert_trees_bitwise_equal(qm.params, qm2.params)
     ref = np.asarray(qm.forward(x))

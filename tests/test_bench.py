@@ -1,4 +1,4 @@
-"""Benchmark subsystem: autotune cache, schema, registry, auto blocks.
+"""Benchmark subsystem: schema, registry, gate; explicit tiles and auto dispatch.
 
 The committed ``BENCH_*.json`` baselines are load-bearing (CI's
 bench-smoke job gates wall-clock against them), so their schema is
@@ -12,243 +12,27 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.bench import autotune, registry, schema
+from repro.bench import registry, schema
 from repro.core.elp_bsd import FORMAT_A
 from repro.kernels.ops import pack_weight, quantized_matmul
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-@pytest.fixture
-def tmp_cache(tmp_path, monkeypatch):
-    """Point the autotune cache at a fresh tmp file for one test."""
-    path = str(tmp_path / "autotune_cache.json")
-    monkeypatch.setenv(autotune.CACHE_ENV, path)
-    autotune.invalidate_memory_cache()
-    yield path
-    autotune.invalidate_memory_cache()
-
-
 # ---------------------------------------------------------------------------
-# Autotune cache round-trip
+# Explicit tiles that keep block_k are bit-exact against the default tiles
 # ---------------------------------------------------------------------------
-class TestAutotuneCache:
-    def test_miss_returns_default(self, tmp_cache):
-        blocks = autotune.lookup_blocks(8, 64, 32, fmt_name="elp_bsd_a4", nibble=True)
-        assert blocks == autotune.DEFAULT_BLOCKS
-
-    def test_write_then_hit_and_disk_roundtrip(self, tmp_cache):
-        key = autotune.cache_key(8, 512, 128, "elp_bsd_a4", True, "cpu")
-        autotune.write_entries({key: {"blocks": [256, 128, 128], "wall_us": 10.0}})
-        assert os.path.exists(tmp_cache)
-        got = autotune.lookup_blocks(
-            8, 512, 128, fmt_name="elp_bsd_a4", nibble=True, backend="cpu"
-        )
-        assert got == (256, 128, 128)
-        # Drop the in-memory copy: the same answer must come off disk.
-        autotune.invalidate_memory_cache()
-        got = autotune.lookup_blocks(
-            8, 512, 128, fmt_name="elp_bsd_a4", nibble=True, backend="cpu"
-        )
-        assert got == (256, 128, 128)
-        # Other shapes / backends still miss.
-        assert (
-            autotune.lookup_blocks(8, 512, 128, fmt_name="elp_bsd_a4", nibble=False, backend="cpu")
-            == autotune.DEFAULT_BLOCKS
-        )
-        assert (
-            autotune.lookup_blocks(8, 512, 128, fmt_name="elp_bsd_a4", nibble=True, backend="tpu")
-            == autotune.DEFAULT_BLOCKS
-        )
-
-    def test_write_merges_existing_entries(self, tmp_cache):
-        k1 = autotune.cache_key(8, 128, 128, "elp_bsd_a4", True, "cpu")
-        k2 = autotune.cache_key(8, 256, 128, "elp_bsd_c6", False, "cpu")
-        autotune.write_entries({k1: {"blocks": [128, 128, 128]}})
-        autotune.write_entries({k2: {"blocks": [256, 256, 128]}})
-        autotune.invalidate_memory_cache()
-        with open(tmp_cache) as f:
-            doc = json.load(f)
-        assert set(doc["entries"]) == {k1, k2}
-        assert doc["schema_version"] == autotune.CACHE_SCHEMA_VERSION
-
-    @pytest.mark.parametrize(
-        "content",
-        [
-            "not json at all {",
-            json.dumps({"schema_version": 999, "entries": {}}),
-            json.dumps({"schema_version": 1, "entries": "nope"}),
-            json.dumps(
-                {"schema_version": 1, "entries": {"cpu|f|nib|1x2x3": {"blocks": [0, -1, "x"]}}}
-            ),
-        ],
-        ids=["garbage", "bad-version", "bad-entries", "bad-blocks"],
-    )
-    def test_corrupt_cache_degrades_to_default(self, tmp_cache, content):
-        with open(tmp_cache, "w") as f:
-            f.write(content)
-        blocks = autotune.lookup_blocks(1, 2, 3, fmt_name="f", nibble=True, backend="cpu")
-        assert blocks == autotune.DEFAULT_BLOCKS
-
-    def test_write_refuses_to_clobber_corrupt_cache(self, tmp_cache):
-        """Reads degrade to defaults, but writes must not silently wipe
-        an existing file they cannot parse (e.g. committed TPU entries
-        behind a merge-conflict marker)."""
-        with open(tmp_cache, "w") as f:
-            f.write("not json {")
-        with pytest.raises(RuntimeError, match="refusing"):
-            autotune.write_entries({"k": {"blocks": [128, 128, 128]}})
-        with open(tmp_cache) as f:
-            assert f.read() == "not json {"  # untouched
-
-    def test_autotune_matmul_populates_cache(self, tmp_cache):
-        res = autotune.autotune_matmul(
-            8, 64, 32, FORMAT_A, iters=1, warmup=1, backend="cpu"
-        )
-        assert res["blocks"] == [128, 128, 128]  # single candidate at tiny dims
-        assert res["candidates"] == len(res["ranking"]) >= 1
-        autotune.invalidate_memory_cache()
-        got = autotune.lookup_blocks(8, 64, 32, fmt_name="elp_bsd_a4", nibble=True, backend="cpu")
-        assert got == tuple(res["blocks"])
-
-    def test_sweep_nibble_tunes_both_storage_modes(self, tmp_cache):
-        results = autotune.sweep_nibble(8, 64, 32, FORMAT_A, iters=1, warmup=1)
-        # The returned key is the cross-impl winner's; each result covers
-        # one storage mode, and every raced impl lands its own entry.
-        assert {r["key"] for r in results} == {
-            autotune.cache_key(
-                8, 64, 32, "elp_bsd_a4", nib, jax.default_backend(), impl=r["impl"]
-            )
-            for r, nib in zip(results, (False, True))
-        }
-        autotune.invalidate_memory_cache()
-        entries = autotune.cache_entries()
-        for nib in (False, True):
-            for impl in autotune.IMPLS:
-                assert (
-                    autotune.cache_key(
-                        8, 64, 32, "elp_bsd_a4", nib, jax.default_backend(), impl=impl
-                    )
-                    in entries
-                )
-
-    def test_autotune_rejects_foreign_backend(self):
-        other = "tpu" if jax.default_backend() != "tpu" else "cpu"
-        with pytest.raises(ValueError, match="cannot tune for backend"):
-            autotune.autotune_matmul(8, 64, 32, FORMAT_A, backend=other)
-
-    def test_candidates_respect_nibble_and_bit_stability(self):
-        cands = autotune.candidate_blocks(512, 2048, 512, nibble=True, bit_stable=True)
-        assert all(bk == autotune.DEFAULT_BLOCKS[2] for _, _, bk in cands)
-        assert len(cands) > 1  # m/n actually searched
-        free = autotune.candidate_blocks(512, 2048, 512, nibble=True, bit_stable=False)
-        assert {bk for _, _, bk in free} > {128}
-        assert all(bk % 2 == 0 for _, _, bk in free)
-
-
-# ---------------------------------------------------------------------------
-# Schema v2: impl-qualified keys, v1 migration, winner lookup
-# ---------------------------------------------------------------------------
-class TestAutotuneV2:
-    def test_v1_cache_migrates_blocks_but_not_votes(self, tmp_cache):
-        """A v1 file keeps steering block sizes under the pallas impl key,
-        but its wall_us must NOT survive migration — a stale pallas-only
-        timing would win lookup_impl unopposed."""
-        v1_key = "cpu|elp_bsd_a4|nib|8x512x128"
-        with open(tmp_cache, "w") as f:
-            json.dump(
-                {
-                    "schema_version": 1,
-                    "entries": {
-                        v1_key: {"blocks": [256, 256, 128], "wall_us": 1.0},
-                        "short|key": {"blocks": [128, 128, 128]},  # unmigratable: dropped
-                    },
-                },
-                f,
-            )
-        got = autotune.lookup_blocks(
-            8, 512, 128, fmt_name="elp_bsd_a4", nibble=True, backend="cpu"
-        )
-        assert got == (256, 256, 128)
-        impl, blocks = autotune.lookup_impl(
-            8, 512, 128, fmt_name="elp_bsd_a4", nibble=True, backend="cpu"
-        )
-        assert impl is None and blocks == autotune.DEFAULT_BLOCKS
-        assert "short|key" not in autotune.cache_entries()
-
-    def test_lookup_impl_returns_min_wall_entry(self, tmp_cache):
-        def mk(impl):
-            return autotune.cache_key(4, 2048, 2048, "elp_bsd_a4", True, "cpu", impl=impl)
-        autotune.write_entries(
-            {
-                mk("pallas"): {"blocks": [128, 128, 128], "wall_us": 900.0},
-                mk("pallas_fused"): {"blocks": [128, 256, 128], "wall_us": 120.0},
-                mk("xla"): {"blocks": [128, 128, 128], "wall_us": 150.0},
-            }
-        )
-        impl, blocks = autotune.lookup_impl(
-            4, 2048, 2048, fmt_name="elp_bsd_a4", nibble=True, backend="cpu"
-        )
-        assert impl == "pallas_fused"
-        assert blocks == (128, 256, 128)
-
-    def test_lookup_impl_ignores_entries_without_wall_us(self, tmp_cache):
-        key = autotune.cache_key(4, 64, 64, "elp_bsd_a4", False, "cpu", impl="pallas")
-        autotune.write_entries({key: {"blocks": [128, 128, 128]}})
-        impl, _ = autotune.lookup_impl(
-            4, 64, 64, fmt_name="elp_bsd_a4", nibble=False, backend="cpu"
-        )
-        assert impl is None
-
-    def test_cache_key_impl_segment_and_positional_compat(self):
-        assert autotune.cache_key(1, 2, 3, "f", True, "cpu") == "cpu|pallas|f|nib|1x2x3"
-        assert (
-            autotune.cache_key(1, 2, 3, "f", False, "tpu", impl="pallas_fused")
-            == "tpu|pallas_fused|f|u8|1x2x3"
-        )
-
-    def test_lookup_flash_block_s(self, tmp_cache):
-        key = autotune.flash_cache_key(4, 8, 64, 256, "cpu")
-        autotune.write_entries({key: {"blocks": [1, 64, 1], "wall_us": 5.0}})
-        assert autotune.lookup_flash_block_s(4, 8, 64, 256, backend="cpu") == 64
-        # one-shot sentinel (block_s = 0), non-divisors and >= s read as None
-        for bad in (0, 96, 256, 512):
-            autotune.write_entries({key: {"blocks": [1, bad, 1]}})
-            autotune.invalidate_memory_cache()
-            assert autotune.lookup_flash_block_s(4, 8, 64, 256, backend="cpu") is None
-        assert autotune.lookup_flash_block_s(4, 8, 64, 999, backend="cpu") is None  # miss
-
-    def test_autotune_matmul_ranking_covers_all_impls(self, tmp_cache):
-        res = autotune.autotune_matmul(4, 64, 32, FORMAT_A, iters=1, warmup=1, backend="cpu")
-        raced = {r["impl"] for r in res["ranking"]}
-        assert raced == set(autotune.IMPLS)
-        assert res["impl"] == res["ranking"][0]["impl"]
-        assert res["wall_us"] == min(r["wall_us"] for r in res["ranking"])
-
-
-# ---------------------------------------------------------------------------
-# block_sizes="auto" resolves through the cache, bit-exactly
-# ---------------------------------------------------------------------------
-def test_auto_blocks_bit_exact_vs_default(tmp_cache):
+def test_auto_blocks_bit_exact_vs_default():
+    """Re-tiling M and N leaves every output's K sum in the same order."""
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.normal(size=(8, 512)), jnp.float32)
     w = jnp.asarray(rng.normal(size=(512, 128)) * 0.05, jnp.float32)
     pw, _ = pack_weight(w, FORMAT_A)
     want = np.asarray(quantized_matmul(x, pw, impl="pallas"))
-
-    # Install a non-default tiling for exactly this shape, then retrace.
-    key = autotune.cache_key(8, 512, 128, "elp_bsd_a4", True, jax.default_backend())
-    autotune.write_entries({key: {"blocks": [256, 256, 128]}})
-    jax.clear_caches()  # "auto" resolves at trace time; force a fresh trace
-    assert autotune.lookup_blocks(8, 512, 128, fmt_name="elp_bsd_a4", nibble=True) == (
-        256,
-        256,
-        128,
-    )
-    got = np.asarray(quantized_matmul(x, pw, impl="pallas", block_sizes="auto"))
+    got = np.asarray(quantized_matmul(x, pw, impl="pallas", block_sizes=(256, 256, 128)))
     np.testing.assert_array_equal(got, want)
 
-    # Conv path resolves too (im2col shape) and stays bit-exact.
+    # The conv path forwards the tiles to its im2col matmul, bit-exactly.
     from repro.kernels.conv import quantized_conv2d
     from repro.kernels.ops import pack_conv_weight
 
@@ -256,7 +40,7 @@ def test_auto_blocks_bit_exact_vs_default(tmp_cache):
     wc = jnp.asarray(rng.normal(size=(3, 3, 8, 16)) * 0.1, jnp.float32)
     pwc, _ = pack_conv_weight(wc, FORMAT_A)
     ref = np.asarray(quantized_conv2d(xc, pwc, impl="pallas"))
-    got = np.asarray(quantized_conv2d(xc, pwc, impl="pallas", block_sizes="auto"))
+    got = np.asarray(quantized_conv2d(xc, pwc, impl="pallas", block_sizes=(256, 256, 128)))
     np.testing.assert_array_equal(got, ref)
 
 
@@ -297,31 +81,10 @@ def test_gate_selected_same_impl_skips_entry_check():
 
 
 # ---------------------------------------------------------------------------
-# impl="auto" dispatch: cache winner, conv xla fallback, flash chunking
+# impl="auto" dispatch off TPU: conv takes XLA; flash-decode chunking
 # ---------------------------------------------------------------------------
-def test_auto_impl_follows_cache_winner_bit_exact(tmp_cache):
-    """auto == xla on a cold cache (CPU heuristic), and still == xla when
-    the cache elects pallas_fused (its off-TPU form is the same graph)."""
-    if jax.default_backend() == "tpu":
-        pytest.skip("CPU heuristic under test")
-    rng = np.random.default_rng(2)
-    x = jnp.asarray(rng.normal(size=(4, 512)), jnp.float32)
-    pw, _ = pack_weight(jnp.asarray(rng.normal(size=(512, 128)) * 0.05, jnp.float32), FORMAT_A)
-    want = np.asarray(quantized_matmul(x, pw, impl="xla"))
-    got = np.asarray(quantized_matmul(x, pw, impl="auto"))
-    np.testing.assert_array_equal(got, want)
-
-    key = autotune.cache_key(
-        4, 512, 128, "elp_bsd_a4", True, jax.default_backend(), impl="pallas_fused"
-    )
-    autotune.write_entries({key: {"blocks": [128, 128, 128], "wall_us": 1.0}})
-    jax.clear_caches()  # "auto" resolves at trace time
-    got = np.asarray(quantized_matmul(x, pw, impl="auto", block_sizes="auto"))
-    np.testing.assert_array_equal(got, want)
-
-
-def test_conv_auto_falls_back_to_xla_on_cache_miss(tmp_cache):
-    """Untuned conv shapes take impl="xla" — never interpret-mode Pallas."""
+def test_conv_auto_is_xla():
+    """Conv ``impl="auto"`` takes XLA's conv — never interpret-mode Pallas."""
     from repro.kernels.conv import quantized_conv2d
     from repro.kernels.ops import pack_conv_weight
 
@@ -335,9 +98,9 @@ def test_conv_auto_falls_back_to_xla_on_cache_miss(tmp_cache):
     np.testing.assert_array_equal(got, want)
 
 
-def test_flash_decode_chunked_matches_oneshot(tmp_cache):
-    """block_s streaming combine == one-shot slice, and the default
-    block_s=None picks up a tuned chunk from the cache."""
+def test_flash_decode_chunked_matches_oneshot():
+    """block_s streaming combine == the one-shot slice of the default
+    block_s=None."""
     from repro.models.context import ParallelCtx
     from repro.models.flash_decode import flash_decode_attention
 
@@ -350,23 +113,9 @@ def test_flash_decode_chunked_matches_oneshot(tmp_cache):
     cv = jax.random.normal(jax.random.PRNGKey(6), (b, smax, kv, hd))
     pos = jnp.int32(49)
     with mesh:
-        oneshot = flash_decode_attention(q, ck, cv, pos, pctx=pctx)  # cold cache: one-shot
+        oneshot = flash_decode_attention(q, ck, cv, pos, pctx=pctx)
         chunked = flash_decode_attention(q, ck, cv, pos, pctx=pctx, block_s=16)
     np.testing.assert_allclose(np.asarray(chunked), np.asarray(oneshot), rtol=2e-6, atol=2e-6)
-
-    autotune.write_entries(
-        {
-            autotune.flash_cache_key(b, h, hd, smax, jax.default_backend()): {
-                "blocks": [1, 16, 1],
-                "wall_us": 3.0,
-            }
-        }
-    )
-    jax.clear_caches()
-    assert autotune.lookup_flash_block_s(b, h, hd, smax) == 16
-    with mesh:
-        tuned = flash_decode_attention(q, ck, cv, pos, pctx=pctx)
-    np.testing.assert_allclose(np.asarray(tuned), np.asarray(oneshot), rtol=2e-6, atol=2e-6)
 
 
 def test_explicit_block_sizes_tuple_and_bad_value():
@@ -376,10 +125,12 @@ def test_explicit_block_sizes_tuple_and_bad_value():
     want = np.asarray(quantized_matmul(x, pw, impl="pallas"))
     got = np.asarray(quantized_matmul(x, pw, impl="pallas", block_sizes=(256, 128, 128)))
     np.testing.assert_array_equal(got, want)
-    # Misuse raises on the xla fallback too, not only once on TPU.
-    for impl in ("pallas", "xla"):
-        with pytest.raises(ValueError, match="block_sizes"):
-            quantized_matmul(x, pw, impl=impl, block_sizes="fastest")
+    # Misuse raises on the xla fallback too, not only once on TPU; "auto"
+    # tiles are no longer a value (the shape rule picks them under impl="auto").
+    for impl in ("pallas", "xla", "auto"):
+        for bad in ("fastest", "auto"):
+            with pytest.raises(ValueError, match="block_sizes"):
+                quantized_matmul(x, pw, impl=impl, block_sizes=bad)
         with pytest.raises(ValueError, match="even block_k"):
             quantized_matmul(x, pw, impl=impl, block_sizes=(128, 128, 127))
 

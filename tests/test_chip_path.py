@@ -126,7 +126,7 @@ def test_init_packed_lm_params_equals_pack_of_init():
 def test_select_impl_structural_cases():
     """Only stacked weights are structural: a weight on a mesh is
     resolved at its per-device shape like any one-device weight."""
-    kw = dict(fmt_name="elp_bsd_a4", nibble=True)
+    kw = dict(nibble=True)
     assert select_impl(8, 64, 64, stacked=True, **kw)[::2] == ("xla", "structural")
     assert select_impl(8, 64, 64, stacked=False, **kw)[2] != "structural"
     assert select_impl(8, 64, 16, stacked=False, **kw)[2] != "structural"

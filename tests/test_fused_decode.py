@@ -20,6 +20,8 @@ from repro.kernels import ref as kref
 from repro.kernels.fused_decode import MAX_FUSED_M, fused_decode_matmul
 from repro.kernels.ops import pack_weight, quantized_matmul
 
+from lm_shapes import QWEN3_8B_KN, YI_34B_LOCAL_KN
+
 # (fmt alias, nibble) — the storable layout grid
 GRID = [("elp4", True), ("elp4", False), ("elp8", False)]
 GRID_IDS = ["elp4-nib", "elp4-u8", "elp8-u8"]
@@ -222,39 +224,49 @@ def test_pallas_fused_rejects_stacked_codes():
 # ---------------------------------------------------------------------------
 # the TPU tile rule and bf16 operands (pure functions; interpret mode)
 # ---------------------------------------------------------------------------
-QWEN3_8B_KN = [(4096, 4096), (4096, 1024), (4096, 12288), (12288, 4096)]
 # a decode step's seven packed GEMMs per layer: wq, wk, wv, wo, w1, w3, w2
 QWEN3_8B_LAYER = [(4096, 4096), (4096, 1024), (4096, 1024), (4096, 4096),
                   (4096, 12288), (4096, 12288), (12288, 4096)]
 
 
 @pytest.fixture
-def tpu_miss(monkeypatch, tmp_path):
-    """``select_impl`` as on a TPU whose autotune cache holds nothing
-    (the parsed cache is keyed by its path, so a fresh path is empty)."""
-    from repro.bench import autotune
-
-    monkeypatch.setenv(autotune.CACHE_ENV, str(tmp_path / "empty.json"))
+def on_tpu(monkeypatch):
+    """``select_impl`` as on a TPU."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _tpu_rule_tiles(m, k, n, nibble):
+    """The rule's tiles for one shape, checked against what ``tpu_blocks``
+    promises: the impl M calls for, every tile dividing its dimension (no
+    pad of codes or activations on any call), a code panel of at most
+    1 MB and an activation block of at most 2 MB (4 bytes an element)."""
+    from repro.kernels.ops import select_impl
+
+    impl, (bm, bn, bk), source = select_impl(m, k, n, nibble=nibble, stacked=False)
+    assert source == "heuristic"
+    assert impl == ("pallas_fused" if m <= MAX_FUSED_M else "pallas")
+    assert k % bk == 0 and n % bn == 0 and bn % 128 == 0 and bk % 128 == 0
+    assert impl == "pallas_fused" or m % bm == 0
+    panel = bk * bn // (2 if nibble else 1)
+    assert panel <= (1 << 20) and 4 * bm * bk <= (2 << 20)
+    return panel
 
 
 @pytest.mark.parametrize("nibble", [True, False], ids=["nib", "u8"])
 @pytest.mark.parametrize("m", [16, 512])
 @pytest.mark.parametrize("k,n", QWEN3_8B_KN)
-def test_tpu_rule_tiles_divide_qwen3_8b_shapes(tpu_miss, k, n, m, nibble):
-    from repro.kernels.ops import select_impl
-
-    impl, (bm, bn, bk), source = select_impl(
-        m, k, n, fmt_name="elp_bsd_a4" if nibble else "elp_bsd_c6", nibble=nibble,
-        stacked=False,
-    )
-    assert source == "heuristic"
-    assert impl == ("pallas_fused" if m <= MAX_FUSED_M else "pallas")
-    # no pad of codes or activations on any call: every tile divides
-    assert k % bk == 0 and n % bn == 0 and bn % 128 == 0 and bk % 128 == 0
-    assert impl == "pallas_fused" or m % bm == 0
-    panel = bk * bn // (2 if nibble else 1)
+def test_tpu_rule_tiles_divide_qwen3_8b_shapes(on_tpu, k, n, m, nibble):
+    panel = _tpu_rule_tiles(m, k, n, nibble)
     assert panel >= (256 << 10)  # a grid step carries far more than 128³'s 8 KB
+
+
+@pytest.mark.parametrize("nibble", [True, False], ids=["nib", "u8"])
+@pytest.mark.parametrize("m", [32, 1024], ids=["decode", "prefill"])
+@pytest.mark.parametrize("k,n", YI_34B_LOCAL_KN)
+def test_tpu_rule_tiles_divide_yi_34b_shard_shapes(on_tpu, k, n, m, nibble):
+    """One chip's share of each yi-34b GEMM on a 1x4 model mesh: 32 slots
+    decoding, a 1024-token prefill."""
+    _tpu_rule_tiles(m, k, n, nibble)
 
 
 @pytest.mark.parametrize("nibble", [True, False], ids=["nib", "u8"])
@@ -272,16 +284,14 @@ def test_tpu_rule_decode_step_grid(nibble):
     assert steps <= 200
 
 
-def test_select_impl_off_tpu_keeps_xla_and_default_blocks(monkeypatch, tmp_path):
-    from repro.bench import autotune
+def test_select_impl_off_tpu_keeps_xla_and_default_blocks():
+    """Off TPU the rule names XLA and no tiles, so an explicit kernel call
+    keeps the kernels' default 128³ tiles."""
     from repro.kernels.ops import select_impl
 
-    monkeypatch.setenv(autotune.CACHE_ENV, str(tmp_path / "empty.json"))
-    got = select_impl(16, 4096, 4096, fmt_name="elp_bsd_a4", nibble=True, stacked=False)
-    assert got == ("xla", None, "heuristic")
-    assert autotune.lookup_blocks(16, 4096, 4096, fmt_name="elp_bsd_a4", nibble=True) == (
-        autotune.DEFAULT_BLOCKS
-    )
+    for m in (16, 512):
+        got = select_impl(m, 4096, 4096, nibble=True, stacked=False)
+        assert got == ("xla", None, "heuristic")
 
 
 @pytest.mark.parametrize("fmt_name", sorted(PRESET_FORMATS))

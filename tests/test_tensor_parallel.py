@@ -192,6 +192,79 @@ def test_yi_shaped_engine_serves_the_reference_argmax(yi_served):
     assert len(gaps) == 6 and max(gaps) <= LOGIT_TOL * yi_served["scale"]
 
 
+# the engine's jitted steps: the function that builds it, which of its jits, its jitted name
+SERVE_STEPS = {
+    "serve_prefill": ("build_serve_fns", 0, "prefill_fn"),
+    "serve_decode": ("build_serve_fns", 1, "decode_fn"),
+    "slot_prefill": ("build_slot_prefill", None, "prefill_slot"),
+    "paged_prefill": ("build_paged_prefill", None, "prefill_slot"),
+    "greedy_decode": ("build_greedy_decode", None, "decode_greedy"),
+    "draft_run": ("build_draft_run", None, "draft"),
+    "verify_step": ("build_verify_step", None, "verify"),
+}
+
+
+@pytest.fixture(scope="module")
+def placements():
+    """Each serve step of a Yi-shaped decoder lowered on one device and
+    on a 1x4 mesh: the arguments it donates and its jitted name."""
+    return _child(
+        f"""
+        import dataclasses, json
+        import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import Mesh
+        from repro.configs import get_config
+        from repro.models import get_model
+        from repro.serve import engine
+
+        cfg = dataclasses.replace(get_config("yi_34b"), **{YI_TINY!r})
+        api = get_model(cfg)
+        aparams = jax.eval_shape(lambda: api.init_params(cfg, jax.random.PRNGKey(0)))
+        i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
+        meshes = {{"one_chip": None,
+                   "mesh": Mesh(np.asarray(jax.devices()).reshape(1, 4), ("data", "model"))}}
+        out = {{}}
+        for where, mesh in meshes.items():
+            for step, (build, which, _) in {SERVE_STEPS!r}.items():
+                paged = step == "paged_prefill"
+                setup = engine.ServeSetup(cfg=cfg, mesh=mesh, max_len=16, batch=2,
+                                          page_size=4 if paged else 0)
+                fn = getattr(engine, build)(setup, api, aparams)
+                fn = fn if which is None else fn[which]
+                cache = engine._abstract_cache(setup, api)
+                if paged:  # the batch-1 page-table row the engine injects
+                    cache = {{**cache, "pages": i32(1, cache["pages"].shape[1])}}
+                args = {{
+                    "serve_prefill": (aparams, {{"tokens": i32(2, 8)}}, cache),
+                    "serve_decode": (aparams, i32(2, 1), cache, i32(2)),
+                    "slot_prefill": (aparams, i32(1, 8), cache, i32()),
+                    "paged_prefill": (aparams, i32(1, 4), cache, i32(1)),
+                    "greedy_decode": (aparams, i32(2, 1), cache, i32(2)),
+                    "draft_run": (aparams, i32(2, 1), cache, i32(2), 3),
+                    "verify_step": (aparams, i32(2, 3), cache, i32(2)),
+                }}[step]
+                low = fn.lower(*args)
+                donated = [i for i, a in enumerate(low.args_info[0])
+                           if any(x.donated for x in jax.tree.leaves(a))]
+                name = low.as_text().split("module @", 1)[1].split(" ", 1)[0]
+                out[where + "/" + step] = {{"donated": donated, "name": name}}
+        print("JSON " + json.dumps(out))
+        """
+    )
+
+
+@pytest.mark.parametrize("where", ["one_chip", "mesh"])
+@pytest.mark.parametrize("step", sorted(SERVE_STEPS))
+def test_serve_step_donation_and_name(placements, step, where):
+    """On a mesh every step donates its cache (argument 2); on one device
+    only the draft loop does. Each jit keeps its function's name, which
+    the device-trace readers key on."""
+    r = placements[f"{where}/{step}"]
+    donates = where == "mesh" or step == "draft_run"
+    assert r["donated"] == ([2] if donates else [])
+    assert r["name"] == "jit_" + SERVE_STEPS[step][2]
+
+
 def _eps_seen(cfg, monkeypatch, *, drop_eps=False):
     """The eps of every RMSNorm the decoder forward calls, and its logits;
     with ``drop_eps`` each call takes ``rms_norm``'s own default."""

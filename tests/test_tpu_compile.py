@@ -5,8 +5,8 @@ vector shifts, unaligned code tiles, VMEM limits). These tests hand the
 kernels to the TPU compiler for a v5e that is described, not attached:
 qwen3-8b's four GEMM shapes at decode (M = 8 slots) and prefill
 (M = 512 tokens), in nibble (elp_bsd_a4) and u8 (elp_bsd_c6) codes, the
-same shapes through ``impl="auto"`` as a TPU resolves them at an
-autotune-cache miss (M = 16 and 512, the tile rule's blocks), one
+same shapes through ``impl="auto"`` as a TPU resolves them from the
+shape (M = 16 and 512, the tile rule's blocks), one
 VGG-16 im2col conv through the ops wrapper, and yi-34b's GEMMs as one
 chip of a 1x4 model mesh computes them: each local shape alone, and the
 per-shard path whole on four described chips; and the decode attention
@@ -32,9 +32,8 @@ from repro.kernels.elp_bsd_matmul import elp_bsd_matmul
 from repro.kernels.fused_decode import fused_decode_matmul
 from repro.kernels.ops import PackedWeight, quantized_matmul, tpu_blocks
 
-QWEN3_8B_KN = [(4096, 4096), (4096, 1024), (4096, 12288), (12288, 4096)]
-# yi-34b's per-chip GEMMs on a 1x4 model mesh: wq, wk/wv, wo, w1/w3, w2
-YI_34B_LOCAL_KN = [(7168, 1792), (7168, 256), (1792, 7168), (7168, 5120), (5120, 7168)]
+from lm_shapes import QWEN3_8B_KN, YI_34B_LOCAL_KN
+
 LAYOUTS = [("elp_bsd_a4", True), ("elp_bsd_c6", False)]
 
 
@@ -69,13 +68,10 @@ def one_chip(topo):
 
 
 @pytest.fixture
-def tpu_miss(monkeypatch, tmp_path):
-    """The program's backend checks read a TPU, and the autotune cache
-    is empty. A CPU process otherwise takes its CPU branches, and the
-    compile for the described chip would not see the kernels."""
-    from repro.bench import autotune
-
-    monkeypatch.setenv(autotune.CACHE_ENV, str(tmp_path / "empty.json"))
+def on_tpu(monkeypatch):
+    """The program's backend checks read a TPU. A CPU process otherwise
+    takes its CPU branches, and the compile for the described chip would
+    not see the kernels."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
 
@@ -139,8 +135,8 @@ def test_vgg16_im2col_conv_compiles(one_chip):
 @pytest.mark.parametrize("m", [16, 512], ids=["decode", "prefill"])
 @pytest.mark.parametrize("fmt_name,nibble", LAYOUTS)
 @pytest.mark.parametrize("k,n", QWEN3_8B_KN)
-def test_auto_dispatch_compiles_at_qwen3_8b(one_chip, tpu_miss, k, n, fmt_name, nibble, m):
-    """``impl="auto"`` at a TPU cache miss, as the serve step calls it:
+def test_auto_dispatch_compiles_at_qwen3_8b(one_chip, on_tpu, k, n, fmt_name, nibble, m):
+    """``impl="auto"`` on a TPU, as the serve step calls it:
     the tile rule's blocks fit the v5e's VMEM, the GEMM is one kernel
     named for ``_quantized_matmul``, and nothing pads or copies the codes."""
     pw = PackedWeight(
@@ -152,7 +148,7 @@ def test_auto_dispatch_compiles_at_qwen3_8b(one_chip, tpu_miss, k, n, fmt_name, 
     )
     x = jax.ShapeDtypeStruct((m, k), jnp.bfloat16, sharding=one_chip)
     text = _compiled_text(
-        lambda x, pw: quantized_matmul(x, pw, impl="auto", block_sizes="auto", out_dtype=x.dtype),
+        lambda x, pw: quantized_matmul(x, pw, impl="auto", out_dtype=x.dtype),
         x,
         pw,
     )
@@ -163,7 +159,7 @@ def test_auto_dispatch_compiles_at_qwen3_8b(one_chip, tpu_miss, k, n, fmt_name, 
     assert all(op == "parameter" or (op != "pad" and "S(1)" in t) for t, op in made), made
 
 
-def test_kernel_dispatch_reports_tiles_and_operand_dtype(tpu_miss):
+def test_kernel_dispatch_reports_tiles_and_operand_dtype(on_tpu):
     """The engine reports each decode GEMM's impl, tiles and operand
     dtype as a TPU resolves them: the fused kernel at the rule's tiles,
     bf16 operands for elp4 codes under bf16 activations."""
@@ -185,7 +181,7 @@ def test_kernel_dispatch_reports_tiles_and_operand_dtype(tpu_miss):
 
 @pytest.mark.parametrize("m", [32, 1024], ids=["decode", "prefill"])
 @pytest.mark.parametrize("k,n", YI_34B_LOCAL_KN)
-def test_auto_dispatch_compiles_at_yi_34b_shard(one_chip, tpu_miss, k, n, m):
+def test_auto_dispatch_compiles_at_yi_34b_shard(one_chip, on_tpu, k, n, m):
     """One chip's share of each yi-34b GEMM on a 1x4 model mesh, through
     ``impl="auto"`` as a TPU resolves the local shape: 32 slots decoding,
     a 1024-token prefill."""
@@ -198,7 +194,7 @@ def test_auto_dispatch_compiles_at_yi_34b_shard(one_chip, tpu_miss, k, n, m):
     )
     x = jax.ShapeDtypeStruct((m, k), jnp.bfloat16, sharding=one_chip)
     text = _compiled_text(
-        lambda x, pw: quantized_matmul(x, pw, impl="auto", block_sizes="auto", out_dtype=x.dtype),
+        lambda x, pw: quantized_matmul(x, pw, impl="auto", out_dtype=x.dtype),
         x,
         pw,
     )
@@ -206,7 +202,7 @@ def test_auto_dispatch_compiles_at_yi_34b_shard(one_chip, tpu_miss, k, n, m):
 
 
 @pytest.mark.parametrize("name,k,n", [("wq", 7168, 7168), ("wo", 7168, 7168)])
-def test_sharded_gemm_compiles_on_four_chips(topo, tpu_miss, name, k, n):
+def test_sharded_gemm_compiles_on_four_chips(topo, on_tpu, name, k, n):
     """The per-shard path on a described 1x4 mesh: a column-parallel
     weight runs the kernel on each chip's columns; a row-parallel one on
     each chip's rows, then one all-reduce of the partial sums."""
@@ -231,7 +227,7 @@ def test_sharded_gemm_compiles_on_four_chips(topo, tpu_miss, name, k, n):
     pw = shr.mark_splits(placed, specs, mesh)[name]
     x = jax.ShapeDtypeStruct((32, k), jnp.bfloat16, sharding=NamedSharding(mesh, P()))
     text = _compiled_text(
-        lambda x, pw: quantized_matmul(x, pw, impl="auto", block_sizes="auto", out_dtype=x.dtype),
+        lambda x, pw: quantized_matmul(x, pw, impl="auto", out_dtype=x.dtype),
         x,
         pw,
     )
