@@ -130,14 +130,15 @@ def phase_cnn(spec: cnn.CnnSpec, *, seed: int, batch: int) -> dict:
     with jax.default_matmul_precision("highest"):
         ref = jax.jit(lambda p, v: cnn.forward(p, spec, v))(dequantize_tree(qm.params), x)
     out = {}
-    # qm.forward's default CNN dispatch is the XLA conv on decoded codes
+    # qm.forward's default CNN dispatch: XLA's conv on decoded codes, the
+    # fused decode kernel for the fc layers
     for label, xs, impl in (("default", x, None), ("pallas", x[:1], "pallas")):
         t = time.perf_counter()
         logits = jax.block_until_ready(qm.forward(xs, impl=impl))
         err = _rel_err(logits, ref[: xs.shape[0]])
         top1 = float(np.mean(np.argmax(logits, -1) == np.argmax(ref[: xs.shape[0]], -1)))
         print(
-            f"[cnn] batch={xs.shape[0]} impl={impl or 'xla (default)'}: max rel err {err:.3e} "
+            f"[cnn] batch={xs.shape[0]} impl={impl or 'auto (default)'}: max rel err {err:.3e} "
             f"(tol {CNN_TOL}), top-1 agreement {top1:.3f}, "
             f"{time.perf_counter() - t:.1f} s incl. compile"
         )

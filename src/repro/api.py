@@ -370,7 +370,7 @@ class QuantizedModel:
                 x,
                 calib=calib,
                 act_bits=act_bits,
-                impl=impl or "xla",
+                impl=impl or "auto",
                 block_sizes=self.scheme.block_sizes if block_sizes is None else block_sizes,
                 interpret=interpret,
             )

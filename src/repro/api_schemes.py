@@ -421,7 +421,7 @@ class CnnAdapter:
         *,
         calib: CalibrationTable | None = None,
         act_bits: int | None = None,
-        impl: str = "xla",
+        impl: str = "auto",
         block_sizes=None,
         interpret: bool | None = None,
     ) -> Array:

@@ -9,7 +9,7 @@ decode+matmul Pallas kernel — the conv never materializes float weights
 in HBM, which is the paper's energy story on the conv workload.
 
 ``impl="xla"`` is the fallback: dequantize in-graph and call
-``lax.conv_general_dilated`` (XLA fuses the decode; same HBM bytes).
+``lax.conv_general_dilated`` on the decoded weight.
 
 Patch layout contract: patches are ordered ``(kh, kw, cin)`` with
 ``cin`` fastest — exactly the row-major flattening of an ``HWIO``

@@ -13,9 +13,9 @@ execution paths:
 ``quantized_matmul`` picks between:
   * ``impl="pallas"`` — the fused decode+matmul kernel (TPU target,
     interpret-mode on CPU),
-  * ``impl="xla"``    — dequantize-then-dot in plain jnp. Same HBM story
-    (codes are the stored operand), used for stacked (expert) weights,
-    off TPU, and as the reference datapath.
+  * ``impl="xla"``    — dequantize-then-dot in plain jnp (XLA writes the
+    decoded weight to HBM before the dot reads it), used for stacked
+    (expert) weights, off TPU, and as the reference datapath.
 
 A 2-D weight on a mesh of several devices runs one of these per device
 under ``jax.shard_map``, on the device's own shard of the codes

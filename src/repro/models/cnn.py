@@ -148,7 +148,7 @@ def forward(
     *,
     calib=None,
     tap=None,
-    impl: str = "xla",
+    impl: str = "auto",
     block_sizes: tuple[int, int, int] | None = None,
     interpret: bool | None = None,
 ) -> Array:
@@ -173,8 +173,12 @@ def forward(
     leaves (see :func:`quantize_params`): packed convs run through
     :func:`~repro.kernels.conv.quantized_conv2d` and packed fc layers
     through ``quantized_matmul``, so the whole network executes on
-    ELP_BSD codes end-to-end. ``impl`` selects the packed execution path
-    ("xla" dequant-fused fallback, "pallas" fused decode+matmul kernel);
+    ELP_BSD codes end-to-end. ``impl`` selects the packed execution path:
+    the default ``"auto"`` resolves each layer by its op's own rule (on a
+    TPU the fc layers take the decode+matmul kernel at the tiles
+    ``kernels/ops.select_impl`` derives from the shape, the convs take
+    XLA's conv; off a TPU both take XLA), ``"xla"`` decodes then calls
+    XLA's dot or conv, ``"pallas"`` / ``"pallas_fused"`` force a kernel.
     ``block_sizes`` forwards a ``(block_m, block_n, block_k)`` tuple to
     the packed kernels.
     """

@@ -7,7 +7,8 @@ qwen3-8b's four GEMM shapes at decode (M = 8 slots) and prefill
 (M = 512 tokens), in nibble (elp_bsd_a4) and u8 (elp_bsd_c6) codes, the
 same shapes through ``impl="auto"`` as a TPU resolves them from the
 shape (M = 16 and 512, the tile rule's blocks), one
-VGG-16 im2col conv through the ops wrapper, and yi-34b's GEMMs as one
+VGG-16 im2col conv through the ops wrapper, VGG-16's fc layers through
+``impl="auto"`` at batch 1 and 64, and yi-34b's GEMMs as one
 chip of a 1x4 model mesh computes them: each local shape alone, and the
 per-shard path whole on four described chips; and the decode attention
 over the dense bf16 cache at both LM cells' shapes, where GQA must read
@@ -177,6 +178,34 @@ def test_kernel_dispatch_reports_tiles_and_operand_dtype(on_tpu):
         assert d["impl"] == "pallas_fused" and d["source"] == "heuristic", key
         assert d["blocks"] == tpu_blocks(m, k, n, nibble=key.endswith("|nib"))[1], key
         assert d["operand"] == "bfloat16", key
+
+
+# VGG-16's fc6, fc7 and fc8
+VGG16_FC_KN = [(25088, 4096), (4096, 4096), (4096, 1000)]
+
+
+@pytest.mark.parametrize("m", [1, 64])
+@pytest.mark.parametrize("k,n", VGG16_FC_KN)
+def test_auto_dispatch_compiles_at_vgg16_fc(one_chip, on_tpu, k, n, m):
+    """VGG-16's fc layers as ``models/cnn.forward`` calls them on a TPU:
+    float32 activations at batch 1 and 64 through ``impl="auto"``. Each
+    is one kernel, and no float array the size of the weight is made
+    (XLA's decode wrote fc6 out as float32 [25088, 4096] and relaid it)."""
+    pw = PackedWeight(
+        codes=jax.ShapeDtypeStruct((k // 2, n), jnp.uint8, sharding=one_chip),
+        sf=jax.ShapeDtypeStruct((1, 1), jnp.float32, sharding=one_chip),
+        fmt_name="elp_bsd_a4",
+        nibble=True,
+        shape=(k, n),
+    )
+    x = jax.ShapeDtypeStruct((m, k), jnp.float32, sharding=one_chip)
+    text = _compiled_text(
+        lambda x, pw: quantized_matmul(x, pw, impl="auto", out_dtype=jnp.float32), x, pw
+    )
+    kernels = re.findall(r"%_quantized_matmul\S* = \S+ custom-call\(.*tpu_custom_call", text)
+    assert len(kernels) == 1, kernels
+    big = _tensors_of_at_least(text, k * n)
+    assert not [t for t in big if t[0].startswith(("f", "bf"))], big
 
 
 @pytest.mark.parametrize("m", [32, 1024], ids=["decode", "prefill"])
